@@ -10,18 +10,34 @@ jax.sharding meshes + XLA collectives. Drop-in Python API:
 """
 import os as _os
 
-# Persistent XLA compilation cache: tree training launches a family of
-# jitted programs per (bucket-size, config); caching makes reruns warm.
-if not _os.environ.get("LGBM_TPU_NO_COMP_CACHE"):
-    try:
-        import jax as _jax
-        _cache_dir = _os.environ.get(
-            "JAX_COMPILATION_CACHE_DIR",
-            _os.path.join(_os.path.expanduser("~"), ".cache", "lightgbm_tpu_xla"))
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:  # pragma: no cover
-        pass
+import jax as _jax
+
+CHECKOUT_CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".jax_compile_cache")
+
+
+def _configure_compile_cache() -> None:
+    """The one rule for the persistent XLA compile cache (the growth
+    ladder compiles for minutes cold, so a cache that misses is most of
+    a cold run): where JAX_COMPILATION_CACHE_DIR is set JAX reads it
+    itself and no directory is set in code; otherwise the cache lives at
+    a fixed path inside the checkout — the path is part of the cache
+    key, so it must never move. LGBM_TPU_NO_COMP_CACHE is the test
+    suite's opt-out (tests/conftest.py)."""
+    if _os.environ.get("LGBM_TPU_NO_COMP_CACHE"):
+        return
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+
+
+_configure_compile_cache()
+
+
+def compile_cache_dir():
+    """The persistent compile cache directory in effect (None = off)."""
+    return _jax.config.jax_compilation_cache_dir
 
 from . import telemetry
 from .basic import Booster, Dataset
@@ -45,7 +61,7 @@ __all__ = [
     "LGBMModel", "LGBMRegressor", "LGBMClassifier", "LGBMRanker",
     "early_stopping", "print_evaluation", "record_evaluation",
     "record_telemetry", "reset_parameter", "EarlyStopException",
-    "LightGBMError", "telemetry",
+    "LightGBMError", "telemetry", "compile_cache_dir",
     "plot_importance", "plot_split_value_histogram", "plot_metric",
     "plot_tree", "create_tree_digraph",
 ]

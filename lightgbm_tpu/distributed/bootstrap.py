@@ -72,35 +72,31 @@ def _initialize_supervised(coordinator_address: str, num_processes: int,
     The stock ``jax.distributed.initialize`` arms the coordination
     service's own heartbeat: when a rank dies, the service tears down
     every *survivor* (hard process abort from a C++ polling thread) —
-    the opposite of elastic recovery, and its Python
-    missed-heartbeat callback path aborts with std::bad_cast on this
-    jaxlib. So the supervised path builds the same service/client pair
-    manually with effectively-infinite heartbeat knobs: the service
-    degenerates to the bootstrap KV store the backends need, while
-    OUR supervision (distributed/supervisor.py) owns liveness with a
-    clean Python-side failure path. ``shutdown_on_destruction=False``
-    keeps the client destructor from joining threads blocked on dead
-    peers during shrink."""
+    the opposite of elastic recovery. So the supervised path builds the
+    same service/client pair manually with an effectively-infinite
+    heartbeat timeout: the service degenerates to the bootstrap KV
+    store the backends need, while OUR supervision
+    (distributed/supervisor.py) owns liveness with a clean Python-side
+    failure path. ``shutdown_on_destruction=False`` keeps the client
+    destructor from joining threads blocked on dead peers during
+    shrink."""
     from jax._src import distributed as _jd
-    from jaxlib import xla_extension as xe
+    from jax._src.lib import _jax
 
-    # seconds; the service only declares death after
-    # heartbeat_interval * max_missing_heartbeats — push it past any
-    # plausible job length
-    inert_s = 1_000_000
+    # seconds; push the service's death verdict past any plausible job
+    inert_s = 10_000_000
     if int(process_id) == 0 and _jd.global_state.service is None:
         port = coordinator_address.rsplit(":", 1)[1]
-        _jd.global_state.service = xe.get_distributed_runtime_service(
-            f"[::]:{port}", int(num_processes),
-            heartbeat_interval=inert_s, max_missing_heartbeats=10)
+        _jd.global_state.service = _jax.get_distributed_runtime_service(
+            f"[::]:{port}", int(num_processes), heartbeat_timeout=inert_s)
     # init_timeout doubles as the elastic-rejoin wait: a replacement
     # process blocks here until the existing members reach their
     # re-form boundary and rank 0 starts the new service
     init_timeout = int(os.environ.get("LGBM_TPU_INIT_TIMEOUT_S", 60))
-    client = xe.get_distributed_runtime_client(
+    client = _jax.get_distributed_runtime_client(
         coordinator_address, int(process_id), init_timeout=init_timeout,
-        heartbeat_interval=inert_s, max_missing_heartbeats=10,
-        shutdown_on_destruction=False, use_compression=True)
+        heartbeat_timeout=inert_s, shutdown_on_destruction=False,
+        use_compression=True)
     client.connect()
     _jd.global_state.client = client
     _jd.global_state.num_processes = int(num_processes)

@@ -79,32 +79,14 @@ def _jaxlib_version() -> str:
         return "?"
 
 
-def _cpu_runtime() -> str:
-    """Which XLA:CPU runtime compiled this process's executables. The
-    thunk runtime (the jax 0.4.37 default) JIT-resolves fusion-kernel
-    symbols in-memory, so its serialized executables only reload in the
-    process that built them; the legacy runtime
-    (``--xla_cpu_use_thunk_runtime=false``) emits self-contained object
-    code that survives a process restart. Part of the fingerprint so a
-    runtime mismatch degrades to the StableHLO rebuild instead of a
-    confusing native-load failure."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    return "legacy" if "xla_cpu_use_thunk_runtime=false" in flags \
-        else "thunks"
-
-
 def env_fingerprint(donate: bool) -> Dict[str, str]:
     """The native layer's validity domain: an executable deserializes
     safely only into the exact runtime that serialized it."""
     import jax
-    backend = jax.default_backend()
-    fp = {"jax": jax.__version__,
-          "jaxlib": _jaxlib_version(),
-          "backend": backend,
-          "donate": "1" if donate else "0"}
-    if backend == "cpu":
-        fp["cpu_runtime"] = _cpu_runtime()
-    return fp
+    return {"jax": jax.__version__,
+            "jaxlib": _jaxlib_version(),
+            "backend": jax.default_backend(),
+            "donate": "1" if donate else "0"}
 
 
 def cache_dir_for_model(model_file: str) -> str:
@@ -233,7 +215,7 @@ class ExportCache:
                     continue
                 header, payload, trees, hlo = entry
                 if header["env"] == want_env and self._install_native(
-                        predictor, family, bucket, payload, trees):
+                        predictor, model, family, bucket, payload, trees):
                     stats["restored"] += 1
                     telem_counters.incr("export_cache_hits")
                 elif hlo and self._install_rebuilt(
@@ -266,13 +248,17 @@ class ExportCache:
         except (OSError, ValueError, KeyError, struct.error):
             return None
 
-    def _install_native(self, predictor, family, bucket, payload,
+    def _install_native(self, predictor, model, family, bucket, payload,
                         trees) -> bool:
         try:
+            import jax
             from jax.experimental import serialize_executable
             in_tree, out_tree = pickle.loads(trees)
+            # a single-device executable must be told its device, or the
+            # loader assumes one shard per local device
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=[model.device or jax.devices()[0]])
             predictor.install(family, bucket, compiled)
             return True
         except Exception as exc:   # noqa: BLE001 — fall through to hlo

@@ -1,7 +1,7 @@
 """ctypes bridge to the native C++ data parser (cpp/parser.cpp).
 
-Builds lazily with make on first use if the shared library is missing
-(the reference ships its native code prebuilt in lib_lightgbm; ours builds
+Runs make on first use (a no-op when the shared library is current; the
+reference ships its native code prebuilt in lib_lightgbm, ours builds
 from source in-tree).
 """
 from __future__ import annotations
@@ -34,16 +34,15 @@ def _load() -> Optional[ctypes.CDLL]:
         return _LIB
     _TRIED = True
     path = _lib_path()
-    if not os.path.exists(path):
-        try:
-            subprocess.run(["make", "-C", os.path.dirname(path)],
-                           check=True, capture_output=True, timeout=120)
-        except Exception as e:  # pragma: no cover
-            log.debug("native parser build failed: %s", e)
-            return None
     try:
+        # unconditional: make is a no-op when the library is newer than
+        # parser.cpp, and a stale or missing one is rebuilt
+        subprocess.run(["make", "-C", os.path.dirname(path)],
+                       check=True, capture_output=True, timeout=120)
         lib = ctypes.CDLL(path)
-    except OSError:
+    except (OSError, subprocess.SubprocessError) as e:
+        log.warning("native parser unavailable (%s); file parsing uses "
+                    "the Python parser", e)
         return None
     lib.parser_probe.restype = ctypes.c_int
     lib.parser_probe.argtypes = [

@@ -54,7 +54,7 @@ def _host_global(arr) -> Optional[np.ndarray]:
         return np.asarray(jax.device_get(arr))
     from jax.experimental import multihost_utils
     gathered = faults.run_collective(
-        lambda: multihost_utils.process_allgather(arr),
+        lambda: multihost_utils.process_allgather(arr, tiled=True),
         site="host_global")
     return np.asarray(gathered)
 
@@ -567,8 +567,8 @@ class GBDT:
             # program gates the delta to 0 when k == 0, so this is safe
             # before k is known), stash the record handles, and replay
             # the PREVIOUS iteration's tree while this program runs on
-            # device — hiding the ~70 ms/iter record-fetch round trip
-            # and the host replay entirely (tools/profile_fused.py).
+            # device — hiding the record-fetch round trip and the host
+            # replay (tools/profile_fused.py).
             with telem.phase("score_update"):
                 self.score_updater.score = score_before.at[0].set(new_score)
             with self._pend_lock:

@@ -81,7 +81,7 @@ class SerialTreeLearner:
             for f in dataset.used_features)
         # XLA's fused one-hot contraction measured faster than the Pallas
         # kernel on v5e (tools/microbench_injit.py); opt-in only.
-        self._use_pallas = use_pallas_env() and jax.default_backend() == "tpu"
+        self._use_pallas = use_pallas_env()
         # quantized-gradient training (ops/quantize.py): per-iteration
         # int discretization, exact integer histograms, bit-exact sibling
         # subtraction; 0 = float path (default, unchanged)

@@ -111,9 +111,8 @@ def build_histogram_pallas_t(codes_t: jax.Array, gh: jax.Array, num_bins: int,
     return out
 
 
-def _hist_kernel_q(codes_ref, ghq_ref, out_ref, *, num_bins: int,
-                   op_bits: int):
-    """Integer variant of _hist_kernel: ONE i8 (or i32) matmul per tile
+def _hist_kernel_q(codes_ref, ghq_ref, out_ref, *, num_bins: int):
+    """Integer variant of _hist_kernel: ONE i8 matmul per tile
     accumulating EXACT int32 per-bin sums — no hi/lo split operand, no
     recombination pass, and a (C, 4) operand instead of (C, 6)."""
     p_idx = pl.program_id(1)
@@ -122,12 +121,11 @@ def _hist_kernel_q(codes_ref, ghq_ref, out_ref, *, num_bins: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    op_dtype = jnp.int8 if op_bits <= 8 else jnp.int32
-    ghq = ghq_ref[...].astype(op_dtype)                # (C, 4)
+    ghq = ghq_ref[...].astype(jnp.int8)                # (C, 4)
     codes = codes_ref[...].astype(jnp.int32)           # (Ft, C)
     ft, c = codes.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (ft, num_bins, c), 1)
-    onehot = (codes[:, None, :] == iota).astype(op_dtype)  # (Ft, B, C)
+    onehot = (codes[:, None, :] == iota).astype(jnp.int8)  # (Ft, B, C)
     part = jax.lax.dot_general(
         onehot.reshape(ft * num_bins, c), ghq,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -141,7 +139,7 @@ def _hist_kernel_q(codes_ref, ghq_ref, out_ref, *, num_bins: int,
 def build_histogram_pallas_quantized(binned_rows: jax.Array, ghq: jax.Array,
                                      num_bins: int, chunk_rows: int = 2048,
                                      interpret: bool = False) -> jax.Array:
-    """(P, F) codes + (P, 3) int [qg, qh, valid] -> (F, B, 3) int32."""
+    """(P, F) codes + (P, 3) int8 [qg, qh, valid] -> (F, B, 3) int32."""
     return build_histogram_pallas_quantized_t(
         binned_rows.T, ghq, num_bins, chunk_rows=chunk_rows,
         interpret=interpret)
@@ -152,16 +150,22 @@ def build_histogram_pallas_quantized(binned_rows: jax.Array, ghq: jax.Array,
 def build_histogram_pallas_quantized_t(codes_t: jax.Array, ghq: jax.Array,
                                        num_bins: int, chunk_rows: int = 2048,
                                        interpret: bool = False) -> jax.Array:
-    """(F, P) transposed codes + (P, 3) int [qg, qh, valid] ->
+    """(F, P) transposed codes + (P, 3) int8 [qg, qh, valid] ->
     (F, B, 3) int32 exact histogram.
 
     Same tiling contract as build_histogram_pallas_t; the operand rides
     as int32 blocks (Mosaic's narrow-int tiling is stricter) and is cast
-    to int8 inside the kernel when the quantization fits, so the MXU
-    still sees the native i8 contraction. Pad rows must carry ghq == 0.
+    to int8 inside the kernel, so the MXU sees the native i8
+    contraction. Wider quantization (grad_bits > 8 stores int32) has no
+    kernel: Mosaic rejects an i32 x i32 matmul on the chip ("Bad lhs/rhs
+    type", v5e, jax 0.9.0). Pad rows must carry ghq == 0.
     """
+    if ghq.dtype != jnp.int8:
+        raise ValueError(
+            "the Pallas quantized histogram kernel takes int8 gradients "
+            f"(grad_bits <= 8), got {ghq.dtype}; unset LGBM_TPU_PALLAS "
+            "for wider quantization")
     f, p = codes_t.shape
-    op_bits = 8 if ghq.dtype == jnp.int8 else 32
     pad_p = (-p) % chunk_rows
     pad_f = (-f) % FEAT_TILE
     if pad_p or pad_f:
@@ -171,8 +175,7 @@ def build_histogram_pallas_quantized_t(codes_t: jax.Array, ghq: jax.Array,
 
     grid = (ff // FEAT_TILE, pp // chunk_rows)
     out = pl.pallas_call(
-        functools.partial(_hist_kernel_q, num_bins=num_bins,
-                         op_bits=op_bits),
+        functools.partial(_hist_kernel_q, num_bins=num_bins),
         grid=grid,
         in_specs=[
             pl.BlockSpec((FEAT_TILE, chunk_rows), lambda fi, pi: (fi, pi)),

@@ -35,15 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:   # jax < 0.5: experimental API, check_rep not check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *args, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_exp(f, *args, **kwargs)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import Config
@@ -146,8 +138,10 @@ class DataParallelTreeLearner(SerialTreeLearner):
         self.n_pad = n + pad
         self.max_local_bucket = _bucket(self.local_n, 1 << 30)
         rsh = NamedSharding(self.mesh, P("data", None))
+        # host -> shards directly: a jnp.asarray first would commit the
+        # whole matrix to device 0 before resharding
         self.binned = jax.device_put(
-            jnp.asarray(binned_np).reshape(self.shards, self.local_n, -1), rsh)
+            np.asarray(binned_np).reshape(self.shards, self.local_n, -1), rsh)
         self._build_sharded_fns()
 
     # -- shard_map programs --------------------------------------------
@@ -304,7 +298,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
                 rows = local_of[shard_of == s]
                 bufs[s, : len(rows)] = rows
                 counts[s] = len(rows)
-        self._idx_buf = jax.device_put(jnp.asarray(bufs), rsh)
+        self._idx_buf = jax.device_put(bufs, rsh)
         self._leaf_begin: Dict[int, np.ndarray] = {0: np.zeros(self.shards, np.int64)}
         self._leaf_count: Dict[int, np.ndarray] = {0: counts}
         return self._train_from_root(iter_seed)
@@ -763,8 +757,9 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
             if pad:
                 cp = np.pad(cp, ((0, pad), (0, 0)))
                 cr = np.pad(cr, ((0, pad), (0, 0)))
-            self.codes_pack = jax.device_put(jnp.asarray(cp), rsh)
-            self.codes_row = jax.device_put(jnp.asarray(cr), rsh)
+            # host -> shards directly (the base class kept both host-side)
+            self.codes_pack = jax.device_put(cp, rsh)
+            self.codes_row = jax.device_put(cr, rsh)
         self._meta = (self.f_numbins, self.f_missing, self.f_default,
                       self.f_monotone, self.f_penalty, self.f_categorical,
                       self.f_col, self.f_base, self.f_elide, self.hist_idx)
@@ -815,7 +810,7 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
             sl = block[max(lo, 0):lo + local_n]
             if sl.shape[0] < local_n:
                 sl = np.pad(sl, ((0, local_n - sl.shape[0]), (0, 0)))
-            bufs.append(jax.device_put(jnp.asarray(sl), dev))
+            bufs.append(jax.device_put(sl, dev))
         return jax.make_array_from_single_device_arrays(
             (self.n_pad, int(block.shape[1])),
             NamedSharding(self.mesh, P("data", None)), bufs)
@@ -1127,9 +1122,9 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 gp[:rows] = self._host_rows(grad, lo, hi)
                 hp[:rows] = self._host_rows(hess, lo, hi)
             wp = np.asarray(wv[lo:lo + local_n], dtype=np.float32)
-            gj = jax.device_put(jnp.asarray(gp), dev)
-            hj = jax.device_put(jnp.asarray(hp), dev)
-            wj = jax.device_put(jnp.asarray(wp), dev)
+            gj = jax.device_put(gp, dev)
+            hj = jax.device_put(hp, dev)
+            wj = jax.device_put(wp, dev)
             buf = self._dp_stream_init(local_n, d_cols, cw)(gj, hj, wj)
             if rows:
                 wire_lo = lo - shard_begin
@@ -1483,49 +1478,66 @@ def create_tree_learner(config: Config, dataset: Dataset,
                 "CEGB / pool budget); fix the config or set "
                 "stream_mode=off")
         return DeviceTreeLearner(config, dataset)
+
+    def host_loop(cls, reason, *args):
+        # the one place a run leaves the whole-tree device programs: say
+        # so, or a benchmark (and chip_smoke.py) measures the wrong learner
+        log.warning("tree_learner=%s trains on the host-loop %s, not the "
+                    "whole-tree device program: %s", name, cls.__name__,
+                    reason)
+        return cls(config, dataset, *args)
+
+    def device_reason(strategy=None, identity_only=False):
+        """Why the device learner cannot take this config (None = it
+        can). identity_only: the feature/voting device learners need the
+        identity feature->column mapping (no EFB bundles), the float row
+        layout and no by-node sampling."""
+        if host_only:
+            return "LGBM_TPU_HOST_LEARNER=1"
+        if identity_only:
+            if dataset.bundle_arrays() is not None:
+                return "the dataset is EFB-bundled"
+            if config.quant_bits:
+                return "quantized gradients"
+            if 0.0 < config.feature_fraction_bynode < 1.0:
+                return "feature_fraction_bynode sampling"
+        return DeviceTreeLearner.unsupported_reason(config, dataset,
+                                                    strategy=strategy)
+
     if name in ("serial",):
-        if not host_only and DeviceTreeLearner.supports(config, dataset):
+        reason = device_reason()
+        if reason is None:
             return DeviceTreeLearner(config, dataset)
-        return SerialTreeLearner(config, dataset)
+        return host_loop(SerialTreeLearner, reason)
     if name in ("feature", "feature_parallel"):
-        # whole-tree device FP needs the identity feature->column mapping
-        # (no EFB bundles), no by-node sampling and the float row layout
-        # (quantized packed rows gate to serial/DP; the host FP learner
-        # below carries the quantized pipeline via GSPMD shardings)
-        if (not host_only
-                and dataset.bundle_arrays() is None
-                and not config.quant_bits
-                and not (0.0 < config.feature_fraction_bynode < 1.0)
-                and DeviceTreeLearner.supports(config, dataset,
-                                               strategy="compact")):
+        reason = device_reason("compact", identity_only=True)
+        if reason is None:
             return DeviceFeatureParallelTreeLearner(config, dataset, mesh)
-        return FeatureParallelTreeLearner(config, dataset, mesh)
+        return host_loop(FeatureParallelTreeLearner, reason, mesh)
     if name in ("data", "data_parallel"):
         # the DP device learner always runs the compact strategy; check
         # the learner that will actually be built
-        if not host_only and DeviceTreeLearner.supports(
-                config, dataset, strategy="compact"):
+        reason = device_reason("compact")
+        if reason is None:
             return DeviceDataParallelTreeLearner(config, dataset, mesh)
         if rows_sharded:
             raise LightGBMError(
                 "dist_shard_mode=rows needs the device data-parallel "
-                "learner, but this config is unsupported by it (forced "
-                "splits / CEGB / pool budget); fix the config or use "
-                "dist_shard_mode=replicated")
-        return DataParallelTreeLearner(config, dataset, mesh)
+                f"learner, but this config is unsupported by it ({reason})"
+                "; fix the config or use dist_shard_mode=replicated")
+        return host_loop(DataParallelTreeLearner, reason, mesh)
     if name in ("voting", "voting_parallel"):
-        # device PV-Tree needs the identity mapping and a feature count
-        # the 2k election actually reduces
+        # device PV-Tree also needs a feature count the 2k election
+        # actually reduces, and more than one shard
         n_shards = (mesh.devices.size if mesh is not None
                     else len(jax.devices()))
-        if (not host_only
-                and dataset.bundle_arrays() is None
-                and not config.quant_bits
-                and not (0.0 < config.feature_fraction_bynode < 1.0)
-                and dataset.num_features > 2 * max(1, int(config.top_k))
-                and n_shards > 1
-                and DeviceTreeLearner.supports(config, dataset,
-                                               strategy="compact")):
+        reason = device_reason("compact", identity_only=True)
+        if reason is None and dataset.num_features <= 2 * max(
+                1, int(config.top_k)):
+            reason = "top_k elects every feature (nothing to reduce)"
+        if reason is None and n_shards <= 1:
+            reason = "one device (no election to hold)"
+        if reason is None:
             return DeviceVotingParallelTreeLearner(config, dataset, mesh)
-        return VotingParallelTreeLearner(config, dataset, mesh)
+        return host_loop(VotingParallelTreeLearner, reason, mesh)
     log.fatal("Unknown tree learner %s", name)

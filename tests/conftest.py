@@ -11,14 +11,16 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
-# Persistent XLA compile cache: DISABLED for the suite. In this image
-# (jaxlib 0.4.37, CPU backend) deserializing a cached executable written
-# by a PREVIOUS process segfaults the interpreter (reproduce: run
-# test_binning+test_bundling twice against one JAX_COMPILATION_CACHE_DIR
-# — cold run passes, warm run dies in jax array _value). The in-memory
-# jit cache still dedups within the run; cross-run caching costs
-# correctness here, so it's off. LGBM_TPU_NO_COMP_CACHE also stops the
-# package __init__ from pointing the cache at ~/.cache.
+# Persistent XLA compile cache: DISABLED for the suite, so a run never
+# depends on what an earlier run left on disk. The jaxlib-0.4.37 reason
+# (a cached executable from a previous process segfaulted the
+# interpreter) no longer reproduces under jaxlib 0.9.0 — test_binning +
+# test_bundling twice against one cache directory pass warm — but every
+# XLA:CPU reload there logs "Machine type used for XLA:CPU compilation
+# doesn't match the machine type for execution ... could lead to
+# execution errors such as SIGILL" (cpu_aot_loader.cc), and the driver's
+# checkout starts with no cache anyway. LGBM_TPU_NO_COMP_CACHE is the
+# package's opt-out (lightgbm_tpu/__init__.py holds the one cache rule).
 os.environ["LGBM_TPU_NO_COMP_CACHE"] = "1"
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 

@@ -19,6 +19,7 @@ tree_sizes byte counts) and the gains are separately pinned allclose.
 """
 import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -133,6 +134,15 @@ def _train_multiclass(x, y, k, monkeypatch, batched, n_iter=2, **extra):
     return _train(params, x, y, n_iter=n_iter)
 
 
+# vmap over >= 8 classes batches the histogram contraction into a dot
+# that XLA:CPU (jaxlib 0.9.0) cannot run
+_xfail_cpu_batched_bf16_dot = pytest.mark.xfail(
+    jax.default_backend() == "cpu", raises=jax.errors.JaxRuntimeError,
+    reason="XLA:CPU: 'UNIMPLEMENTED: Unsupported element type for "
+           "DotThunk::Execute: BF16 x BF16 = F32'")
+
+
+@_xfail_cpu_batched_bf16_dot
 def test_vmap_k8_matches_per_class_loop(monkeypatch):
     """One vmapped program for all 8 per-class trees must produce
     bit-identical predictions and tree structure to 8 sequential
@@ -163,6 +173,7 @@ def test_vmap_k8_matches_per_class_loop(monkeypatch):
 
 
 @pytest.mark.slow
+@_xfail_cpu_batched_bf16_dot
 def test_vmap_k100_smoke(monkeypatch):
     """Large-K: 100 per-class trees through ONE batched program per
     iteration, counters prove it."""

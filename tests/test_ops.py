@@ -191,10 +191,10 @@ def test_partition_preserves_overrun_region():
 
 @pytest.mark.parametrize("num_bins", [16, 64, 128])
 def test_pallas_histogram_interpret_parity(num_bins):
-    """Execute the Pallas kernel (interpret mode on CPU, compiled on TPU)
-    and compare against the XLA one-hot path — the GPU_DEBUG_COMPARE
-    host-oracle pattern (reference: gpu_tree_learner.cpp:996-1019)."""
-    import jax
+    """Execute the Pallas kernel in interpret mode (chip_smoke.py's
+    kernels leg compiles it with Mosaic on the chip) and compare against
+    the XLA one-hot path — the GPU_DEBUG_COMPARE host-oracle pattern
+    (reference: gpu_tree_learner.cpp:996-1019)."""
     from lightgbm_tpu.ops.pallas import histogram_kernel as pk
     r = np.random.RandomState(7)
     n, f = 3000, 11          # non-multiples of chunk_rows / FEAT_TILE
@@ -204,9 +204,8 @@ def test_pallas_histogram_interpret_parity(num_bins):
     valid = np.ones(n, dtype=bool)
     valid[2700:] = False
     gh = np.stack([g * valid, h * valid, valid.astype(np.float32)], axis=1)
-    interpret = jax.default_backend() != "tpu"
     got = np.asarray(pk.build_histogram_pallas(
-        jnp.asarray(binned), jnp.asarray(gh), num_bins, interpret=interpret))
+        jnp.asarray(binned), jnp.asarray(gh), num_bins, interpret=True))
     want = np.asarray(hist_ops.build_histogram(
         jnp.asarray(binned), jnp.asarray(gh), num_bins=num_bins,
         use_pallas=False))
@@ -219,15 +218,13 @@ def test_pallas_histogram_interpret_parity(num_bins):
 
 
 def test_pallas_histogram_transposed_layout_interpret():
-    import jax
     from lightgbm_tpu.ops.pallas import histogram_kernel as pk
     r = np.random.RandomState(8)
     n, f, b = 2048, 8, 32
     binned = r.randint(0, b, size=(n, f)).astype(np.uint8)
     gh = np.stack([r.randn(n), r.rand(n), np.ones(n)], axis=1).astype(np.float32)
-    interpret = jax.default_backend() != "tpu"
     got = np.asarray(pk.build_histogram_pallas_t(
-        jnp.asarray(binned.T.copy()), jnp.asarray(gh), b, interpret=interpret))
+        jnp.asarray(binned.T.copy()), jnp.asarray(gh), b, interpret=True))
     want = _ref_histogram(binned, gh[:, 0], gh[:, 1], np.ones(n, bool), b)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
@@ -275,8 +272,8 @@ def test_histogram_multichunk_inside_shard_map():
     carry fails shard_map's scan carry type check — this was invisible
     until a host-loop learner met a >2048-row window on a mesh)."""
     import jax
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     r = np.random.RandomState(0)
     rows = r.randint(0, 64, (8 * 4096, 13)).astype(np.uint8)

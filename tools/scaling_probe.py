@@ -11,11 +11,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import os as _os  # noqa: E402
-_os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", _os.path.join(
-    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
-    ".jax_compile_cache"))
-_os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "2")
 import jax  # noqa: E402
 
 from lightgbm_tpu.config import Config  # noqa: E402

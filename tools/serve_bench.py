@@ -49,16 +49,6 @@ import threading
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if os.environ.get("SERVE_BENCH_CACHE_DIR"):
-    # cross-process executable reuse on XLA:CPU needs the legacy runtime
-    # (the thunk runtime JIT-resolves kernel symbols in-memory, so its
-    # serialized executables only reload in the process that built
-    # them); must be set before jax initializes. TPU/GPU executables
-    # are self-contained and need no flag.
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in _flags:
-        os.environ["XLA_FLAGS"] = (
-            _flags + " --xla_cpu_use_thunk_runtime=false").strip()
 
 import numpy as np
 
