@@ -16,6 +16,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..config import Config
+from ..telemetry import spans as telem_spans
 from ..utils import log
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
                       MISSING_NONE, MISSING_ZERO, BinMapper,
@@ -131,10 +132,12 @@ class Dataset:
                 default=1)
         else:
             cat_idx = self._resolve_categorical(categorical_feature)
-            self.bin_mappers = (
-                self._build_mappers_sparse(sparse, cat_idx)
-                if sparse is not None
-                else self._build_mappers(data, cat_idx))
+            with telem_spans.stage("setup_find_bin_seconds",
+                                   "dataset/find_bin"):
+                self.bin_mappers = (
+                    self._build_mappers_sparse(sparse, cat_idx)
+                    if sparse is not None
+                    else self._build_mappers(data, cat_idx))
             self.used_features = [i for i, m in enumerate(self.bin_mappers)
                                   if not m.is_trivial]
             if not self.used_features:
@@ -142,15 +145,18 @@ class Dataset:
             self.max_num_bins = max(
                 [self.bin_mappers[i].num_bin for i in self.used_features], default=1)
 
-        self.binned = (self._bin_data_sparse(sparse) if sparse is not None
-                       else self._bin_data(data))
+        with telem_spans.stage("setup_bin_data_seconds",
+                               "dataset/bin_data"):
+            self.binned = (self._bin_data_sparse(sparse)
+                           if sparse is not None else self._bin_data(data))
         # EFB: plan storage columns and encode the bundled matrix
         # (reference: dataset.cpp:69-225 FindGroups/FastFeatureBundling).
         # self.binned stays the logical per-feature view for generic
         # consumers; the device learner trains on the narrower bundle view.
-        self.columns = (reference.columns if reference is not None
-                        else self._plan_bundles())
-        self.bundled = self._encode_bundles() if self.columns else None
+        with telem_spans.stage("setup_bundle_seconds", "dataset/bundle"):
+            self.columns = (reference.columns if reference is not None
+                            else self._plan_bundles())
+            self.bundled = self._encode_bundles() if self.columns else None
         # raw column stats used for leaf renewal on some objectives
         self._device_cache: Dict[str, Any] = {}
 

@@ -57,6 +57,7 @@ from ..ops.fused import run_split_loop
 from ..ops.partition import decide_left
 from ..ops.pallas.histogram_kernel import build_histogram_pallas_t
 from .. import telemetry
+from ..telemetry import spans as telem_spans
 from ..telemetry import recorder as telem
 from ..utils import log
 from ..utils.log import LightGBMError
@@ -196,6 +197,7 @@ def _tree_helpers(base_mask, f_numbins, f_missing, f_default, f_monotone,
         kth = jnp.sort(u)[bynode_k - 1]
         return base_mask & (u <= kth)
 
+    @jax.named_scope("lgbm.split_scan")
     def scan(col_hist, sg, sh, cnt, mn, mx, fmask):
         if dequant is not None:
             col_hist = dequant(col_hist)
@@ -257,16 +259,20 @@ def search2_simple(scan2, best_row):
     return search2
 
 
-def split_epilogue(*, k, key, l, new_id, row, mono_f, best_cat_l,
+@jax.named_scope("lgbm.split_epilogue")
+def split_epilogue(*, k, key, l, new_id, row, feat, f_monotone,
                    leaf_min, leaf_max, depth, rec, rec_cat, best, best_cat,
                    hist_l, hist_r, search2):
     """The split bookkeeping every growth strategy shares (one copy;
     divergence here silently forks the strategies): monotone-constraint
     propagation (basic mode, serial_tree_learner.cpp:771-852), depth
     update, split-record append, and the two children's re-scan via
-    `search2` (which carries the sharded modes' election when present).
+    `search2` (which carries the sharded modes' election when present;
+    the scan itself is the `lgbm.split_scan` stage inside this one).
     Returns the updated (key, leaf_min, leaf_max, depth, rec, rec_cat,
     best, best_cat)."""
+    mono_f = f_monotone[feat]
+    best_cat_l = best_cat[l]
     mid = (row[B_LOUT] + row[B_ROUT]) * 0.5
     pmin, pmax = leaf_min[l], leaf_max[l]
     lmin = jnp.where(mono_f < 0, jnp.maximum(pmin, mid), pmin)
@@ -358,10 +364,11 @@ def grow_tree(codes_t: jax.Array,         # (C, N) column codes (EFB view)
         cat_statics=cat_statics, dequant=dequant)
 
     # ---- root ------------------------------------------------------------
-    hist0 = hist_fn(gh)
-    totals = hist0[0].sum(axis=0)                       # (3,): sum_g, sum_h, cnt
-    if quant_bits:
-        totals = dequant(totals)
+    with jax.named_scope("lgbm.root_hist"):
+        hist0 = hist_fn(gh)
+        totals = hist0[0].sum(axis=0)           # (3,): sum_g, sum_h, cnt
+        if quant_bits:
+            totals = dequant(totals)
     root_key, loop_key = jax.random.split(rng_key)
     root_res, root_cm = scan(hist0, totals[0], totals[1], totals[2],
                              jnp.float32(-np.inf), jnp.float32(np.inf),
@@ -391,39 +398,45 @@ def grow_tree(codes_t: jax.Array,         # (C, N) column codes (EFB view)
         return (c.k < L - 1) & (jnp.max(c.best[:, B_GAIN]) > 1e-10)
 
     def body(c: _Carry) -> _Carry:
-        b = c.best
-        l = jnp.argmax(b[:, B_GAIN]).astype(jnp.int32)
-        row = b[l]
-        new_id = c.k + 1
-        feat = row[B_FEAT].astype(jnp.int32)
-        thr = row[B_THR].astype(jnp.int32)
-        dleft = row[B_DLEFT] > 0.5
+        with jax.named_scope("lgbm.leaf_select"):
+            b = c.best
+            l = jnp.argmax(b[:, B_GAIN]).astype(jnp.int32)
+            row = b[l]
+            new_id = c.k + 1
+            feat = row[B_FEAT].astype(jnp.int32)
+            thr = row[B_THR].astype(jnp.int32)
+            dleft = row[B_DLEFT] > 0.5
 
-        col = jax.lax.dynamic_slice_in_dim(codes_t, f_col[feat], 1, axis=0)[0]
-        fbins = bundle_ops.logical_bins_for_feature(
-            col.astype(jnp.int32), f_base[feat], f_default[feat],
-            f_numbins[feat], f_elide[feat])
-        go_left = decide_left(fbins, thr, dleft,
-                              f_missing[feat], f_default[feat], f_numbins[feat])
-        if has_cat:
-            # categorical routing: left iff the row's logical bin is in
-            # the winning left-bin mask (CategoricalDecisionInner)
-            cmask = c.best_cat[l]
-            cat_left = cmask[jnp.clip(fbins, 0, cat_b - 1)] > 0.5
-            go_left = jnp.where(f_categorical[feat] != 0, cat_left, go_left)
-        parent = c.leaf_id == l
-        lmask = parent & go_left
-        leaf_id = jnp.where(parent & ~go_left, new_id, c.leaf_id)
+        with jax.named_scope("lgbm.go_left"):
+            col = jax.lax.dynamic_slice_in_dim(
+                codes_t, f_col[feat], 1, axis=0)[0]
+            fbins = bundle_ops.logical_bins_for_feature(
+                col.astype(jnp.int32), f_base[feat], f_default[feat],
+                f_numbins[feat], f_elide[feat])
+            go_left = decide_left(fbins, thr, dleft, f_missing[feat],
+                                  f_default[feat], f_numbins[feat])
+            if has_cat:
+                # categorical routing: left iff the row's logical bin is in
+                # the winning left-bin mask (CategoricalDecisionInner)
+                cmask = c.best_cat[l]
+                cat_left = cmask[jnp.clip(fbins, 0, cat_b - 1)] > 0.5
+                go_left = jnp.where(f_categorical[feat] != 0, cat_left,
+                                    go_left)
+        with jax.named_scope("lgbm.partition"):
+            parent = c.leaf_id == l
+            lmask = parent & go_left
+            leaf_id = jnp.where(parent & ~go_left, new_id, c.leaf_id)
 
-        ghl = gh * lmask[:, None].astype(gh.dtype)
-        hist_l = hist_fn(ghl)
-        hist_r = c.pool[l] - hist_l
-        pool = c.pool.at[l].set(hist_l).at[new_id].set(hist_r)
+        with jax.named_scope("lgbm.child_hist"):
+            ghl = gh * lmask[:, None].astype(gh.dtype)
+            hist_l = hist_fn(ghl)
+            hist_r = c.pool[l] - hist_l
+            pool = c.pool.at[l].set(hist_l).at[new_id].set(hist_r)
 
         (key, leaf_min, leaf_max, depth, rec2, rec_cat2, best2,
          best_cat2) = split_epilogue(
             k=c.k, key=c.key, l=l, new_id=new_id, row=row,
-            mono_f=f_monotone[feat], best_cat_l=c.best_cat[l],
+            feat=feat, f_monotone=f_monotone,
             leaf_min=c.leaf_min, leaf_max=c.leaf_max, depth=c.depth,
             rec=c.rec, rec_cat=c.rec_cat, best=b, best_cat=c.best_cat,
             hist_l=hist_l, hist_r=hist_r,
@@ -1006,46 +1019,48 @@ def grow_tree_compact_core(
 
     # ---- root ------------------------------------------------------------
     from ..ops.histogram import build_histogram, build_histogram_quantized
-    if quant:
-        r0_g, r0_h = q_ratios(root_max) if renew else q_ratios(None)
-        ghq0 = quant_ops.gh_operand_scaled(
-            gh_packed, w > 0, quant_bits, qcap_op, r0_g, r0_h)
-        hist0 = build_histogram_quantized(codes_row, ghq0, col_bins,
-                                          use_pallas=use_pallas)
-        if scatter:
-            # exact global int totals first (3 scalars), then the
-            # two-lane reduce-scatter with count reconstruction
-            tot_q = jax.lax.psum(hist0[0].sum(axis=0), axis_name)
-            totals = q_dequant(tot_q, r0_g, r0_h)
-            hist0 = reduce_q(hist0, totals[2], tot_q[1].astype(jnp.float32))
+    with jax.named_scope("lgbm.root_hist"):
+        if quant:
+            r0_g, r0_h = q_ratios(root_max) if renew else q_ratios(None)
+            ghq0 = quant_ops.gh_operand_scaled(
+                gh_packed, w > 0, quant_bits, qcap_op, r0_g, r0_h)
+            hist0 = build_histogram_quantized(codes_row, ghq0, col_bins,
+                                              use_pallas=use_pallas)
+            if scatter:
+                # exact global int totals first (3 scalars), then the
+                # two-lane reduce-scatter with count reconstruction
+                tot_q = jax.lax.psum(hist0[0].sum(axis=0), axis_name)
+                totals = q_dequant(tot_q, r0_g, r0_h)
+                hist0 = reduce_q(hist0, totals[2],
+                                 tot_q[1].astype(jnp.float32))
+            else:
+                if axis_name is not None:
+                    hist0 = jax.lax.psum(hist0, axis_name)
+                totals = q_dequant(hist0[0].sum(axis=0), r0_g, r0_h)
+            hist0_scan = q_dequant(hist0, r0_g, r0_h)
+        elif fp:
+            # rows are replicated: totals come straight from gh, and the
+            # root histogram is built from this shard's column slice only
+            totals = gh.sum(axis=0)
+            cr = codes_row
+            if cr.shape[1] < cs * D:
+                cr = jnp.pad(cr, ((0, 0), (0, cs * D - cr.shape[1])))
+            cr_sl = jax.lax.dynamic_slice(
+                cr, (jnp.int32(0), (shard * cs).astype(jnp.int32)), (n, cs))
+            hist0 = build_histogram(cr_sl, gh, col_bins, use_pallas=use_pallas)
         else:
-            if axis_name is not None:
-                hist0 = jax.lax.psum(hist0, axis_name)
-            totals = q_dequant(hist0[0].sum(axis=0), r0_g, r0_h)
-        hist0_scan = q_dequant(hist0, r0_g, r0_h)
-    elif fp:
-        # rows are replicated: totals come straight from gh, and the
-        # root histogram is built from this shard's column slice only
-        totals = gh.sum(axis=0)
-        cr = codes_row
-        if cr.shape[1] < cs * D:
-            cr = jnp.pad(cr, ((0, 0), (0, cs * D - cr.shape[1])))
-        cr_sl = jax.lax.dynamic_slice(
-            cr, (jnp.int32(0), (shard * cs).astype(jnp.int32)), (n, cs))
-        hist0 = build_histogram(cr_sl, gh, col_bins, use_pallas=use_pallas)
-    else:
-        hist0 = build_histogram(codes_row, gh, col_bins,
-                                use_pallas=use_pallas)
-        if scatter or voting:
-            # global totals first (the post-reduce histogram is a column
-            # slice / stays local), then reduce per mode
-            totals = jax.lax.psum(hist0[0].sum(axis=0), axis_name)
-            hist0 = reduce_hist(hist0)
-        else:
-            hist0 = reduce_hist(hist0)
-            totals = hist0[0].sum(axis=0)
-    if not quant:
-        hist0_scan = hist0
+            hist0 = build_histogram(codes_row, gh, col_bins,
+                                    use_pallas=use_pallas)
+            if scatter or voting:
+                # global totals first (the post-reduce histogram is a column
+                # slice / stays local), then reduce per mode
+                totals = jax.lax.psum(hist0[0].sum(axis=0), axis_name)
+                hist0 = reduce_hist(hist0)
+            else:
+                hist0 = reduce_hist(hist0)
+                totals = hist0[0].sum(axis=0)
+        if not quant:
+            hist0_scan = hist0
     pool_c = hist0.shape[0]
     root_key, loop_key = jax.random.split(rng_key)
     row0, cm0 = search_row(hist0_scan, totals[0], totals[1], totals[2],
@@ -1092,29 +1107,35 @@ def grow_tree_compact_core(
             begin = c.leaf_begin[l]
             pcount = c.leaf_phys[l]
 
-            win = jax.lax.dynamic_slice(c.data, (begin, 0), (wsz, d_cols))
-            valid = jnp.arange(wsz, dtype=jnp.int32) < pcount
-            go_left = packed_go_left(
-                win, feat, row[B_THR].astype(jnp.int32),
-                row[B_DLEFT] > 0.5, f_numbins, f_missing, f_default,
-                f_col, f_base, f_elide, item_bits=item_bits,
-                f_categorical=f_categorical if has_cat else None,
-                cat_mask=c.best_cat[l] if has_cat else None) & valid
-            if renew:
-                # each child's stored-int maxes seed its leaf-local
-                # requant ratio (measured here: the window is in hand)
-                qmax2 = _quant_side_maxes(win, go_left, valid, cw=cw, gw=gw)
+            with jax.named_scope("lgbm.partition"):
+                win = jax.lax.dynamic_slice(c.data, (begin, 0), (wsz, d_cols))
+                valid = jnp.arange(wsz, dtype=jnp.int32) < pcount
+            with jax.named_scope("lgbm.go_left"):
+                go_left = packed_go_left(
+                    win, feat, row[B_THR].astype(jnp.int32),
+                    row[B_DLEFT] > 0.5, f_numbins, f_missing, f_default,
+                    f_col, f_base, f_elide, item_bits=item_bits,
+                    f_categorical=f_categorical if has_cat else None,
+                    cat_mask=c.best_cat[l] if has_cat else None) & valid
+                if renew:
+                    # each child's stored-int maxes seed its leaf-local
+                    # requant ratio (measured here: the window is in hand)
+                    qmax2 = _quant_side_maxes(win, go_left, valid,
+                                              cw=cw, gw=gw)
 
             # stable partition of the window (reference DataPartition::
             # Split): overrun rows past pcount get key 2; the full 3-way
             # compaction is identity on them (they are already tail-
             # contiguous), so they return to their slots untouched
-            key3 = jnp.where(valid, jnp.where(go_left, 0, 1), 2)
-            win_sorted = partition_window(win, key3, partition)
-            data = jax.lax.dynamic_update_slice(c.data, win_sorted,
-                                                (begin, 0))
-            lphys = jnp.sum(go_left.astype(jnp.int32))
-            rphys = pcount - lphys
+            with jax.named_scope("lgbm.partition"):
+                key3 = jnp.where(valid, jnp.where(go_left, 0, 1), 2)
+                win_sorted = partition_window(win, key3, partition)
+            with jax.named_scope("lgbm.table_update"):
+                data = jax.lax.dynamic_update_slice(c.data, win_sorted,
+                                                    (begin, 0))
+            with jax.named_scope("lgbm.partition"):
+                lphys = jnp.sum(go_left.astype(jnp.int32))
+                rphys = pcount - lphys
             # pos_leaf / leaf_begin / leaf_phys updates happen OUTSIDE the
             # switch (the body computes them from lphys): fewer branch
             # outputs means fewer carry buffers crossing the conditional
@@ -1129,70 +1150,71 @@ def grow_tree_compact_core(
             # contiguous half window; fallback (possible only when local
             # physical share is skewed vs the global choice under
             # bagging/sharding): masked pass over the full window.
-            left_small = row[B_LCNT] <= row[B_RCNT]
-            s_begin = jnp.where(left_small, 0, lphys)
-            s_count = jnp.where(left_small, lphys, rphys)
-            hist_dtype = jnp.int32 if quant else jnp.float32
+            with jax.named_scope("lgbm.child_hist"):
+                left_small = row[B_LCNT] <= row[B_RCNT]
+                s_begin = jnp.where(left_small, 0, lphys)
+                s_count = jnp.where(left_small, lphys, rphys)
+                hist_dtype = jnp.int32 if quant else jnp.float32
 
-            def win_hist(rows2d, vbool):
-                """Histogram of a row window restricted to `vbool` rows —
-                the one layout dispatch (float triple vs packed int)."""
-                s_codes = decode_for_hist(rows2d[:, :cw])
-                if quant:
-                    ghq = _quant_win_operand(
-                        rows2d, vbool, cw=cw, gw=gw, quant_bits=quant_bits,
-                        qcap_op=qcap_op, r_g=rq_g, r_h=rq_h)
-                    return build_histogram_quantized(
-                        s_codes, ghq, col_bins, use_pallas=use_pallas)
-                s_gh = jax.lax.bitcast_convert_type(
-                    rows2d[:, cw:cw + 3], jnp.float32) \
-                    * vbool.astype(jnp.float32)[:, None]
-                return build_histogram(s_codes, s_gh, col_bins,
-                                       use_pallas=use_pallas)
+                def win_hist(rows2d, vbool):
+                    """Histogram of a row window restricted to `vbool` rows —
+                    the one layout dispatch (float triple vs packed int)."""
+                    s_codes = decode_for_hist(rows2d[:, :cw])
+                    if quant:
+                        ghq = _quant_win_operand(
+                            rows2d, vbool, cw=cw, gw=gw, quant_bits=quant_bits,
+                            qcap_op=qcap_op, r_g=rq_g, r_h=rq_h)
+                        return build_histogram_quantized(
+                            s_codes, ghq, col_bins, use_pallas=use_pallas)
+                    s_gh = jax.lax.bitcast_convert_type(
+                        rows2d[:, cw:cw + 3], jnp.float32) \
+                        * vbool.astype(jnp.float32)[:, None]
+                    return build_histogram(s_codes, s_gh, col_bins,
+                                           use_pallas=use_pallas)
 
-            def hist_half(_):
-                start = jnp.clip(s_begin, 0, wsz - half)
-                off = s_begin - start
-                sw = jax.lax.dynamic_slice(win_sorted, (start, 0),
-                                           (half, d_cols))
-                j = jnp.arange(half, dtype=jnp.int32)
-                return win_hist(sw, (j >= off) & (j < off + s_count))
+                def hist_half(_):
+                    start = jnp.clip(s_begin, 0, wsz - half)
+                    off = s_begin - start
+                    sw = jax.lax.dynamic_slice(win_sorted, (start, 0),
+                                               (half, d_cols))
+                    j = jnp.arange(half, dtype=jnp.int32)
+                    return win_hist(sw, (j >= off) & (j < off + s_count))
 
-            def hist_range(range_begin, range_count):
-                # masked full-window pass over [range_begin,
-                # range_begin + range_count)
-                j = jnp.arange(wsz, dtype=jnp.int32)
-                return win_hist(win_sorted,
-                                (j >= range_begin)
-                                & (j < range_begin + range_count))
+                def hist_range(range_begin, range_count):
+                    # masked full-window pass over [range_begin,
+                    # range_begin + range_count)
+                    j = jnp.arange(wsz, dtype=jnp.int32)
+                    return win_hist(win_sorted,
+                                    (j >= range_begin)
+                                    & (j < range_begin + range_count))
 
-            if trivial_weights and axis_name is None:
-                # all-ones weights single-chip: record counts equal
-                # physical counts, so the smaller side always fits the
-                # contiguous half window — the masked full-window
-                # fallback (and its extra compiled histogram program
-                # per window class) is statically dead
-                hist_small = hist_half(None)
-            else:
-                hist_small = jax.lax.cond(
-                    s_count <= half, hist_half,
-                    lambda _: hist_range(s_begin, s_count), operand=None)
+                if trivial_weights and axis_name is None:
+                    # all-ones weights single-chip: record counts equal
+                    # physical counts, so the smaller side always fits the
+                    # contiguous half window — the masked full-window
+                    # fallback (and its extra compiled histogram program
+                    # per window class) is statically dead
+                    hist_small = hist_half(None)
+                else:
+                    hist_small = jax.lax.cond(
+                        s_count <= half, hist_half,
+                        lambda _: hist_range(s_begin, s_count), operand=None)
 
-            # pooled mode, parent-histogram miss: the sibling cannot come
-            # from subtraction, so build the LARGER child's histogram
-            # directly with a masked pass over the window (reference
-            # HistogramPool miss -> ConstructHistograms re-run)
-            if pooled:
-                o_begin = jnp.where(left_small, lphys, 0)
-                o_count = pcount - s_count
-                hist_other = jax.lax.cond(
-                    need_other, lambda _: hist_range(o_begin, o_count),
-                    lambda _: jnp.zeros((hist_cols, col_bins, 3),
-                                        hist_dtype),
-                    operand=None)
-            else:
-                hist_other = jnp.zeros((hist_cols, col_bins, 3),
-                                       hist_dtype)
+                # pooled mode, parent-histogram miss: the sibling cannot come
+                # from subtraction, so build the LARGER child's histogram
+                # directly with a masked pass over the window (reference
+                # HistogramPool miss -> ConstructHistograms re-run)
+                if pooled:
+                    o_begin = jnp.where(left_small, lphys, 0)
+                    o_count = pcount - s_count
+                    hist_other = jax.lax.cond(
+                        need_other, lambda _: hist_range(o_begin, o_count),
+                        lambda _: jnp.zeros((hist_cols, col_bins, 3),
+                                            hist_dtype),
+                        operand=None)
+                else:
+                    hist_other = jnp.zeros((hist_cols, col_bins, 3),
+                                           hist_dtype)
             out = (data, lphys, hist_small, hist_other)
             return out + (qmax2,) if renew else out
         return branch
@@ -1200,124 +1222,132 @@ def grow_tree_compact_core(
     branches = [make_branch(wsz) for wsz in classes]
 
     def body(c: _CarryC, qx=None):
-        b = c.best
-        l = jnp.argmax(b[:, B_GAIN]).astype(jnp.int32)
-        row = b[l]
-        new_id = c.k + 1
-        feat = row[B_FEAT].astype(jnp.int32)
-        pcount = c.leaf_phys[l]
-        slot_l = c.slot_of[l]
-        have_parent = slot_l >= 0
-        j = jnp.sum((pcount > thresholds).astype(jnp.int32))
+        with jax.named_scope("lgbm.leaf_select"):
+            b = c.best
+            l = jnp.argmax(b[:, B_GAIN]).astype(jnp.int32)
+            row = b[l]
+            new_id = c.k + 1
+            feat = row[B_FEAT].astype(jnp.int32)
+            pcount = c.leaf_phys[l]
+            slot_l = c.slot_of[l]
+            have_parent = slot_l >= 0
+            j = jnp.sum((pcount > thresholds).astype(jnp.int32))
+        # the dispatch over the window ladder belongs to `leaf_select`
+        # (which leaf, which rung); each branch names its own stages
         if renew:
             # the leaf's operand ratio comes from maxes recorded at its
             # CREATION (replicated), so the branch needs no collective
             scale_of, leafmax = qx
             rq_g, rq_h = q_ratios(leafmax[l])
-            data, lphys, hist_small, hist_other, qmax2 = jax.lax.switch(
-                j, branches,
-                (c, l, row, new_id, ~have_parent, (rq_g, rq_h)))
+            with jax.named_scope("lgbm.leaf_select"):
+                data, lphys, hist_small, hist_other, qmax2 = \
+                    jax.lax.switch(
+                        j, branches,
+                        (c, l, row, new_id, ~have_parent, (rq_g, rq_h)))
             if axis_name is not None:
                 qmax2 = jax.lax.pmax(qmax2, axis_name)
         else:
             rq_g = rq_h = jnp.float32(1.0)
-            data, lphys, hist_small, hist_other = jax.lax.switch(
-                j, branches, (c, l, row, new_id, ~have_parent))
-        begin = c.leaf_begin[l]
-        rphys = pcount - lphys
-        leaf_begin = c.leaf_begin.at[new_id].set(begin + lphys)
-        leaf_phys = c.leaf_phys.at[l].set(lphys).at[new_id].set(rphys)
-        # O(N) elementwise pos_leaf rewrite (fuses to one in-place pass;
-        # cheaper than carrying the update through the conditional)
-        posv = jnp.arange(n + wmax, dtype=jnp.int32)
-        pos_leaf = jnp.where(
-            (posv >= begin) & (posv < begin + lphys), l,
-            jnp.where((posv >= begin + lphys) & (posv < begin + pcount),
-                      new_id, c.pos_leaf))
-        left_small = row[B_LCNT] <= row[B_RCNT]
-        if axis_name is not None:
-            # cross-shard histogram reduction: psum replicates (dense
-            # equivalent of the reference's reduce-scatter, scan runs
-            # identically everywhere); scatter mode IS the reference's
-            # pattern (each shard owns its column tile). The miss-path
-            # histogram reduces alongside so no shard ever takes a
-            # collective the others skip.
-            if quant and scatter:
-                # two integer lanes on the wire; counts reconstructed
-                # from the hessian lane + the replicated global count
-                s_cnt_g = jnp.where(left_small, row[B_LCNT], row[B_RCNT])
-                s_qh_g = jnp.where(left_small, row[B_LSH], row[B_RSH]) \
-                    * (q_sh * rq_h)
-                hist_small = reduce_q(hist_small, s_cnt_g, s_qh_g)
-                if pooled:
-                    o_cnt_g = row[B_LCNT] + row[B_RCNT] - s_cnt_g
-                    o_qh_g = (row[B_LSH] + row[B_RSH]) * (q_sh * rq_h) \
-                        - s_qh_g
-                    hist_other = reduce_q(hist_other, o_cnt_g, o_qh_g)
+            with jax.named_scope("lgbm.leaf_select"):
+                data, lphys, hist_small, hist_other = jax.lax.switch(
+                    j, branches, (c, l, row, new_id, ~have_parent))
+        with jax.named_scope("lgbm.table_update"):
+            begin = c.leaf_begin[l]
+            rphys = pcount - lphys
+            leaf_begin = c.leaf_begin.at[new_id].set(begin + lphys)
+            leaf_phys = c.leaf_phys.at[l].set(lphys).at[new_id].set(rphys)
+            # O(N) elementwise pos_leaf rewrite (fuses to one in-place pass;
+            # cheaper than carrying the update through the conditional)
+            posv = jnp.arange(n + wmax, dtype=jnp.int32)
+            pos_leaf = jnp.where(
+                (posv >= begin) & (posv < begin + lphys), l,
+                jnp.where((posv >= begin + lphys) & (posv < begin + pcount),
+                          new_id, c.pos_leaf))
+        with jax.named_scope("lgbm.child_hist"):
+            left_small = row[B_LCNT] <= row[B_RCNT]
+            if axis_name is not None:
+                # cross-shard histogram reduction: psum replicates (dense
+                # equivalent of the reference's reduce-scatter, scan runs
+                # identically everywhere); scatter mode IS the reference's
+                # pattern (each shard owns its column tile). The miss-path
+                # histogram reduces alongside so no shard ever takes a
+                # collective the others skip.
+                if quant and scatter:
+                    # two integer lanes on the wire; counts reconstructed
+                    # from the hessian lane + the replicated global count
+                    s_cnt_g = jnp.where(left_small, row[B_LCNT], row[B_RCNT])
+                    s_qh_g = jnp.where(left_small, row[B_LSH], row[B_RSH]) \
+                        * (q_sh * rq_h)
+                    hist_small = reduce_q(hist_small, s_cnt_g, s_qh_g)
+                    if pooled:
+                        o_cnt_g = row[B_LCNT] + row[B_RCNT] - s_cnt_g
+                        o_qh_g = (row[B_LSH] + row[B_RSH]) * (q_sh * rq_h) \
+                            - s_qh_g
+                        hist_other = reduce_q(hist_other, o_cnt_g, o_qh_g)
+                else:
+                    hist_small = reduce_hist(hist_small)
+                    if pooled:
+                        hist_other = reduce_hist(hist_other)
+
+            parent = (c.pool[jnp.clip(slot_l, 0, K - 1)] if pooled
+                      else c.pool[l])
+            if renew:
+                # re-express the parent pool entry in the split's ratio
+                # before subtraction (counts pass through exact)
+                parent = quant_ops.rescale_histogram(
+                    parent, rq_g / scale_of[l, 0], rq_h / scale_of[l, 1])
+            sibling = jnp.where(have_parent, parent - hist_small, hist_other) \
+                if pooled else parent - hist_small
+            hist_l = jnp.where(left_small, hist_small, sibling)
+            hist_r = jnp.where(left_small, sibling, hist_small)
+
+            # pool slot bookkeeping: l reuses its parent slot when cached,
+            # otherwise allocates; new_id always allocates. Allocation takes
+            # a free slot first, else evicts the least-recently-used (the
+            # reference HistogramPool's Get/Move semantics).
+            step = new_id
+            if pooled:
+                iarangeK = jnp.arange(K, dtype=jnp.int32)
+
+                def alloc(slot_of, slot_owner, slot_last, forbid, want):
+                    score = jnp.where(slot_owner < 0, jnp.int32(-1), slot_last)
+                    score = jnp.where(iarangeK == forbid,
+                                      jnp.iinfo(jnp.int32).max, score)
+                    s = jnp.argmin(score).astype(jnp.int32)
+                    old = slot_owner[s]
+                    safe_old = jnp.clip(old, 0, L - 1)
+                    slot_of = slot_of.at[safe_old].set(
+                        jnp.where(want & (old >= 0), -1, slot_of[safe_old]))
+                    return s, slot_of
+
+                s_l_new, slot_of = alloc(c.slot_of, c.slot_owner, c.slot_last,
+                                         jnp.int32(-1), ~have_parent)
+                s_l = jnp.where(have_parent, slot_l, s_l_new)
+                slot_of = slot_of.at[l].set(s_l)
+                slot_owner = c.slot_owner.at[s_l].set(l)
+                slot_last = c.slot_last.at[s_l].set(step)
+                s_r, slot_of = alloc(slot_of, slot_owner, slot_last, s_l,
+                                     jnp.bool_(True))
+                slot_of = slot_of.at[new_id].set(s_r)
+                slot_owner = slot_owner.at[s_r].set(new_id)
+                slot_last = slot_last.at[s_r].set(step)
             else:
-                hist_small = reduce_hist(hist_small)
-                if pooled:
-                    hist_other = reduce_hist(hist_other)
+                s_l, s_r = l, new_id
+                slot_of = c.slot_of
+                slot_owner, slot_last = c.slot_owner, c.slot_last
+            pool = c.pool.at[s_l].set(hist_l).at[s_r].set(hist_r)
 
-        parent = (c.pool[jnp.clip(slot_l, 0, K - 1)] if pooled
-                  else c.pool[l])
-        if renew:
-            # re-express the parent pool entry in the split's ratio
-            # before subtraction (counts pass through exact)
-            parent = quant_ops.rescale_histogram(
-                parent, rq_g / scale_of[l, 0], rq_h / scale_of[l, 1])
-        sibling = jnp.where(have_parent, parent - hist_small, hist_other) \
-            if pooled else parent - hist_small
-        hist_l = jnp.where(left_small, hist_small, sibling)
-        hist_r = jnp.where(left_small, sibling, hist_small)
-
-        # pool slot bookkeeping: l reuses its parent slot when cached,
-        # otherwise allocates; new_id always allocates. Allocation takes
-        # a free slot first, else evicts the least-recently-used (the
-        # reference HistogramPool's Get/Move semantics).
-        step = new_id
-        if pooled:
-            iarangeK = jnp.arange(K, dtype=jnp.int32)
-
-            def alloc(slot_of, slot_owner, slot_last, forbid, want):
-                score = jnp.where(slot_owner < 0, jnp.int32(-1), slot_last)
-                score = jnp.where(iarangeK == forbid,
-                                  jnp.iinfo(jnp.int32).max, score)
-                s = jnp.argmin(score).astype(jnp.int32)
-                old = slot_owner[s]
-                safe_old = jnp.clip(old, 0, L - 1)
-                slot_of = slot_of.at[safe_old].set(
-                    jnp.where(want & (old >= 0), -1, slot_of[safe_old]))
-                return s, slot_of
-
-            s_l_new, slot_of = alloc(c.slot_of, c.slot_owner, c.slot_last,
-                                     jnp.int32(-1), ~have_parent)
-            s_l = jnp.where(have_parent, slot_l, s_l_new)
-            slot_of = slot_of.at[l].set(s_l)
-            slot_owner = c.slot_owner.at[s_l].set(l)
-            slot_last = c.slot_last.at[s_l].set(step)
-            s_r, slot_of = alloc(slot_of, slot_owner, slot_last, s_l,
-                                 jnp.bool_(True))
-            slot_of = slot_of.at[new_id].set(s_r)
-            slot_owner = slot_owner.at[s_r].set(new_id)
-            slot_last = slot_last.at[s_r].set(step)
-        else:
-            s_l, s_r = l, new_id
-            slot_of = c.slot_of
-            slot_owner, slot_last = c.slot_owner, c.slot_last
-        pool = c.pool.at[s_l].set(hist_l).at[s_r].set(hist_r)
-
-        if quant:
-            # scans read f32: dequantize the children at the split's
-            # leaf-local scale (the pool keeps the exact integers)
-            hist_l_s = q_dequant(hist_l, rq_g, rq_h)
-            hist_r_s = q_dequant(hist_r, rq_g, rq_h)
-        else:
-            hist_l_s, hist_r_s = hist_l, hist_r
+            if quant:
+                # scans read f32: dequantize the children at the split's
+                # leaf-local scale (the pool keeps the exact integers)
+                hist_l_s = q_dequant(hist_l, rq_g, rq_h)
+                hist_r_s = q_dequant(hist_r, rq_g, rq_h)
+            else:
+                hist_l_s, hist_r_s = hist_l, hist_r
         (key, leaf_min, leaf_max, depth, rec2, rec_cat2, best2,
          best_cat2) = split_epilogue(
             k=c.k, key=c.key, l=l, new_id=new_id, row=row,
-            mono_f=f_monotone[feat], best_cat_l=c.best_cat[l],
+            feat=feat, f_monotone=f_monotone,
             leaf_min=c.leaf_min, leaf_max=c.leaf_max, depth=c.depth,
             rec=c.rec, rec_cat=c.rec_cat, best=b, best_cat=c.best_cat,
             hist_l=hist_l_s, hist_r=hist_r_s, search2=search2_rows)
@@ -1782,6 +1812,7 @@ def grow_tree_chunk_core(
         hist_zero = jnp.zeros((acc_w, col_bins, 3),
                               jnp.int32 if quant else jnp.float32)
 
+        @jax.named_scope("lgbm.child_hist")
         def chunk_hist(rows_win, count):
             codes = decode_hist_cols(rows_win[:, :cw])
             if quant:
@@ -1925,7 +1956,7 @@ def grow_tree_chunk_core(
         (key, leaf_min, leaf_max, depth, rec2, rec_cat2, best2,
          best_cat2) = split_epilogue(
             k=c.k, key=c.key, l=l, new_id=new_id, row=row,
-            mono_f=f_monotone[feat], best_cat_l=c.best_cat[l],
+            feat=feat, f_monotone=f_monotone,
             leaf_min=c.leaf_min, leaf_max=c.leaf_max, depth=c.depth,
             rec=c.rec, rec_cat=c.rec_cat, best=b, best_cat=c.best_cat,
             hist_l=hist_l_s, hist_r=hist_r_s, search2=search2)
@@ -2050,6 +2081,7 @@ def make_sliced_search(*, axis_name, fp, D, c_cols, col_bins, item_bits,
     return reduce_hist, search_row, search2_rows, cs, shard, start
 
 
+@jax.named_scope("lgbm.partition")
 def partition_window(win: jax.Array, key3: jax.Array,
                      partition: str) -> jax.Array:
     """Stable 3-way reorder of a (W, D) u32 window by key3 in {0,1,2} —
@@ -2074,6 +2106,7 @@ def partition_window(win: jax.Array, key3: jax.Array,
     return jnp.take(win, order, axis=0)
 
 
+@jax.named_scope("lgbm.go_left")
 def packed_go_left(win: jax.Array, feat, thr, dleft,
                    f_numbins, f_missing, f_default, f_col, f_base, f_elide,
                    *, item_bits: int, f_categorical=None,
@@ -2101,6 +2134,33 @@ def packed_go_left(win: jax.Array, feat, thr, dleft,
         return num_left
     cat_left = cat_mask[jnp.clip(fbins, 0, cat_mask.shape[0] - 1)] > 0.5
     return jnp.where(f_categorical[feat] != 0, cat_left, num_left)
+
+
+def fused_step_surface(step_impl, make_args, obj_keys):
+    """What every `make_fused_step` returns: `step(score_row, base_mask,
+    tree_key, bag_key, shrinkage)` runs the jitted `step_impl` on the
+    arguments `make_args` builds at CALL time (so a rebuilt code buffer
+    is never silently shadowed by a stale snapshot). `step.impl` and
+    `step.obj_keys` are the contract surface for tests and tools
+    (program-size pinning). On the same five arguments, `step.lower(...)`
+    lowers the program and `step.stage_map(...)` compiles it too and
+    maps its instructions to the `lgbm.<stage>` scopes
+    (telemetry.stage_map) — for a reader of a device trace, never on the
+    hot path. A warm persistent compile cache hands back the executable
+    as it was compiled, so a map from a cache entry older than the
+    scopes comes back empty."""
+    def step(*args):
+        return step_impl(*make_args(*args))
+
+    def lower(*args):
+        return step_impl.lower(*make_args(*args))
+
+    step.impl = step_impl
+    step.obj_keys = obj_keys
+    step.lower = lower
+    step.stage_map = lambda *args: telemetry.stage_map(
+        lower(*args).compile().as_text())
+    return step
 
 
 def objective_buffer_names(objective):
@@ -2300,6 +2360,12 @@ class DeviceTreeLearner:
 
     def __init__(self, config: Config, dataset: Dataset,
                  strategy: Optional[str] = None, device_place: bool = True):
+        with telem_spans.stage("setup_learner_build_seconds",
+                               "learner_build"):
+            self._build(config, dataset, strategy, device_place)
+
+    def _build(self, config: Config, dataset: Dataset,
+               strategy: Optional[str], device_place: bool) -> None:
         # device_place=False keeps the compact buffers host-side so a
         # sharding subclass can place them itself without a device
         # round-trip (DeviceDataParallelTreeLearner)
@@ -2631,7 +2697,7 @@ class DeviceTreeLearner:
 
         self.last_leaf_id = leaf_id
         self._leaf_id_host = None
-        with telem.phase("host_sync"):
+        with telem.phase("record_fetch"):
             if rec_cat is None:
                 rec_h, k = jax.device_get((rec, n_splits))
                 rec_cat_h = None
@@ -2724,7 +2790,7 @@ class DeviceTreeLearner:
         self._batched_leaf_ids = leaf_ids
         self.last_leaf_id = None
         self._leaf_id_host = None
-        with telem.phase("host_sync"):
+        with telem.phase("record_fetch"):
             if rec_cat is None:
                 rec_h, ks = jax.device_get((rec, n_splits))
                 rec_cat_h = None
@@ -3149,6 +3215,10 @@ class DeviceTreeLearner:
 
         obj_keys = objective_buffer_names(objective)
 
+        # `step_impl` is a name the benchmark reads: it finds the tree
+        # program in a device trace by the module `jit_step_impl`
+        # (benchmark/trace_reduce.py MODULE_HINT; tests/test_trace_spans.py
+        # pins it)
         @jax.jit
         def step_impl(codes_pack, codes_row, obj_bufs, score_row,
                       base_mask, tree_key, bag_key, shrinkage):
@@ -3162,7 +3232,8 @@ class DeviceTreeLearner:
             # cache on the dataset bytes instead of just shapes. Masked
             # strategy passes (codes_t, codes_t).
             # tests/test_program_size.py pins the property.
-            with swapped_attrs(objective, obj_keys, obj_bufs):
+            with swapped_attrs(objective, obj_keys, obj_bufs), \
+                    jax.named_scope("lgbm.gradients"):
                 g, h = objective.get_gradients(score_row)
             bag_idx = oob_idx = None
             if goss is not None:
@@ -3208,31 +3279,26 @@ class DeviceTreeLearner:
             # score on a no-split iteration, so the pipelined caller
             # (gbdt._train_one_iter_fused) can commit it before k is
             # fetched and still match the reference's stop semantics.
-            lv = leaf_values_from_rec(rec, k, L)
-            delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) * shrinkage
-            delta = jnp.where(k > 0, delta, jnp.zeros_like(delta))
-            new_score = score_row + delta
-            # in-program non-finite sentry: any NaN/inf gradient or leaf
-            # output propagates into the updated score, so one reduction
-            # INSIDE the program covers the whole fused iteration and a
-            # guarded run adds zero extra dispatches
-            finite = jnp.all(jnp.isfinite(new_score))
+            with jax.named_scope("lgbm.score_update"):
+                lv = leaf_values_from_rec(rec, k, L)
+                delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) \
+                    * shrinkage
+                delta = jnp.where(k > 0, delta, jnp.zeros_like(delta))
+                new_score = score_row + delta
+                # in-program non-finite sentry: any NaN/inf gradient or
+                # leaf output propagates into the updated score, so one
+                # reduction INSIDE the program covers the whole fused
+                # iteration and a guarded run adds zero extra dispatches
+                finite = jnp.all(jnp.isfinite(new_score))
             return new_score, rec, rec_cat, leaf_id, k, finite
 
-        def step(score_row, base_mask, tree_key, bag_key, shrinkage):
-            # read self.codes_* at CALL time like the DP/FP wrappers, so
-            # a rebuilt code buffer is never silently shadowed by a
-            # stale snapshot
+        def make_args(*step_args):
             codes_args = ((self.codes_pack, self.codes_row) if use_compact
                           else (self.codes_t, self.codes_t))
             obj_bufs = tuple(getattr(objective, k) for k in obj_keys)
-            return step_impl(*codes_args, obj_bufs, score_row, base_mask,
-                             tree_key, bag_key, shrinkage)
+            return (*codes_args, obj_bufs, *step_args)
 
-        # contract surface for tests/tools (program-size pinning)
-        step.impl = step_impl
-        step.obj_keys = obj_keys
-        return step
+        return fused_step_surface(step_impl, make_args, obj_keys)
 
     # ------------------------------------------------------------------
     def leaf_rows(self, leaf: int) -> np.ndarray:

@@ -239,25 +239,34 @@ class GBDT:
         True when the iteration found no split (k == 0)."""
         rec, rec_cat, leaf_id, k_dev, _score_before, init_score, it, \
             shrinkage = pend
-        if rec_cat is None:
-            rec_h, k = jax.device_get((rec, k_dev))
-            rec_cat_h = None
-        else:
-            rec_h, rec_cat_h, k = jax.device_get((rec, rec_cat, k_dev))
+        # the blocking fetch of this tree's records; everything after it
+        # is host work. Synchronous path: the wait for the tree program.
+        # Pipelined path: the program it would wait for ended while the
+        # host sat in `mask_sync` (see _train_one_iter_fused), so this
+        # reads under a millisecond there
+        with telem.phase("record_fetch"):
+            if rec_cat is None:
+                rec_h, k = jax.device_get((rec, k_dev))
+                rec_cat_h = None
+            else:
+                rec_h, rec_cat_h, k = jax.device_get((rec, rec_cat, k_dev))
         k = int(k)
         if k == 0:
             return True
-        tree = self.learner.replay_tree(rec_h, k, rec_cat_h)
-        tree.apply_shrinkage(shrinkage)
-        if abs(init_score) > K_EPSILON:
-            tree.add_bias(init_score)
+        with telem.phase("tree_replay"):
+            tree = self.learner.replay_tree(rec_h, k, rec_cat_h)
+            tree.apply_shrinkage(shrinkage)
+            if abs(init_score) > K_EPSILON:
+                tree.add_bias(init_score)
         self.learner.last_leaf_id = leaf_id
         self.learner._leaf_id_host = None
         self.learner._bag_mask_host = None
         self._last_leaf_ids[0] = leaf_id
         self._last_leaf_ids_iter = it
-        for vu in self.valid_updaters:
-            vu.add_tree(tree, 0)
+        if self.valid_updaters:
+            with telem.phase("valid_update"):
+                for vu in self.valid_updaters:
+                    vu.add_tree(tree, 0)
         self._models.append(tree)
         return False
 
@@ -515,17 +524,27 @@ class GBDT:
             self._fused_step = {}
         fkey = goss_params is not None
         if fkey not in self._fused_step:
-            self._fused_step[fkey] = self.learner.make_fused_step(
-                self.objective, goss=goss_params, bagging=bagging)
+            with telem.phase("fused_step_build"):
+                self._fused_step[fkey] = self.learner.make_fused_step(
+                    self.objective, goss=goss_params, bagging=bagging)
         fused_step = self._fused_step[fkey]
-        rng = np.random.RandomState(
-            (cfg.feature_fraction_seed + self.iter) % (2**31 - 1))
-        fmask = self.learner._feature_mask(rng)
-        if not getattr(self.learner, "cat_in_program", False):
-            # learners without in-program categorical splitting (the
-            # parallel device learners) must not sample cat features
-            fmask = fmask & np.asarray(self.learner.f_categorical == 0)
-        base_mask = jnp.asarray(fmask)
+        with telem.phase("feature_mask"):     # host work only
+            rng = np.random.RandomState(
+                (cfg.feature_fraction_seed + self.iter) % (2**31 - 1))
+            fmask = self.learner._feature_mask(rng)
+        # the mask's device round trip. On the pipelined path this is
+        # where the host waits for the device: the compare and the
+        # upload queue behind the tree program still running, so the
+        # phase lasts about as long as that program (measured on the
+        # chip, PERF.md §5) — a blocking phase, not host work
+        with telem.phase("mask_sync"):
+            if not getattr(self.learner, "cat_in_program", False):
+                # learners without in-program categorical splitting (the
+                # parallel device learners, and any learner whose table
+                # has no categorical feature) must not sample cat
+                # features: a compare on the device, read back
+                fmask = fmask & np.asarray(self.learner.f_categorical == 0)
+            base_mask = jnp.asarray(fmask)       # an H2D a tree
         tree_key = jax.random.PRNGKey(self.iter)
         # same bag key for bagging_freq consecutive iterations == reference
         # re-bags only on iter % freq == 0 and reuses the bag otherwise;
@@ -575,9 +594,8 @@ class GBDT:
                 prev = self._pending_fused
                 self._pending_fused = pend
             self.iter += 1
-            with telem.phase("host_sync"):
-                prev_stopped = (prev is not None
-                                and self._materialize_one(prev))
+            prev_stopped = (prev is not None
+                            and self._materialize_one(prev))
             if prev_stopped:
                 # the PREVIOUS iteration found no split, so training
                 # should already have stopped there. Its score delta was
@@ -596,8 +614,7 @@ class GBDT:
                 return self._train_one_iter_generic()
             return False
 
-        with telem.phase("host_sync"):
-            stopped = self._materialize_one(pend)
+        stopped = self._materialize_one(pend)
         if stopped:
             # delegate the stop bookkeeping (constant init-score tree on a
             # first-iteration stop, warning, model trimming) to the generic

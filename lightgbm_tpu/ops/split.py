@@ -85,6 +85,7 @@ def _split_gains(gl, hl, gr, hr, l1, l2, mds, min_c, max_c, mono):
     return jnp.where(violate, 0.0, gain)
 
 
+@jax.named_scope("lgbm.split_scan")
 def per_feature_best(
     hist: jax.Array,            # (F, B, 3) f32 [sum_grad, sum_hess, count]
     sum_grad: jax.Array,        # scalar: leaf total gradient
@@ -205,6 +206,7 @@ def per_feature_best(
     return per_feature_rel, per_feature_t, use_m1, prefix
 
 
+@jax.named_scope("lgbm.split_scan")
 def materialize_split(feat, per_feature_rel, per_feature_t, use_m1, prefix,
                       sum_grad, sum_hess, num_data,
                       min_constraint, max_constraint,
@@ -305,6 +307,7 @@ class CatSplitResult(NamedTuple):
     right_output: jax.Array
 
 
+@jax.named_scope("lgbm.split_scan")
 def per_feature_best_categorical(
     hist: jax.Array, sum_grad: jax.Array, sum_hess: jax.Array,
     num_data: jax.Array, feature_num_bins: jax.Array,
@@ -423,6 +426,7 @@ def per_feature_best_categorical(
     return rel, aux
 
 
+@jax.named_scope("lgbm.split_scan")
 def materialize_cat_split(feat, rel, aux, hist,
                           sum_grad, sum_hess, num_data,
                           min_constraint, max_constraint,

@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import Config
 from ..io.dataset import Dataset
 from ..models.device_learner import (DeviceTreeLearner,
+                                     fused_step_surface,
                                      objective_buffer_names,
                                      padded_shard_cols, swapped_attrs)
 from ..models.serial_learner import SerialTreeLearner, _bucket, _MIN_BUCKET
@@ -342,9 +343,7 @@ class DataParallelTreeLearner(SerialTreeLearner):
             lanes = 2 if self._quant_bits else 3
             telem_counters.incr("dist_reduce_scatter_bytes",
                                 f * self.device_bins * lanes * 4)
-            with telem.phase("dist_hist_exchange"), \
-                    telem_spans.span("dp_hist", leaf=int(leaf_id),
-                                     bucket=bucket):
+            with telem.phase("dist_hist_exchange"):
                 if self._quant_bits:
                     fn = self._get_hist_fn_q(bucket)
                     return faults.run_collective(
@@ -640,8 +639,7 @@ class VotingParallelTreeLearner(DataParallelTreeLearner):
         k2 = min(2 * max(1, int(self.config.top_k)), f)
         telem_counters.incr("dist_reduce_scatter_bytes",
                             f * 4 + k2 * self.device_bins * 3 * 4)
-        with telem.phase("dist_hist_exchange"), \
-                telem_spans.span("vote_hist", bucket=bucket):
+        with telem.phase("dist_hist_exchange"):
             if self._quant_bits:
                 from ..ops.quantize import dequant_scale3
                 fn = self._get_vote_fn_q(bucket)
@@ -1192,12 +1190,16 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
 
         obj_keys = objective_buffer_names(objective)
 
+        # `step_impl` is a name the benchmark reads (it finds the tree
+        # program in a device trace by the module `jit_step_impl`; see
+        # the serial make_fused_step)
         @jax.jit
         def step_impl(codes_pack, codes_row, obj_bufs, score_row,
                       base_mask, tree_key, bag_key, shrinkage):
             # codes + objective buffers as args, not closure constants —
             # see the serial make_fused_step note (compile payload)
-            with swapped_attrs(objective, obj_keys, obj_bufs):
+            with swapped_attrs(objective, obj_keys, obj_bufs), \
+                    jax.named_scope("lgbm.gradients"):
                 g, h = objective.get_gradients(score_row)
             g = jnp.pad(g, (0, npad - n))
             h = jnp.pad(h, (0, npad - n))
@@ -1205,24 +1207,22 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                 codes_pack, codes_row,
                 g, h, bag_key, base_mask, tree_key)
             leaf_id = leaf_id_pad[:n]
-            lv = leaf_values_from_rec(rec, k, L)
-            delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) * shrinkage
-            new_score = score_row + delta
-            # in-program sentry reduction (see the serial step contract)
-            finite = jnp.all(jnp.isfinite(new_score))
+            with jax.named_scope("lgbm.score_update"):
+                lv = leaf_values_from_rec(rec, k, L)
+                delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) \
+                    * shrinkage
+                new_score = score_row + delta
+                # in-program sentry reduction (see the serial step
+                # contract)
+                finite = jnp.all(jnp.isfinite(new_score))
             return (new_score, rec, rec_cat if has_cat else None,
                     leaf_id, k, finite)
 
-        def step(score_row, base_mask, tree_key, bag_key, shrinkage):
+        def make_args(*step_args):
             obj_bufs = tuple(getattr(objective, k) for k in obj_keys)
-            return step_impl(self.codes_pack, self.codes_row, obj_bufs,
-                             score_row, base_mask, tree_key, bag_key,
-                             shrinkage)
+            return (self.codes_pack, self.codes_row, obj_bufs, *step_args)
 
-        # contract surface for tests/tools (program-size pinning)
-        step.impl = step_impl
-        step.obj_keys = obj_keys
-        return step
+        return fused_step_surface(step_impl, make_args, obj_keys)
 
 
 class DeviceVotingParallelTreeLearner(DeviceDataParallelTreeLearner):
@@ -1356,12 +1356,16 @@ class DeviceFeatureParallelTreeLearner(DeviceTreeLearner):
 
         obj_keys = objective_buffer_names(objective)
 
+        # `step_impl` is a name the benchmark reads (it finds the tree
+        # program in a device trace by the module `jit_step_impl`; see
+        # the serial make_fused_step)
         @jax.jit
         def step_impl(codes_pack, codes_row, obj_bufs, score_row,
                       base_mask, tree_key, bag_key, shrinkage):
             # codes + objective buffers as args, not closure constants —
             # see the serial make_fused_step note (compile payload)
-            with swapped_attrs(objective, obj_keys, obj_bufs):
+            with swapped_attrs(objective, obj_keys, obj_bufs), \
+                    jax.named_scope("lgbm.gradients"):
                 g, h = objective.get_gradients(score_row)
             if goss is not None:
                 from ..models.device_learner import goss_sample
@@ -1374,24 +1378,22 @@ class DeviceFeatureParallelTreeLearner(DeviceTreeLearner):
                 w = jnp.ones((n,), jnp.float32)
             rec, rec_cat, leaf_id, k, _ = fn(codes_pack, codes_row,
                                              g, h, w, base_mask, tree_key)
-            lv = leaf_values_from_rec(rec, k, L)
-            delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) * shrinkage
-            new_score = score_row + delta
-            # in-program sentry reduction (see the serial step contract)
-            finite = jnp.all(jnp.isfinite(new_score))
+            with jax.named_scope("lgbm.score_update"):
+                lv = leaf_values_from_rec(rec, k, L)
+                delta = jnp.take(lv, jnp.clip(leaf_id, 0, L - 1)) \
+                    * shrinkage
+                new_score = score_row + delta
+                # in-program sentry reduction (see the serial step
+                # contract)
+                finite = jnp.all(jnp.isfinite(new_score))
             return (new_score, rec, rec_cat if has_cat else None,
                     leaf_id, k, finite)
 
-        def step(score_row, base_mask, tree_key, bag_key, shrinkage):
+        def make_args(*step_args):
             obj_bufs = tuple(getattr(objective, k) for k in obj_keys)
-            return step_impl(self.codes_pack, self.codes_row, obj_bufs,
-                             score_row, base_mask, tree_key, bag_key,
-                             shrinkage)
+            return (self.codes_pack, self.codes_row, obj_bufs, *step_args)
 
-        # contract surface for tests/tools (program-size pinning)
-        step.impl = step_impl
-        step.obj_keys = obj_keys
-        return step
+        return fused_step_surface(step_impl, make_args, obj_keys)
 
 
 def create_tree_learner(config: Config, dataset: Dataset,

@@ -513,8 +513,8 @@ def run_collective(fn, site: str = "collective",
     deadline_ms = collective_timeout_ms()
     plan = active_plan()
     if plan is None:
-        # clean path: one recorder-gate read (a no-op context manager
-        # while telemetry is off) on top of the plain call
+        # clean path: one recorder-gate read (a bare profiler
+        # annotation while telemetry is off) on top of the plain call
         with telem.phase("collective"):
             if deadline_ms > 0:
                 return _call_with_deadline(fn, site, deadline_ms)

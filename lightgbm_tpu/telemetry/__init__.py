@@ -1,18 +1,26 @@
 """Telemetry: structured tracing + metrics shared by training and serving.
 
-Three layers, all off by default and costing one module-global read per
-hook when off:
+Three layers. Every span, phase and iteration hook always enters a
+`jax.profiler` annotation named `lgbm/<name>` (about a microsecond when
+no profiler session is open), so any profiler trace shows the program's
+own spans on the device trace's clock; what the modes below switch is
+the bookkeeping on top:
 
-* `spans` — nestable monotonic-clock spans in a ring buffer with
+* `spans` — nestable spans; in trace mode also a ring buffer with
   Chrome/Perfetto trace-event export (`telemetry.dump_trace(path)`).
 * `counters` — process-wide counters/gauges (XLA compile events +
-  seconds, device transfer bytes, collective retries, peak host RSS)
-  with Prometheus text exposition (`prometheus_text`, the serving
-  `/metrics` endpoint).
+  seconds by event and by jitted function, set-up stage seconds, device
+  transfer bytes, collective retries, peak host RSS) with Prometheus
+  text exposition (`prometheus_text`, the serving `/metrics` endpoint).
 * `recorder` — per-iteration phase breakdown (gradient, hist, split,
-  partition, score_update, host_sync, ...) consumed by bench.py's
+  partition, score_update, record_fetch, ...) consumed by bench.py's
   `phase_breakdown` field, tools/profile_iter.py and the
   `record_telemetry` callback.
+
+Inside the tree program the stages are `jax.named_scope("lgbm.<stage>")`
+(`STAGES`); `stage_map(hlo_text)` maps a compiled module's instructions
+to them, which is how a device trace (whose events carry instruction
+names and nothing else) is read by stage.
 
 Built on top of those, the flight-recorder layer: `events` (durable
 structured per-iteration JSONL stream, `LGBM_TPU_EVENTS=path`),
@@ -23,8 +31,9 @@ a markdown run report.
 
 Modes (`telemetry` config param, `LGBM_TPU_TELEMETRY` env — env wins):
 
-* ``off``     every hook is a no-op; the float path is byte-for-byte
-  unchanged (compile events still accumulate once a listener exists —
+* ``off``     every hook is its profiler annotation and nothing else (no
+  clock read, no lock, no state); the float path is byte-for-byte
+  unchanged (compile events and set-up stage seconds still accumulate —
   they are process-lifetime forensics, not a hot path).
 * ``summary`` recorder + hot-path counters on: per-iteration phase
   accounting, `telemetry_summary()` one-line JSON.
@@ -36,6 +45,8 @@ See docs/Observability.md.
 from __future__ import annotations
 
 import os
+import re
+from typing import Dict, Optional, Tuple
 
 from ..utils import log
 from . import (aggregate, bundle, clock, counters, events, recorder,
@@ -47,10 +58,43 @@ __all__ = ["counters", "recorder", "spans", "span", "events", "watchdogs",
            "enabled", "resolve_mode", "configure", "dump_trace",
            "telemetry_summary", "phase_breakdown", "prometheus_text",
            "record_iteration", "reset", "xla_trace_active",
-           "note_grow_dispatches"]
+           "note_grow_dispatches", "STAGES", "stage_map"]
 
 MODES = ("off", "summary", "trace")
 _mode = "off"
+
+# -- stages inside the tree program -----------------------------------------
+# `jax.named_scope("lgbm.<stage>")` in models/device_learner.py and
+# ops/split.py; tests/test_trace_spans.py holds the scopes in the code
+# and this tuple to each other. Scopes are metadata: they change neither
+# the compiled computation nor the persistent compile cache's key.
+STAGES = ("gradients", "root_hist", "leaf_select", "go_left", "partition",
+          "table_update", "child_hist", "split_scan", "split_epilogue",
+          "score_update")
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\bop_name=\"([^\"]*)\"", re.M)
+_STAGE_IN_OP_NAME = re.compile(r"lgbm\.(\w+)")
+_RUNG_IN_OP_NAME = re.compile(r"branch_(\d+)_fun")
+
+
+def stage_map(hlo_text: str) -> Dict[str, Tuple[str, Optional[int]]]:
+    """{instruction name: (stage, rung or None)} of a compiled module's
+    text (`jit(f).lower(...).compile().as_text()`): the instructions
+    whose `op_name` runs through a `lgbm.<stage>` scope, under the
+    innermost such scope, with the branch index of the enclosing
+    `lax.switch` (the compact core's window ladder) as the rung. A
+    device trace names its "XLA Ops" events by the instruction's text,
+    which starts with this name; that is the join. Instructions the
+    compiler made itself (copies round a `while` or `conditional`) carry
+    no `op_name` and are not in the map."""
+    out = {}
+    for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
+        stages = _STAGE_IN_OP_NAME.findall(op_name)
+        if stages:
+            rung = _RUNG_IN_OP_NAME.findall(op_name)
+            out[name] = (stages[-1], int(rung[-1]) if rung else None)
+    return out
 
 # -- XLA timeline (jax.profiler) under trace mode ---------------------------
 # Opt-in via LGBM_TPU_XLA_TRACE=<dir>: entering trace mode starts a

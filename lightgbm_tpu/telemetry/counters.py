@@ -10,9 +10,13 @@ Three kinds of state:
   `is_active()`, flipped by `telemetry.set_mode`.
 * **XLA compile events** — a jax monitoring listener recording every
   trace/lower/backend-compile duration event in the process, by event
-  name, with accumulated seconds. This is the grown-up version of the
-  counter `tests/test_serving.py` used to keep private: serving tests
-  and telemetry tests now import `compile_events()` from here.
+  name and (where JAX names it) by jitted function, with accumulated
+  seconds. This is the grown-up version of the counter
+  `tests/test_serving.py` used to keep private: serving tests and
+  telemetry tests now import `compile_events()` from here.
+* **Set-up stages** — `setup_*_seconds`, fed by `spans.stage(counter,
+  name)` round work done once a Dataset or learner, unconditionally
+  like the compile seconds.
 * **Peak host RSS** — read live from getrusage at snapshot time.
 
 Prometheus text exposition (`prometheus_text`) renders all of it plus
@@ -27,7 +31,8 @@ from typing import Dict, List, Optional
 
 __all__ = ["incr", "add_seconds", "set_gauge", "get", "is_active",
            "set_active", "snapshot", "reset", "install_compile_listener",
-           "compile_events", "compile_seconds", "peak_rss_bytes",
+           "compile_events", "compile_seconds",
+           "compile_seconds_by_function", "peak_rss_bytes",
            "prometheus_text"]
 
 _lock = threading.Lock()
@@ -77,6 +82,7 @@ def reset() -> None:
 # -- XLA compile events -----------------------------------------------------
 _compile_events: List[str] = []
 _compile_seconds: Dict[str, float] = {}
+_compile_seconds_by_fun: Dict[str, Dict[str, float]] = {}
 _listener_state = {"installed": False, "available": True}
 
 
@@ -84,9 +90,13 @@ def _on_duration_event(name: str, *args, **kw) -> None:
     if "compile" not in name:
         return
     secs = float(args[0]) if args else 0.0
-    _compile_events.append(name)
+    fun = kw.get("fun_name")     # JAX names the jitted function on its
+    _compile_events.append(name)    # trace / lower / compile events
     with _lock:
         _compile_seconds[name] = _compile_seconds.get(name, 0.0) + secs
+        if fun:
+            by_event = _compile_seconds_by_fun.setdefault(str(fun), {})
+            by_event[name] = by_event.get(name, 0.0) + secs
 
 
 def install_compile_listener() -> bool:
@@ -121,6 +131,17 @@ def compile_seconds() -> Dict[str, float]:
     install_compile_listener()
     with _lock:
         return dict(_compile_seconds)
+
+
+def compile_seconds_by_function() -> Dict[str, Dict[str, float]]:
+    """{jitted function's name: {XLA event name: seconds}} — WHAT was
+    traced, lowered or compiled, where `compile_seconds()` says only how
+    long. A compile inside a steady-state window names its culprit here
+    (`step_impl` is the fused tree program)."""
+    install_compile_listener()
+    with _lock:
+        return {fun: dict(by_event)
+                for fun, by_event in _compile_seconds_by_fun.items()}
 
 
 def peak_rss_bytes() -> int:

@@ -11,9 +11,11 @@ and tools/profile_iter.py).
 Canonical phase names, so breakdowns from different paths diff cleanly:
 
     boost_avg   gradient   quantize   bagging    hist      split
-    partition   grow_dispatch         grow_fused host_sync tree_replay
-    score_update            sentry    collective eval      stream_wait
-    dist_hist_exchange
+    partition   grow_dispatch         grow_fused feature_mask
+    mask_sync   record_fetch          tree_replay          valid_update
+    host_sync   score_update          sentry     collective
+    eval        stream_wait           dist_hist_exchange
+    fused_step_build
 
 `grow_fused` is the vmap-batched multiclass dispatch: all K per-class
 trees of one iteration as ONE batched whole-tree program
@@ -26,17 +28,27 @@ histogram allreduce — in row-sharded pods it is the ONLY cross-host
 traffic inside an iteration, so its share of wall is the network bill.
 
 One program can fuse several (the device learners grow the whole tree in
-one dispatch — that is `grow_dispatch`, and the blocking record fetch is
-`host_sync`); free-form names are accepted. Phases must NOT nest — each
-second should be attributed exactly once, so `phase_sum / wall` is a
-meaningful coverage ratio. Phases recorded outside an open iteration
-(engine-side eval, a save-triggered materialize) count toward run totals
-but not toward iteration wall/coverage.
+one dispatch — that is `grow_dispatch`; the blocking fetch of its split
+records is `record_fetch`, which is the wait for the device on the
+synchronous paths; on the pipelined fused path the host meets the
+running program earlier, in `mask_sync`, the feature mask's device round
+trip, and `record_fetch` is short; rebuilding the host tree is
+`tree_replay`; `host_sync` is the serial learner's per-split host loop);
+free-form names are accepted. Phases must NOT nest — each second should
+be attributed exactly once, so `phase_sum / wall` is a meaningful
+coverage ratio. Phases recorded outside an open iteration (engine-side
+eval, a save-triggered materialize, the once-a-booster
+`fused_step_build`) count toward run totals but not toward iteration
+wall/coverage.
 
-Disabled (default) both hooks return the shared no-op context manager
-after one module-global read — cheap enough to stay in the float path
-permanently (the tier-1 overhead guard in tests/test_telemetry.py holds
-this to <2% per iteration).
+Every hook ALWAYS enters a profiler annotation (`lgbm/<name>`; the
+iteration a `StepTraceAnnotation("lgbm/iteration", step_num=i)`, so the
+phases of one boosting iteration share its step number), which the
+profiler gates itself: about a microsecond with no session open.
+Disabled (default) that is all a hook does — no clock read, no lock, no
+recorder state (tests/test_telemetry.py counts the clock reads).
+Enabled, the same enter/exit also times the block once, for the totals
+here and the span ring.
 """
 from __future__ import annotations
 
@@ -44,7 +56,9 @@ import threading
 import time
 from typing import Dict, Optional
 
-from .spans import NULL_SPAN, add_event
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+from .spans import PREFIX, add_event
 
 __all__ = ["enable", "enabled", "iteration", "phase", "last_iteration",
            "phase_breakdown", "reset"]
@@ -68,14 +82,20 @@ def enabled() -> bool:
     return _enabled
 
 
+def _step_annotation(index: int):
+    return StepTraceAnnotation(PREFIX + "iteration", step_num=index)
+
+
 class _IterCtx:
-    __slots__ = ("index",)
+    __slots__ = ("index", "annotation")
 
     def __init__(self, index: int):
         self.index = index
+        self.annotation = _step_annotation(index)
 
     def __enter__(self):
         global _cur
+        self.annotation.__enter__()
         _cur = {"index": self.index, "t0": time.perf_counter(),
                 "phases": {}}
         return self
@@ -83,9 +103,11 @@ class _IterCtx:
     def __exit__(self, *exc):
         global _cur, _iter_count, _iter_wall, _phase_in_iter, _last
         cur, _cur = _cur, None
+        now = time.perf_counter()
+        self.annotation.__exit__(*exc)
         if cur is None:            # reentrant/forced-closed: nothing open
             return False
-        wall = time.perf_counter() - cur["t0"]
+        wall = now - cur["t0"]
         with _lock:
             _iter_count += 1
             _iter_wall += wall
@@ -97,17 +119,20 @@ class _IterCtx:
 
 
 class _PhaseCtx:
-    __slots__ = ("name", "t0")
+    __slots__ = ("name", "t0", "annotation")
 
     def __init__(self, name: str):
         self.name = name
+        self.annotation = TraceAnnotation(PREFIX + name)
 
     def __enter__(self):
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self.t0
+        self.annotation.__exit__(*exc)
         with _lock:
             ent = _totals.setdefault(self.name, [0.0, 0])
             ent[0] += dt
@@ -122,14 +147,14 @@ class _PhaseCtx:
 def iteration(index: int):
     """Bracket one boosting iteration (GBDT.train_one_iter owns this)."""
     if not _enabled:
-        return NULL_SPAN
+        return _step_annotation(index)
     return _IterCtx(index)
 
 
 def phase(name: str):
     """Attribute a block to `name` within the current iteration."""
     if not _enabled:
-        return NULL_SPAN
+        return TraceAnnotation(PREFIX + name)
     return _PhaseCtx(name)
 
 

@@ -1,17 +1,28 @@
-"""Nestable monotonic-clock spans with Chrome trace-event export.
+"""Nestable spans: the profiler's annotations, plus a Chrome trace-event
+ring in trace mode.
 
-A span is a `with telemetry.spans.span("name"):` block timed on
-`time.perf_counter()`. Completed spans land in a bounded ring buffer
-(newest win; default 65536 events, `LGBM_TPU_TRACE_RING` overrides) and
-export as Chrome/Perfetto trace-event JSON via `dump_trace(path)` —
-load the file in chrome://tracing or ui.perfetto.dev.
+A span is a `with telemetry.spans.span("name"):` block. It ALWAYS enters
+a `jax.profiler.TraceAnnotation("lgbm/<name>")`, so any profiler session
+(`jax.profiler.trace`, `LGBM_TPU_XLA_TRACE`, the benchmark's `--trace 1`)
+shows the program's spans on the profiler's clock beside the device's
+timeline; the profiler gates these itself, and with no session open one
+costs about a microsecond.
 
-Disabled (the default) every `span()` call returns one shared no-op
-context manager after a single module-global read, so hooks can stay in
-hot paths permanently. Thread identity rides on each event (`tid`), so
-concurrent serving threads render as separate tracks; nesting within a
-thread is inferred from the timestamps, the standard trace-event
-semantics.
+In trace mode the span is also timed on `time.perf_counter()` and lands
+in a bounded ring buffer (newest win; default 65536 events,
+`LGBM_TPU_TRACE_RING` overrides), exported as Chrome/Perfetto
+trace-event JSON via `dump_trace(path)` — load the file in
+chrome://tracing or ui.perfetto.dev. Below trace mode a span reads no
+clock, takes no lock and keeps no state. Thread identity rides on each
+ring event (`tid`), so concurrent serving threads render as separate
+tracks; nesting within a thread is inferred from the timestamps, the
+standard trace-event semantics.
+
+`stage(counter, name)` is a span for work done once a Dataset or learner
+(bin finding, binning, bundling, the learner's packing and H2D): the
+same annotation and ring event, and its seconds also go into a
+`setup_*_seconds` counter whatever the mode, like the compile seconds.
+Never inside an iteration.
 """
 from __future__ import annotations
 
@@ -22,23 +33,15 @@ import time
 from collections import deque
 from typing import Dict, List
 
-__all__ = ["NULL_SPAN", "span", "add_event", "enable", "enabled",
+from jax.profiler import TraceAnnotation
+
+from .counters import add_seconds
+
+__all__ = ["PREFIX", "span", "stage", "add_event", "enable", "enabled",
            "events", "clear", "dump_trace", "epoch", "set_pid", "pid"]
 
-
-class _NullSpan:
-    """The shared do-nothing context manager every disabled hook returns
-    (spans here, phases in recorder.py): no allocation, no clock read."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-NULL_SPAN = _NullSpan()
+# every annotation of this package in a profiler trace starts with this
+PREFIX = "lgbm/"
 
 _enabled = False
 _lock = threading.Lock()
@@ -83,28 +86,44 @@ def enabled() -> bool:
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    """The annotation plus one ring event (trace mode); a set-up stage
+    also feeds its seconds counter. One pair of clock reads serves all."""
+    __slots__ = ("name", "args", "t0", "annotation", "counter")
 
-    def __init__(self, name: str, args: Dict):
+    def __init__(self, name: str, args: Dict, counter: str = None):
         self.name = name
         self.args = args
+        self.counter = counter
+        self.annotation = TraceAnnotation(PREFIX + name, **args)
 
     def __enter__(self):
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        add_event(self.name, time.perf_counter() - self.t0,
-                  t0=self.t0, **self.args)
+        dt = time.perf_counter() - self.t0
+        self.annotation.__exit__(*exc)
+        if self.counter is not None:
+            add_seconds(self.counter, dt)
+        add_event(self.name, dt, t0=self.t0, **self.args)
         return False
 
 
 def span(name: str, **args):
-    """Context manager timing a block as one trace event. `args` become
-    the event's `args` payload (small JSON-able values only)."""
+    """Context manager marking a block as `lgbm/<name>` in any profiler
+    session and, in trace mode, timing it as one ring event. `args`
+    become the event's `args` payload (small JSON-able values only)."""
     if not _enabled:
-        return NULL_SPAN
+        return TraceAnnotation(PREFIX + name, **args)
     return _Span(name, args)
+
+
+def stage(counter: str, name: str):
+    """Bracket one set-up stage: a `lgbm/<name>` span whose seconds are
+    also added to `counter` whatever the telemetry mode (once a Dataset
+    or learner: keep it out of iterations)."""
+    return _Span(name, {}, counter)
 
 
 def add_event(name: str, dur_s: float, t0: float = None, **args) -> None:

@@ -11,8 +11,8 @@ Chrome/Perfetto file shows one track per rank, and merged via
 
 In synchronous SPMD every rank's iteration wall converges to the
 slowest rank's, but each rank spends the difference *waiting inside a
-blocking phase* (``collective`` / ``host_sync`` /
-``dist_hist_exchange``), not computing. Per iteration and per blocking
+blocking phase* (``collective`` / ``host_sync`` / ``record_fetch`` /
+``mask_sync`` / ``dist_hist_exchange``), not computing. Per iteration and per blocking
 phase, the minimum time any rank spent there is that phase's intrinsic
 cost; everything a rank spends above the minimum is wait:
 
@@ -45,7 +45,8 @@ __all__ = ["BLOCKING_PHASES", "ingest", "attribute_pending",
 
 # phases whose time includes waiting on peers; everything above the
 # fleet-minimum in one of these is attributed as collective-wait
-BLOCKING_PHASES = ("collective", "host_sync", "dist_hist_exchange")
+BLOCKING_PHASES = ("collective", "host_sync", "record_fetch", "mask_sync",
+                   "dist_hist_exchange")
 
 _MAX_ATTRIBUTIONS = 4096
 _MAX_PENDING_ITERS = 1024

@@ -552,7 +552,7 @@ def test_two_process_critical_path_charges_delayed_rank(tmp_path):
         # rank is re-synced before the next update — the wait would
         # land in the (unbracketed) gather instead of an iteration
         # phase. With period 2 rank 1 enters every other update late
-        # and rank 0 blocks inside its bracketed host_sync.
+        # and rank 0 blocks inside its bracketed record_fetch.
         env["LGBM_TPU_AGG_PERIOD"] = "2"
         if r == 1:
             env["LGBM_TPU_FAULT_SPEC"] = "delay_ms=300"

@@ -1,0 +1,293 @@
+"""The program's own spans on the profiler's clock, and its stages
+inside the tree program.
+
+Host side: `spans.span`, `recorder.phase` and `recorder.iteration` enter
+`jax.profiler` annotations named `lgbm/<name>` whatever the telemetry
+mode, so a profiler session holds them beside the device's timeline;
+set-up stages also feed `setup_*_seconds` counters. Device side: the
+tree program's stages are `jax.named_scope("lgbm.<stage>")`, and
+`fused_step.stage_map()` maps the compiled module's instructions to
+them. The benchmark finds the tree program by the module name
+`jit_step_impl`; that name is pinned here.
+
+CPU, toy sizes: names, nesting and counts only — never a time.
+"""
+import glob
+import os
+import re
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import lightgbm_tpu as lgb
+from conftest import make_binary
+from lightgbm_tpu import telemetry
+from lightgbm_tpu.telemetry import counters, spans
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PARAMS = {"objective": "binary", "num_leaves": 7, "max_bin": 15,
+          "verbosity": -1}
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_off():
+    telemetry.set_mode("off")
+    telemetry.reset()
+    yield
+    telemetry.set_mode("off")
+    telemetry.reset()
+
+
+def _booster(n=2000, f=6, **params):
+    x, y = make_binary(n=n, f=f, seed=3)
+    return lgb.Booster(dict(PARAMS, **params), lgb.Dataset(x, y))
+
+
+def _step_args(gbdt):
+    f = gbdt.train_set.num_features
+    return (gbdt.score_updater.score[0], jnp.ones((f,), bool),
+            jax.random.PRNGKey(0), jax.random.PRNGKey(1), jnp.float32(0.1))
+
+
+# ---------------------------------------------------------------------------
+# (a) host spans in a profiler session, telemetry off
+
+def _host_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of the `lgbm/` events a
+    profiler session kept, in time order."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(spans.PREFIX):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_profiler_session_holds_program_spans(tmp_path):
+    x, y = make_binary(n=2000, f=6, seed=3)
+    bst = _booster()
+    bst.update()                          # compile outside the session
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0       # as the benchmark sets it
+    assert telemetry.mode() == "off"
+    with jax.profiler.trace(str(tmp_path), profiler_options=options):
+        lgb.Dataset(x, y, params=PARAMS).construct()
+        for _ in range(3):
+            bst.update()
+        _ = bst._gbdt.models
+    assert telemetry.mode() == "off"
+    events = _host_events(tmp_path)
+    names = [e[0] for e in events]
+    assert "lgbm/dataset/find_bin" in names
+    assert "lgbm/dataset/bin_data" in names
+    iterations = [e for e in events if e[0] == "lgbm/iteration"]
+    assert [e[3]["step_num"] for e in iterations] == [1, 2, 3]
+    for _, lo, hi, _ in iterations:
+        inside = {e[0] for e in events if lo <= e[1] and e[2] <= hi}
+        assert {"lgbm/grow_dispatch", "lgbm/record_fetch",
+                "lgbm/tree_replay", "lgbm/feature_mask",
+                "lgbm/mask_sync"} <= inside
+    # off: nothing of the span ring or the recorder moved
+    assert spans.events() == []
+    assert telemetry.phase_breakdown()["iterations"] == 0
+
+
+def test_trace_mode_span_is_timed_once_and_still_annotated(tmp_path):
+    telemetry.set_mode("trace")
+    with jax.profiler.trace(str(tmp_path)):
+        with spans.span("probe", rows=3):
+            with telemetry.recorder.phase("probe_phase"):
+                pass
+    ring = {e["name"]: e for e in spans.events()}
+    assert ring["probe"]["args"] == {"rows": 3}
+    assert set(ring) == {"probe", "probe_phase"}
+    assert telemetry.phase_breakdown()["phases"]["probe_phase"]["calls"] == 1
+    names = [e[0] for e in _host_events(tmp_path)]
+    assert names.count("lgbm/probe") == 1
+    assert names.count("lgbm/probe_phase") == 1
+
+
+def test_stage_feeds_its_counter_and_the_ring_from_one_timing():
+    with spans.stage("setup_probe_seconds", "probe_stage"):
+        pass                                # off: the counter all the same
+    off_s = counters.get("setup_probe_seconds")
+    assert off_s > 0 and spans.events() == []
+    telemetry.set_mode("trace")
+    with spans.stage("setup_probe_seconds", "probe_stage"):
+        pass
+    (event,) = spans.events()
+    assert event["name"] == "probe_stage"
+    assert event["dur"] == pytest.approx(
+        (counters.get("setup_probe_seconds") - off_s) * 1e6)
+
+
+# ---------------------------------------------------------------------------
+# (b) stages inside the tree program
+
+HLO_TOY = """HloModule jit_step_impl, entry_computation_layout={()->f32[]}
+
+%fused_computation.1 (p: f32[4]) -> f32[4] {
+  %p = f32[4]{0} parameter(0)
+  ROOT %scatter.9 = f32[4]{0} scatter(%p), metadata={op_name="jit(step_impl)/while/body/branch_2_fun/lgbm.partition/scatter"}
+}
+
+ENTRY %main (a: f32[4]) -> f32[4] {
+  %a = f32[4]{0} parameter(0)
+  %copy.7 = f32[4]{0} copy(%a)
+  %fusion.3 = f32[4]{0} fusion(%copy.7), kind=kLoop, calls=%fused_computation.1, metadata={op_type="scatter" op_name="jit(step_impl)/while/body/branch_2_fun/lgbm.partition/scatter" source_file="x.py" source_line=3}
+  ROOT add.1 = f32[4]{0} add(%fusion.3, %a), metadata={op_name="jit(step_impl)/lgbm.split_epilogue/lgbm.split_scan/add"}
+}
+"""
+
+
+def test_stage_map_reads_innermost_scope_and_rung():
+    assert telemetry.stage_map(HLO_TOY) == {
+        "scatter.9": ("partition", 2), "fusion.3": ("partition", 2),
+        "add.1": ("split_scan", None)}
+
+
+def test_every_named_scope_is_a_stage_and_every_stage_a_scope():
+    used = set()
+    for root, _dirs, files in os.walk(os.path.join(REPO, "lightgbm_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                with open(os.path.join(root, fn)) as fh:
+                    used.update(re.findall(
+                        r'named_scope\(\s*"lgbm\.(\w+)"', fh.read()))
+    assert used == set(telemetry.STAGES)
+
+
+def _computations(hlo_text):
+    """{computation name: its instruction lines} of a module's text."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = line.split("(")[0].split()[-1].lstrip("%")
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps
+
+
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+([\w\-]+)\(")
+
+
+def _called(lines):
+    out = set()
+    for line in lines:
+        for one, many in _CALLED.findall(line):
+            out.update([one] if one else
+                       [c.strip().lstrip("%") for c in many.split(",")])
+    return out
+
+
+def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    bst = _booster(n=10000)               # three rungs of the window ladder
+    gbdt = bst._gbdt
+    assert gbdt.learner.strategy == "compact"
+    step = gbdt.learner.make_fused_step(gbdt.objective)
+    text = step.lower(*_step_args(gbdt)).compile().as_text()
+    stage_of = telemetry.stage_map(text)
+    assert stage_of == step.stage_map(*_step_args(gbdt))
+    owned = {}
+    for name, (stage, rung) in stage_of.items():
+        owned.setdefault(stage, []).append((name, rung))
+    assert set(owned) == set(telemetry.STAGES)
+    rungs = {rung for _, rung in owned["partition"]}
+    assert None not in rungs and len(rungs) == 3
+    # everything heavy inside the split loop belongs to a stage: walk the
+    # computations reachable from the body of the loop (the `while` whose
+    # op_name is no stage's: the others are loops INSIDE a stage)
+    comps = _computations(text)
+    loops = [line for lines in comps.values() for line in lines
+             if re.search(r"\bwhile\(", line)
+             and not re.search(r'op_name="[^"]*lgbm\.', line)]
+    assert len(loops) == 1, loops
+    todo = [re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)]
+    reached = set()
+    while todo:
+        comp = todo.pop()
+        if comp not in reached:
+            reached.add(comp)
+            todo.extend(_called(comps[comp]))
+    # (an instruction without `op_name` is the compiler's own — the
+    # pieces it cuts a cumsum into, say — and no scope can reach it)
+    unstaged, checked = [], 0
+    for comp in reached:
+        for line in comps[comp]:
+            m = _INSTRUCTION.match(line)
+            if (m and "op_name=" in line and m.group(2) in (
+                    "scatter", "dot", "convolution", "fusion")):
+                checked += 1
+                if m.group(1) not in stage_of:
+                    unstaged.append(line.strip()[:240])
+    assert checked > 50
+    assert not unstaged, "\n".join(unstaged)
+
+
+# ---------------------------------------------------------------------------
+# (c) the name the benchmark finds the tree program by
+
+@pytest.mark.parametrize("tree_learner,learner_class", [
+    ("serial", "DeviceTreeLearner"),
+    ("data", "DeviceDataParallelTreeLearner"),
+    ("feature", "DeviceFeatureParallelTreeLearner")])
+def test_fused_step_module_is_named_jit_step_impl(tree_learner,
+                                                  learner_class):
+    gbdt = _booster(tree_learner=tree_learner)._gbdt
+    assert type(gbdt.learner).__name__ == learner_class
+    step = gbdt.learner.make_fused_step(gbdt.objective)
+    assert step.impl.__name__ == "step_impl"
+    lowered = step.lower(*_step_args(gbdt)).as_text()
+    assert re.search(r"module @jit_step_impl\b", lowered)
+
+
+# ---------------------------------------------------------------------------
+# (d) set-up stages as counters, (e) compiles by function
+
+def test_setup_stage_counters_are_positive_and_inside_construct():
+    x, y = make_binary(n=4000, f=6, seed=3)
+    tick = time.perf_counter()
+    ds = lgb.Dataset(x, y, params=PARAMS).construct()
+    construct_s = time.perf_counter() - tick
+    lgb.Booster(PARAMS, ds)
+    stages = {k: counters.get(f"setup_{k}_seconds")
+              for k in ("find_bin", "bin_data", "bundle", "learner_build")}
+    assert all(v > 0 for v in stages.values()), stages
+    assert (stages["find_bin"] + stages["bin_data"] + stages["bundle"]
+            <= construct_s)
+
+
+def test_retrace_is_named_in_compile_seconds_by_function():
+    counters.install_compile_listener()
+
+    @jax.jit
+    def retraced_probe(v):
+        return v * 2 + 1
+
+    retraced_probe(jnp.ones(3))
+    before = dict(counters.compile_seconds_by_function()["retraced_probe"])
+    assert any("jaxpr_trace" in k for k in before)
+    retraced_probe(jnp.ones(3))           # cached: nothing new
+    assert counters.compile_seconds_by_function()["retraced_probe"] == before
+    retraced_probe(jnp.ones(5))           # a new shape forces a retrace
+    after = counters.compile_seconds_by_function()["retraced_probe"]
+    assert all(after[k] > before[k] for k in before)
+    totals = counters.compile_seconds()
+    assert all(after[k] <= totals[k] + 1e-9 for k in after)

@@ -23,9 +23,11 @@ PHASE_CALL = re.compile(r"\bphase\(\s*[\"']([a-z0-9_]+)[\"']")
 # span lines; findall over whole-file text lets \s* cross newlines)
 EMIT_CALL = re.compile(r"\.emit\(\s*[\"']([a-z0-9_]+)[\"']")
 # literal counters.incr("name") / set_gauge / add_seconds on any
-# receiver whose name ends in "counters" (counters., telem_counters.)
+# receiver whose name ends in "counters" (counters., telem_counters.),
+# and spans.stage("name", ...) — a set-up stage's seconds counter
 COUNTER_CALL = re.compile(
-    r"counters\s*\.\s*(?:incr|set_gauge|add_seconds)\(\s*"
+    r"(?:counters\s*\.\s*(?:incr|set_gauge|add_seconds)"
+    r"|spans\s*\.\s*stage)\(\s*"
     r"[\"']([a-z0-9_]+)[\"']")
 
 # the fault grammar's verb registry: the _KNOWN tuple in
