@@ -45,6 +45,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..config import Config
 from ..io.binning import BIN_CATEGORICAL
@@ -2081,27 +2082,108 @@ def make_sliced_search(*, axis_name, fp, D, c_cols, col_bins, item_bits,
     return reduce_hist, search_row, search2_rows, cs, shard, start
 
 
+# The most rows one scatter of the scan partition takes: the largest power
+# of two in the cheapest regime of the TPU's row scatter. The compiler has
+# two row scatters and chooses by the count of indices, whatever the row's
+# width (the partition compiled for a described v5e at 3 to 40 words a
+# row, PR 27): up to 2**18 indices it sorts them on chip and writes
+# row-major tiles, past that it scatters row by row. u32[W, 11] alone under
+# jit, ns a window row (builder's chip runs, PR 27): one scatter 14.2 at
+# W = 2**18, 103-110 at W = 2**19..12,000,000 (at 5 and 21 words a row:
+# 14.0 and 14.4, then 90.6 and 119.2); a 2**22-row window in tiles of
+# 2**13 / 14 / 15 / 16 / 17 / 18 rows 7.3 / 6.4 / 8.4 / 8.2 / 11.8 / 12.8;
+# the tree program's own rungs, untiled, 6.8 at 2**16 rows, 10.3 at 2**17,
+# 11.8 at 2**18.
+SCATTER_TILE_ROWS = 1 << 16
+
+
+def _rows_minor(x: jax.Array) -> jax.Array:
+    """Pin a (rows, words) buffer to the packed table's own device layout
+    (rows minor). The TPU compiler's fast scatter wants its (tile, words)
+    operand words-minor, a word row padded to 128 lanes; unpinned, layout
+    assignment hands that layout on through the slices to every
+    window-sized buffer of the tiled partition, 512 B a row for 44."""
+    return with_layout_constraint(x, Layout(major_to_minor=(1, 0)))
+
+
+def _scan_partition(win: jax.Array, key3: jax.Array):
+    """The window in the stable 3-way order of key3, and the counts of
+    classes 0 and 1: per-class exclusive ranks via cumsum, then ONE row
+    scatter."""
+    is0 = key3 == 0
+    is1 = key3 == 1
+    i0 = is0.astype(jnp.int32)
+    i1 = is1.astype(jnp.int32)
+    i2 = (key3 == 2).astype(jnp.int32)
+    n0 = jnp.sum(i0)
+    n1 = jnp.sum(i1)
+    d0 = jnp.cumsum(i0) - 1
+    d1 = n0 + jnp.cumsum(i1) - 1
+    d2 = n0 + n1 + jnp.cumsum(i2) - 1
+    dest = jnp.where(is0, d0, jnp.where(is1, d1, d2))
+    return (jnp.zeros_like(win).at[dest].set(win, unique_indices=True),
+            n0, n1)
+
+
+def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
+                          tile_rows: int) -> jax.Array:
+    """_scan_partition of a window of more than `tile_rows` rows with no
+    scatter larger than a tile: each tile is partitioned alone, and its
+    class-0 and class-1 pieces are appended at running offsets by masked
+    whole-tile read-modify-writes (as the chunk core's pass_b places its
+    lefts). PRECONDITION: the key-2 rows are the window's tail
+    (`valid = arange < pcount` in both callers), so they stay where the
+    input has them. No write is clamped: a tile's class-0 piece starts
+    at or before the tile itself, and its class-1 write starts at the
+    earlier tiles' class-0 and class-1 rows plus the later tiles'
+    class-0 rows, at most rows - size."""
+    rows, d = win.shape
+    tiles, rem = divmod(rows, tile_rows)
+    win = _rows_minor(win)
+    n0 = jnp.sum((key3 == 0).astype(jnp.int32))
+
+    def step(acc, start, size):
+        out, o0, o1 = acc
+        j = jnp.arange(size, dtype=jnp.int32)
+        tile = _rows_minor(jax.lax.dynamic_slice(win, (start, 0),
+                                                 (size, d)))
+        s, c0, c1 = _scan_partition(
+            tile, jax.lax.dynamic_slice(key3, (start,), (size,)))
+        s = _rows_minor(s)
+        # [0, c0) of the sorted tile to out[o0:], then [c0, c0 + c1) to
+        # out[o1:]; o1 >= n0 >= c0, so neither start is clamped from below
+        for at, lo, hi in ((o0, 0, c0), (o1 - c0, c0, c0 + c1)):
+            old = jax.lax.dynamic_slice(out, (at, 0), (size, d))
+            out = _rows_minor(jax.lax.dynamic_update_slice(
+                out, _rows_minor(jnp.where(((j >= lo) & (j < hi))[:, None],
+                                           s, old)), (at, 0)))
+        return out, o0 + c0, o1 + c1
+
+    acc = jax.lax.fori_loop(
+        0, tiles, lambda i, acc: step(acc, i * tile_rows, tile_rows),
+        (win, jnp.int32(0), n0))
+    if rem:     # only the top rung, n itself, is not a power of two
+        acc = step(acc, tiles * tile_rows, rem)
+    return acc[0]
+
+
 @jax.named_scope("lgbm.partition")
-def partition_window(win: jax.Array, key3: jax.Array,
-                     partition: str) -> jax.Array:
+def partition_window(win: jax.Array, key3: jax.Array, partition: str,
+                     tile_rows: Optional[int] = None) -> jax.Array:
     """Stable 3-way reorder of a (W, D) u32 window by key3 in {0,1,2} —
     the ONE dispatch over the partition formulations (reference
     DataPartition::Split role), shared by the compact branches and the
     chunk passes. 'sort' = argsort+take; 'scan' = per-class exclusive
-    ranks via cumsum + one row scatter (no sort passes)."""
+    ranks via cumsum + one row scatter (no sort passes), tile by tile
+    where the window holds more than SCATTER_TILE_ROWS rows, and then
+    only for key-2 rows that are the window's tail (see
+    _scan_partition_tiled). `tile_rows` is for tests."""
     if partition == "scan":
-        is0 = key3 == 0
-        is1 = key3 == 1
-        i0 = is0.astype(jnp.int32)
-        i1 = is1.astype(jnp.int32)
-        i2 = (key3 == 2).astype(jnp.int32)
-        n0 = jnp.sum(i0)
-        n1 = jnp.sum(i1)
-        d0 = jnp.cumsum(i0) - 1
-        d1 = n0 + jnp.cumsum(i1) - 1
-        d2 = n0 + n1 + jnp.cumsum(i2) - 1
-        dest = jnp.where(is0, d0, jnp.where(is1, d1, d2))
-        return jnp.zeros_like(win).at[dest].set(win, unique_indices=True)
+        if tile_rows is None:
+            tile_rows = SCATTER_TILE_ROWS
+        if win.shape[0] > tile_rows:
+            return _scan_partition_tiled(win, key3, tile_rows)
+        return _scan_partition(win, key3)[0]
     order = jnp.argsort(key3.astype(jnp.int8), stable=True)
     return jnp.take(win, order, axis=0)
 
@@ -2453,12 +2535,15 @@ class DeviceTreeLearner:
         requested = strategy or strategy_env()
         self.strategy = resolve_strategy(config, dataset, strategy)
         # partition formulation: sort | scan (an explicit
-        # LGBM_TPU_PARTITION wins on any backend). Builder-run on v5e,
-        # 2026-08-01, 1M x 28 x 255 (a dated hypothesis, not measured on
-        # today's code): scan beat sort on the compact strategy — the
-        # argsort's O(W log W) passes dominate — but lost on chunk
-        # (fixed 64k chunks keep the sort short while the scan pays its
-        # scatter on every chunk), so the flip is scoped to TPU + compact.
+        # LGBM_TPU_PARTITION wins on any backend). On the TPU the compact
+        # core takes scan: at 12M x 28 x 255 on a v5e (builder's chip
+        # runs, PR 27) scan with its scatters tiled (SCATTER_TILE_ROWS)
+        # reads 2.26M row-trees/s, and the sort formulation does not
+        # compile: its row gather gets the whole packed table laid out
+        # words-minor, 24.4 GB for the chip's 15.75. The chunk core keeps
+        # sort on a dated reading alone (v5e, 2026-08-01, 1M rows:
+        # 981,580 sort against 574,412 scan); at 12M rows it reads
+        # 579,342 against the compact core's 885,4xx untiled.
         self._partition_mode = partition_mode_env(
             default="scan" if (jax.default_backend() == "tpu"
                                and self.strategy == "compact") else "sort")
@@ -3113,6 +3198,29 @@ class DeviceTreeLearner:
                       * (cw + gw + 1) * 4)
         return {"mode": "resident", "bytes": int(total)}
 
+    def _count_partition_rows(self, rec) -> None:
+        """Program counters of how often the tiled partition engages:
+        `partition_rows`, the parent rows (by the split records' counts)
+        over the tree's splits, and `partition_tiled_rows`, the same over
+        the splits whose window — the smallest rung of the growth core's
+        ladder that holds this device's share of the parent — is more
+        than one scatter tile under the `scan` partition. The masked
+        core moves no rows and counts nothing."""
+        if self.strategy == "masked":
+            return
+        local_n = getattr(self, "local_n", self.dataset.num_data)
+        ladder = np.asarray(
+            _size_classes(local_n, step=self.window_step)
+            if self.strategy == "compact" else [self.chunk_rows])
+        parent = rec[:, R_LCNT].astype(np.float64) + rec[:, R_RCNT]
+        share = np.ceil(parent * (local_n / self.dataset.num_data))
+        rung = ladder[np.minimum(np.searchsorted(ladder, share),
+                                 len(ladder) - 1)]
+        tiled = (rung > SCATTER_TILE_ROWS) & (self._partition_mode == "scan")
+        telemetry.counters.incr("partition_rows", float(parent.sum()))
+        telemetry.counters.incr("partition_tiled_rows",
+                                float(parent[tiled].sum()))
+
     def replay_tree(self, rec_h, k: int, rec_cat_h=None) -> Tree:
         """Materialize a host Tree from the fetched (L-1, 13) split-record
         array (the one device->host transfer per tree). rec_cat_h carries
@@ -3121,6 +3229,7 @@ class DeviceTreeLearner:
         from .serial_learner import _make_bitset
         ds = self.dataset
         rec_h = np.asarray(rec_h)
+        self._count_partition_rows(rec_h[:k])
         tree = Tree(self.config.num_leaves)
         for i in range(k):
             r = rec_h[i]
