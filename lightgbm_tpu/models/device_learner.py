@@ -210,7 +210,7 @@ def _tree_helpers(base_mask, f_numbins, f_missing, f_default, f_monotone,
             f_monotone, mn, mx, f_penalty, None, **scan_kwargs)
         feat = jnp.argmax(rel).astype(jnp.int32)
         res = split_ops.materialize_split(
-            feat, rel, t, use_m1, prefix, sg, sh, cnt, mn, mx,
+            feat, rel, t, use_m1, prefix, mn, mx,
             l1=l1, l2=l2, max_delta_step=max_delta_step)
         if not has_cat:
             return res, jnp.zeros((cat_b,), jnp.float32)
@@ -652,7 +652,7 @@ def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
             jnp.take(f_penalty, elect), None, **scan_kwargs_global)
         fe = jnp.argmax(rel).astype(jnp.int32)
         res = split_ops.materialize_split(
-            fe, rel, t, use_m1, prefix, sg, sh, cnt, mn, mx,
+            fe, rel, t, use_m1, prefix, mn, mx,
             l1=l1, l2=l2, max_delta_step=max_delta_step)
         if has_cat:
             crel, caux = split_ops.per_feature_best_categorical(
@@ -2455,6 +2455,7 @@ class DeviceTreeLearner:
         self.dataset = dataset
         (self.f_numbins, self.f_missing, self.f_default,
          self.f_categorical, self.f_monotone) = dataset.feature_meta_arrays()
+        self._f_missing_host = np.asarray(self.f_missing)
         # categorical splits run inside the whole-tree program (scan-level
         # merge); gbdt's fused path checks cat_in_program before masking
         # categorical features out of the feature sample
@@ -2607,6 +2608,13 @@ class DeviceTreeLearner:
                     "width (%d cols); padding lever inactive",
                     pack_words, host_codes.shape[1])
             packed = self.pack_codes(host_codes, col_target=col_target)
+            # bytes a row of the working table the partition moves:
+            # packed code words + gradient words (the trivial-weight,
+            # unbagged layout) + the row id
+            self.table_row_bytes = 4 * (
+                packed.shape[1] + (1 if self.quant_bits > 0 else 3) + 1)
+            telemetry.counters.set_gauge("table_bytes_per_row",
+                                         self.table_row_bytes)
             if stream_on:
                 # host wire store + double-buffered H2D chunk pipeline;
                 # the device never holds a full copy of the binned rows
@@ -3191,11 +3199,8 @@ class DeviceTreeLearner:
             if a is not None and hasattr(a, "nbytes"):
                 total += int(a.nbytes)
         if self.strategy == "chunk" and self.codes_pack is not None:
-            quant = self.quant_bits > 0
-            gw = 1 if quant else 3  # trivial-weight (unbagged) layout
-            cw = int(self.codes_pack.shape[1])
             total += ((self.dataset.num_data + self.chunk_rows)
-                      * (cw + gw + 1) * 4)
+                      * self.table_row_bytes)
         return {"mode": "resident", "bytes": int(total)}
 
     def _count_partition_rows(self, rec) -> None:
@@ -3221,6 +3226,15 @@ class DeviceTreeLearner:
         telemetry.counters.incr("partition_tiled_rows",
                                 float(parent[tiled].sum()))
 
+    def _count_missing_splits(self, rec) -> None:
+        """Program counters of how often the default-direction path
+        decides a split: `splits`, and `splits_on_missing_feature`, those
+        whose feature has missing type zero or NaN."""
+        kinds = self._f_missing_host[rec[:, R_FEAT].astype(np.int64)]
+        telemetry.counters.incr("splits", float(len(rec)))
+        telemetry.counters.incr("splits_on_missing_feature",
+                                float(np.count_nonzero(kinds)))
+
     def replay_tree(self, rec_h, k: int, rec_cat_h=None) -> Tree:
         """Materialize a host Tree from the fetched (L-1, 13) split-record
         array (the one device->host transfer per tree). rec_cat_h carries
@@ -3230,6 +3244,7 @@ class DeviceTreeLearner:
         ds = self.dataset
         rec_h = np.asarray(rec_h)
         self._count_partition_rows(rec_h[:k])
+        self._count_missing_splits(rec_h[:k])
         tree = Tree(self.config.num_leaves)
         for i in range(k):
             r = rec_h[i]

@@ -85,6 +85,19 @@ def _split_gains(gl, hl, gr, hr, l1, l2, mds, min_c, max_c, mono):
     return jnp.where(violate, 0.0, gain)
 
 
+def _prefix_and_strict_suffix(x):
+    """Running sums of `x` (F, B, C) along the bins: the inclusive prefix
+    over [0..t] and the strict suffix over [t+1..B-1], each summed from
+    its own bins alone (never `whole - other side`), so each is accurate
+    to its own size. The suffix is a shifted inclusive sum and not
+    `inclusive - own bin`, which cancels where a small tail follows a
+    large bin."""
+    pre = jnp.cumsum(x, axis=1)
+    suf = jax.lax.cumsum(x, axis=1, reverse=True)
+    suf = jnp.concatenate([suf[:, 1:], jnp.zeros_like(suf[:, :1])], axis=1)
+    return pre, suf
+
+
 @jax.named_scope("lgbm.split_scan")
 def per_feature_best(
     hist: jax.Array,            # (F, B, 3) f32 [sum_grad, sum_hess, count]
@@ -121,30 +134,38 @@ def per_feature_best(
 
     # The (grad, hess, count) channels ride one (F, B, 3) array through
     # the accumulations and the two missing-directions stack into one
-    # leading axis, so the whole sweep is 2 cumsums + one stacked gain
-    # chain instead of 6 + 2 — this chain runs per split inside the
-    # whole-tree loop, where op count is latency (docs/DESIGN.md 6a-r3).
-    # Element-wise order is unchanged, so results are bit-identical.
+    # leading axis, so the whole sweep is one pair of running sums + one
+    # stacked gain chain instead of 6 + 2 — this chain runs per split
+    # inside the whole-tree loop, where op count is latency
+    # (docs/DESIGN.md 6a-r3).
+    #
+    # Both sides of every threshold are SUMMED FROM THE BINS, never formed
+    # as `leaf total - other side`: a child whose hessian sum is ~3 off a
+    # node whose sum is ~1e6 would otherwise be float32 rounding noise of
+    # the parent (the reference sums in double, hist_t). Each side is a
+    # prefix or a strict suffix of the scanned bins plus the mass that
+    # rides with the missing direction, so its error is relative to its
+    # own size. The two sides add up to the histogram's own total, which
+    # equals the leaf's handed-down total only to float32 rounding (the
+    # counts, exact integers below 2^24, still add up exactly).
 
-    # Zero-missing mode: the default bin never enters either accumulation,
-    # so its mass rides with `parent - accumulated`, i.e. the missing side.
+    # Zero-missing mode: the default bin never enters either accumulation;
+    # its mass rides with the missing side. NaN mode: the NaN bin (last)
+    # stays out of the dir=-1 suffix, so NaN goes left there.
     skip = is_zero & (tgrid == default_b)
-    eff = jnp.where(skip[:, :, None], 0.0, hist)
-
-    # dir=+1: left = prefix over bins [0..t]
-    pre = jnp.cumsum(eff, axis=1)                            # (F, B, 3)
-
-    # dir=-1: right = suffix over bins [t+1 .. last], where `last` excludes
-    # the NaN bin (so NaN goes left). suffix[t] computed via reversed cumsum.
     nan_excl = is_nan & (tgrid >= nbins - 1)                  # NaN bin mask
-    m1_eff = jnp.where(nan_excl[:, :, None], 0.0, eff)
-    # strict suffix sums: sum over j > t
-    suf = jnp.cumsum(m1_eff[:, ::-1, :], axis=1)[:, ::-1, :] - m1_eff
+    riding = (skip | nan_excl)[:, :, None]
+    scanned = jnp.where(riding, 0.0, hist)
+    # the skipped zero bin's or the NaN bin's (g, h, count): (F, 1, 3)
+    miss = jnp.sum(jnp.where(riding, hist, 0.0), axis=1, keepdims=True)
 
-    totals = jnp.stack([sum_grad, sum_hess, num_data])       # (3,)
-    # left sums per direction: p1 = prefix; m1 = total - suffix
-    left2 = jnp.stack([pre, totals[None, None, :] - suf])    # (2, F, B, 3)
-    right2 = totals[None, None, None, :] - left2
+    pre, suf = _prefix_and_strict_suffix(scanned)            # (F, B, 3) each
+
+    # dir=+1: missing rides right; dir=-1: missing rides left. At the
+    # valid thresholds (t < nbins - 1) the NaN bin lies above t, so `pre`
+    # holds none of it in either direction.
+    left2 = jnp.stack([pre, pre + miss])                     # (2, F, B, 3)
+    right2 = jnp.stack([suf + miss, suf])
 
     # valid threshold ranges per feature (reference loop bounds):
     #   dir=+1: t in [0, nb-2]; NaN mode unchanged (NaN bin can sit alone
@@ -202,26 +223,25 @@ def per_feature_best(
         per_feature_rel = jnp.where(per_feature_rel > NEG_INF / 2,
                                     per_feature_rel - feature_cost,
                                     per_feature_rel)
-    prefix = (pre, suf)
+    prefix = (pre, suf, miss)
     return per_feature_rel, per_feature_t, use_m1, prefix
 
 
 @jax.named_scope("lgbm.split_scan")
 def materialize_split(feat, per_feature_rel, per_feature_t, use_m1, prefix,
-                      sum_grad, sum_hess, num_data,
                       min_constraint, max_constraint,
                       *, l1, l2, max_delta_step) -> SplitResult:
     """Build the full SplitResult for one chosen feature."""
-    pre, suf = prefix
+    pre, suf, miss = prefix
     gain = per_feature_rel[feat]
     thr = per_feature_t[feat]
     dleft = use_m1[feat]
-    lg = jnp.where(dleft, sum_grad - suf[feat, thr, 0], pre[feat, thr, 0])
-    lh = jnp.where(dleft, sum_hess - suf[feat, thr, 1], pre[feat, thr, 1])
-    lc = jnp.where(dleft, num_data - suf[feat, thr, 2], pre[feat, thr, 2])
-    rg = sum_grad - lg
-    rh = sum_hess - lh
-    rc = num_data - lc
+    # both children from the bins, as the scan formed them
+    missing_mass = miss[feat, 0]
+    left = pre[feat, thr] + jnp.where(dleft, missing_mass, 0.0)
+    right = suf[feat, thr] + jnp.where(dleft, 0.0, missing_mass)
+    lg, lh, lc = left[0], left[1], left[2]
+    rg, rh, rc = right[0], right[1], right[2]
     lo = _leaf_output_constrained(lg, lh, l1, l2, max_delta_step,
                                   min_constraint, max_constraint)
     ro = _leaf_output_constrained(rg, rh, l1, l2, max_delta_step,
@@ -253,7 +273,7 @@ def find_best_split(
     feat = jnp.argmax(per_feature_rel).astype(jnp.int32)
     return materialize_split(
         feat, per_feature_rel, per_feature_t, use_m1, prefix,
-        sum_grad, sum_hess, num_data, min_constraint, max_constraint,
+        min_constraint, max_constraint,
         l1=l1, l2=l2, max_delta_step=max_delta_step)
 
 

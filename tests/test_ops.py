@@ -84,10 +84,14 @@ def test_split_scan_matches_bruteforce():
     f, b = 6, 16
     hist = np.abs(r.randn(f, b, 3)).astype(np.float32)
     hist[:, :, 0] = r.randn(f, b)
-    # force identical totals per feature (all features see all rows)
+    # force identical totals per feature (all features see all rows): the
+    # scan sums both children from the bins, so the bins have to add up
+    # to the leaf's totals. Hessians and counts (positive sums) are
+    # scaled; a gradient sum may be near zero, so its gap goes into bin 0
     totals = hist[0].sum(axis=0)
     for j in range(1, f):
-        hist[j] *= totals / np.maximum(hist[j].sum(axis=0), 1e-9)
+        hist[j, :, 1:] *= totals[1:] / hist[j, :, 1:].sum(axis=0)
+        hist[j, 0, 0] += totals[0] - hist[j, :, 0].sum()
     sum_g, sum_h, n = totals
     nbins = np.full(f, b, dtype=np.int32)
     res = split_ops.find_best_split(

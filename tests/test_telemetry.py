@@ -165,6 +165,27 @@ def test_counters_under_concurrent_batcher_threads():
         app.close()
 
 
+@pytest.mark.parametrize("kinds, feats, on_missing", [
+    ([0, 0, 0], [0, 1, 2, 2], 0),      # no feature has a missing type
+    ([0, 1, 2], [0, 1, 2, 2], 3),      # zero- and NaN-missing features
+    ([2, 2, 2], [], 0),                # a tree that did not split
+])
+def test_missing_split_counters(kinds, feats, on_missing):
+    """`splits` and `splits_on_missing_feature` (PR 29): fed once a tree
+    from the split records' features and the features' missing types."""
+    from types import SimpleNamespace
+    from lightgbm_tpu.models import device_learner as dl
+    rec = np.zeros((len(feats), 13), np.float32)
+    rec[:, dl.R_FEAT] = feats
+    learner = SimpleNamespace(_f_missing_host=np.asarray(kinds))
+    before = (counters.get("splits"),
+              counters.get("splits_on_missing_feature"))
+    dl.DeviceTreeLearner._count_missing_splits(learner, rec)
+    assert counters.get("splits") - before[0] == len(feats)
+    assert counters.get("splits_on_missing_feature") - before[1] \
+        == on_missing
+
+
 def test_compile_events_shared_counter():
     """The serving tests' XLA ground-truth counter now lives in
     telemetry.counters: a fresh jit compile appends events."""

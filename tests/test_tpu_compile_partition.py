@@ -43,3 +43,30 @@ def test_tiled_partition_compiles_to_tile_sized_scatters(one_chip, rows):
     window = set(re.findall(r"u32\[%d,%d\](\{[01],[01])" % (rows, d_cols),
                             txt))
     assert window == {"{0,1"}, window
+
+
+@pytest.mark.parametrize("features, d_cols", [(28, 11),     # `higgs`
+                                              (67, 21)])    # `criteo-share`
+def test_split_scan_and_wide_rows_compile(one_chip, features, d_cols):
+    """The split scan over a cell's (features, 256 bins) plane, with both
+    children summed from the bins (PR 29), and one tiled partition of the
+    cell's packed row width: what the chip's compiler refuses here costs
+    no chip time."""
+    from lightgbm_tpu.models import device_learner as dl
+    from lightgbm_tpu.ops import split as split_ops
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    scalar, per_f = shaped((), jnp.float32), shaped((features,), jnp.int32)
+    txt = split_ops.find_best_split.lower(
+        shaped((features, 256, 3), jnp.float32), scalar, scalar, scalar,
+        per_f, per_f, per_f, shaped((features,), jnp.bool_), per_f,
+        scalar, scalar, num_bins=256, l1=0.0, l2=0.0, max_delta_step=0.0,
+        min_data_in_leaf=20, min_sum_hessian=1e-3,
+        min_gain_to_split=0.0).compile().as_text()
+    assert "f32[%d,256,3]" % features in txt
+    rows = 2 * dl.SCATTER_TILE_ROWS
+    jax.jit(lambda w, k: dl.partition_window(w, k, "scan")).lower(
+        shaped((rows, d_cols), jnp.uint32),
+        shaped((rows,), jnp.int32)).compile()

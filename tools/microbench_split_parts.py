@@ -110,7 +110,7 @@ def scan_chain(i, a):
             min_gain_to_split=0.0)
         feat = jnp.argmax(rel).astype(jnp.int32)
         res = split_ops.materialize_split(
-            feat, rel, t, use_m1, prefix, tot[0], tot[1], tot[2],
+            feat, rel, t, use_m1, prefix,
             jnp.float32(-np.inf), jnp.float32(np.inf),
             l1=0.0, l2=0.0, max_delta_step=0.0)
         return res.gain
