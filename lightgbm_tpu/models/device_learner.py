@@ -54,7 +54,7 @@ from ..io.stream import DeviceDataShard
 from ..ops import bundle as bundle_ops
 from ..ops import quantize as quant_ops
 from ..ops import split as split_ops
-from ..ops.fused import run_split_loop
+from ..ops.fused import run_once_if, run_split_loop
 from ..ops.partition import decide_left
 from ..ops.pallas.histogram_kernel import build_histogram_pallas_t
 from .. import telemetry
@@ -471,9 +471,9 @@ class _CarryC(NamedTuple):
 
 
 def _size_classes(n: int, min_bucket: int = 4096, step: int = 4):
-    """Padded window-size ladder for the lax.switch dispatch. Smaller
-    step = tighter windows (less wasted per-split work, ~step/2 mean
-    inflation) but more traced branches (compile time); tunable via
+    """Padded window-size ladder of the compact core's split dispatch.
+    Smaller step = tighter windows (less wasted per-split work, ~step/2
+    mean inflation) but more traced rungs (compile time); tunable via
     LGBM_TPU_WINDOW_STEP (read once at learner init, threaded through
     as a static so the jit cache keys on it)."""
     ws = []
@@ -850,7 +850,13 @@ def grow_tree_compact_core(
     SMALLER child's contiguous half-window after the partition (sibling =
     parent - smaller, FeatureHistogram::Subtract). Dynamic leaf sizes meet
     XLA's static shapes through a small ladder of padded window classes
-    (x4 steps) dispatched with lax.switch — each class is traced once.
+    (x4 steps), each traced once: the rungs run one after another, each
+    a `while` of one trip or none (ops/fused.py run_once_if), so that
+    the packed buffer is a loop's carry from the tree's first split to
+    its last and every window is written back in place. (Dispatched
+    with lax.switch, the buffer crossed a `conditional` and the TPU
+    compiler copied all of it once a split on the way in and once on
+    the way out: PERF.md §6, PR 30.)
 
     pool_slots caps the histogram pool at K slots with on-device LRU
     eviction — the role of the reference's HistogramPool
@@ -1094,22 +1100,20 @@ def grow_tree_compact_core(
     def cond(c: _CarryC):
         return (c.k < L - 1) & (jnp.max(c.best[:, B_GAIN]) > 1e-10)
 
-    def make_branch(wsz: int):
+    hist_dtype = jnp.int32 if quant else jnp.float32
+
+    def make_rung(wsz: int):
         half = (wsz + 1) // 2
 
-        def branch(op):
-            if renew:
-                c, l, row, new_id, need_other, rq = op
-                rq_g, rq_h = rq
-            else:
-                c, l, row, new_id, need_other = op
-                rq_g = rq_h = jnp.float32(1.0)
+        def rung(data, begin, pcount, row, cat_mask, need_other, rq_g, rq_h):
+            """One split of a leaf whose rows fit a window of `wsz`:
+            `data` with the window partitioned in place, and the small
+            results (left count, child histograms, `qmax2` under
+            `renew`)."""
             feat = row[B_FEAT].astype(jnp.int32)
-            begin = c.leaf_begin[l]
-            pcount = c.leaf_phys[l]
 
             with jax.named_scope("lgbm.partition"):
-                win = jax.lax.dynamic_slice(c.data, (begin, 0), (wsz, d_cols))
+                win = jax.lax.dynamic_slice(data, (begin, 0), (wsz, d_cols))
                 valid = jnp.arange(wsz, dtype=jnp.int32) < pcount
             with jax.named_scope("lgbm.go_left"):
                 go_left = packed_go_left(
@@ -1117,7 +1121,7 @@ def grow_tree_compact_core(
                     row[B_DLEFT] > 0.5, f_numbins, f_missing, f_default,
                     f_col, f_base, f_elide, item_bits=item_bits,
                     f_categorical=f_categorical if has_cat else None,
-                    cat_mask=c.best_cat[l] if has_cat else None) & valid
+                    cat_mask=cat_mask) & valid
                 if renew:
                     # each child's stored-int maxes seed its leaf-local
                     # requant ratio (measured here: the window is in hand)
@@ -1132,17 +1136,14 @@ def grow_tree_compact_core(
                 key3 = jnp.where(valid, jnp.where(go_left, 0, 1), 2)
                 win_sorted = partition_window(win, key3, partition)
             with jax.named_scope("lgbm.table_update"):
-                data = jax.lax.dynamic_update_slice(c.data, win_sorted,
+                data = jax.lax.dynamic_update_slice(data, win_sorted,
                                                     (begin, 0))
             with jax.named_scope("lgbm.partition"):
                 lphys = jnp.sum(go_left.astype(jnp.int32))
                 rphys = pcount - lphys
             # pos_leaf / leaf_begin / leaf_phys updates happen OUTSIDE the
-            # switch (the body computes them from lphys): fewer branch
-            # outputs means fewer carry buffers crossing the conditional
-            # boundary, where XLA's copy insertion is conservative — the
-            # (N,)-sized pos_leaf update in particular cost a full-array
-            # copy per split here
+            # rung (the body computes them from lphys): the table is the
+            # one large buffer a rung carries
 
             # LOCAL histogram of the GLOBALLY smaller child (all shards
             # must hist the same side so the cross-shard sum is one
@@ -1155,7 +1156,6 @@ def grow_tree_compact_core(
                 left_small = row[B_LCNT] <= row[B_RCNT]
                 s_begin = jnp.where(left_small, 0, lphys)
                 s_count = jnp.where(left_small, lphys, rphys)
-                hist_dtype = jnp.int32 if quant else jnp.float32
 
                 def win_hist(rows2d, vbool):
                     """Histogram of a row window restricted to `vbool` rows —
@@ -1218,9 +1218,9 @@ def grow_tree_compact_core(
                                            hist_dtype)
             out = (data, lphys, hist_small, hist_other)
             return out + (qmax2,) if renew else out
-        return branch
+        return rung
 
-    branches = [make_branch(wsz) for wsz in classes]
+    rungs = [make_rung(wsz) for wsz in classes]
 
     def body(c: _CarryC, qx=None):
         with jax.named_scope("lgbm.leaf_select"):
@@ -1233,32 +1233,46 @@ def grow_tree_compact_core(
             slot_l = c.slot_of[l]
             have_parent = slot_l >= 0
             j = jnp.sum((pcount > thresholds).astype(jnp.int32))
-        # the dispatch over the window ladder belongs to `leaf_select`
-        # (which leaf, which rung); each branch names its own stages
         if renew:
             # the leaf's operand ratio comes from maxes recorded at its
-            # CREATION (replicated), so the branch needs no collective
+            # CREATION (replicated), so the rung needs no collective
             scale_of, leafmax = qx
             rq_g, rq_h = q_ratios(leafmax[l])
-            with jax.named_scope("lgbm.leaf_select"):
-                data, lphys, hist_small, hist_other, qmax2 = \
-                    jax.lax.switch(
-                        j, branches,
-                        (c, l, row, new_id, ~have_parent, (rq_g, rq_h)))
-            if axis_name is not None:
-                qmax2 = jax.lax.pmax(qmax2, axis_name)
         else:
             rq_g = rq_h = jnp.float32(1.0)
-            with jax.named_scope("lgbm.leaf_select"):
-                data, lphys, hist_small, hist_other = jax.lax.switch(
-                    j, branches, (c, l, row, new_id, ~have_parent))
-        with jax.named_scope("lgbm.table_update"):
+        # the dispatch over the window ladder belongs to `leaf_select`
+        # (which leaf, which rung); each rung names its own stages. The
+        # rungs run one after another, each once if it is the leaf's and
+        # not at all otherwise (run_once_if), so that the table is the
+        # carry of a `while` all the way and is updated in place; the
+        # small results start as zeros and the rung that runs sets them
+        with jax.named_scope("lgbm.leaf_select"):
+            zeros_hist = jnp.zeros((hist_cols, col_bins, 3), hist_dtype)
+            st = (c.data, jnp.int32(0), zeros_hist, zeros_hist)
+            if renew:
+                st += (jnp.zeros((2, 2), jnp.float32),)
+            cat_mask = c.best_cat[l] if has_cat else None
             begin = c.leaf_begin[l]
+            need_other = ~have_parent
+            for r, rung in enumerate(rungs):
+                with jax.named_scope(f"rung_{r}"):
+                    st = run_once_if(
+                        j == r,
+                        lambda s, rung=rung: rung(
+                            s[0], begin, pcount, row, cat_mask,
+                            need_other, rq_g, rq_h),
+                        st)
+        data, lphys, hist_small, hist_other = st[:4]
+        if renew:
+            qmax2 = st[4]
+            if axis_name is not None:
+                qmax2 = jax.lax.pmax(qmax2, axis_name)
+        with jax.named_scope("lgbm.table_update"):
             rphys = pcount - lphys
             leaf_begin = c.leaf_begin.at[new_id].set(begin + lphys)
             leaf_phys = c.leaf_phys.at[l].set(lphys).at[new_id].set(rphys)
             # O(N) elementwise pos_leaf rewrite (fuses to one in-place pass;
-            # cheaper than carrying the update through the conditional)
+            # cheaper than carrying the update through the rungs)
             posv = jnp.arange(n + wmax, dtype=jnp.int32)
             pos_leaf = jnp.where(
                 (posv >= begin) & (posv < begin + lphys), l,
@@ -1458,18 +1472,18 @@ def grow_tree_chunk_core(
         quant_bits: int = 0, quant_renew: bool = True,
         quant_total_rows: int = 0, data_prebuilt: bool = False,
         grow_program: str = "per_split"):
-    """Switch-free whole-tree growth over fixed-size chunks.
+    """Ladder-free whole-tree growth over fixed-size chunks.
 
-    The compact strategy resolves dynamic leaf sizes with a lax.switch
-    over padded window classes; XLA's copy insertion around that
-    conditional copies the packed working buffer once per split, and
-    every class duplicates the branch program. This variant removes the
-    conditional entirely: a split of a p-row leaf runs ceil(p / CH)
-    iterations of fixed-(CH, D)-shaped inner fori loops, so every carry
-    update is an unconditional dynamic_update_slice XLA aliases in
-    place, one traced partition program serves every leaf size, and the
+    The compact strategy resolves dynamic leaf sizes with a ladder of
+    padded window classes, and every class duplicates the rung's
+    program. This variant has no ladder: a split of a p-row leaf runs
+    ceil(p / CH) iterations of fixed-(CH, D)-shaped inner fori loops, so
+    one traced partition program serves every leaf size, and the
     per-split fixed cost is a handful of chunk passes instead of the
-    branch machinery.
+    rung machinery. Both cores keep the packed working buffer a loop's
+    carry throughout, so that every update of it is a
+    dynamic_update_slice XLA aliases in place (the compact core since
+    PR 30: its rungs are `while` loops, no `conditional`).
 
     Correctness of the in-place movement (reference DataPartition::Split
     semantics, stable 3-way):
@@ -2225,12 +2239,14 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     is never silently shadowed by a stale snapshot). `step.impl` and
     `step.obj_keys` are the contract surface for tests and tools
     (program-size pinning). On the same five arguments, `step.lower(...)`
-    lowers the program and `step.stage_map(...)` compiles it too and
-    maps its instructions to the `lgbm.<stage>` scopes
-    (telemetry.stage_map) — for a reader of a device trace, never on the
-    hot path. A warm persistent compile cache hands back the executable
-    as it was compiled, so a map from a cache entry older than the
-    scopes comes back empty."""
+    lowers the program, `step.stage_map(...)` compiles it too and maps
+    its instructions to the `lgbm.<stage>` scopes (telemetry.stage_map),
+    and `step.table_copies(...)` counts the copies of the packed table
+    inside the compiled split loop, by computation
+    (telemetry.table_copies_in_split_loop) — for a reader of a device
+    trace and for tests, never on the hot path. A warm persistent
+    compile cache hands back the executable as it was compiled, so a map
+    from a cache entry older than the scopes comes back empty."""
     def step(*args):
         return step_impl(*make_args(*args))
 
@@ -2240,8 +2256,12 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     step.impl = step_impl
     step.obj_keys = obj_keys
     step.lower = lower
-    step.stage_map = lambda *args: telemetry.stage_map(
-        lower(*args).compile().as_text())
+    def compiled_text(*args):
+        return lower(*args).compile().as_text()
+
+    step.stage_map = lambda *args: telemetry.stage_map(compiled_text(*args))
+    step.table_copies = lambda *args: telemetry.table_copies_in_split_loop(
+        compiled_text(*args))
     return step
 
 
@@ -2371,7 +2391,7 @@ def resolve_strategy(config: Config, dataset: Dataset,
     """Growth-strategy selection shared by __init__ and supports():
     compaction pays off once O(N)-per-split masked passes dominate;
     small data stays on the simpler masked program. 'chunk' is the
-    switch-free fixed-chunk formulation (opt-in pending on-chip A/B);
+    ladder-free fixed-chunk formulation (opt-in pending on-chip A/B);
     it requires the dense histogram pool, so LRU-capped configs fall
     back to compact."""
     strat = forced or strategy_env()

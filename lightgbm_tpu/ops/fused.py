@@ -310,3 +310,20 @@ def run_split_loop(cond, body, state, num_steps: int,
 
     out, _ = jax.lax.scan(_trip, state, None, length=num_steps)
     return out
+
+
+def run_once_if(pred, body, state):
+    """`body(state)` where `pred` holds, `state` untouched where it does
+    not: a ``lax.while_loop`` of one trip or none, and not a
+    ``lax.cond``. Chosen, like the forms above, for what the compiler
+    aliases: a ``while`` must alias its carry from init to result, so a
+    ``dynamic_update_slice`` of a large buffer in `body` happens in
+    place, where the TPU compiler copies a buffer on its way into a
+    ``conditional`` and again on its way out (the compact core's packed
+    table: a whole-table copy a split and another a rung; PERF.md §6,
+    PR 30). `body` returns the structure it is given."""
+    _, out = jax.lax.while_loop(
+        lambda s: s[0],
+        lambda s: (jnp.bool_(False), body(s[1])),
+        (jnp.asarray(pred, jnp.bool_), state))
+    return out

@@ -20,7 +20,10 @@ the bookkeeping on top:
 Inside the tree program the stages are `jax.named_scope("lgbm.<stage>")`
 (`STAGES`); `stage_map(hlo_text)` maps a compiled module's instructions
 to them, which is how a device trace (whose events carry instruction
-names and nothing else) is read by stage.
+names and nothing else) is read by stage, and
+`table_copies_in_split_loop(hlo_text)` counts the copies of the packed
+table the compiler left inside the split loop (none, while the table
+is updated in place).
 
 Built on top of those, the flight-recorder layer: `events` (durable
 structured per-iteration JSONL stream, `LGBM_TPU_EVENTS=path`),
@@ -58,7 +61,8 @@ __all__ = ["counters", "recorder", "spans", "span", "events", "watchdogs",
            "enabled", "resolve_mode", "configure", "dump_trace",
            "telemetry_summary", "phase_breakdown", "prometheus_text",
            "record_iteration", "reset", "xla_trace_active",
-           "note_grow_dispatches", "STAGES", "stage_map"]
+           "note_grow_dispatches", "STAGES", "stage_map",
+           "table_copies_in_split_loop"]
 
 MODES = ("off", "summary", "trace")
 _mode = "off"
@@ -75,25 +79,93 @@ STAGES = ("gradients", "root_hist", "leaf_select", "go_left", "partition",
 _HLO_INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\bop_name=\"([^\"]*)\"", re.M)
 _STAGE_IN_OP_NAME = re.compile(r"lgbm\.(\w+)")
-_RUNG_IN_OP_NAME = re.compile(r"branch_(\d+)_fun")
+_RUNG_IN_OP_NAME = re.compile(r"\brung_(\d+)")
 
 
 def stage_map(hlo_text: str) -> Dict[str, Tuple[str, Optional[int]]]:
     """{instruction name: (stage, rung or None)} of a compiled module's
     text (`jit(f).lower(...).compile().as_text()`): the instructions
     whose `op_name` runs through a `lgbm.<stage>` scope, under the
-    innermost such scope, with the branch index of the enclosing
-    `lax.switch` (the compact core's window ladder) as the rung. A
-    device trace names its "XLA Ops" events by the instruction's text,
-    which starts with this name; that is the join. Instructions the
-    compiler made itself (copies round a `while` or `conditional`) carry
-    no `op_name` and are not in the map."""
+    innermost such scope, with the index of the enclosing `rung_<r>`
+    scope (the compact core's window ladder) as the rung. A device
+    trace names its "XLA Ops" events by the instruction's text, which
+    starts with this name; that is the join. Instructions the compiler
+    made itself (copies round a `while` or `conditional`) carry no
+    `op_name` and are not in the map."""
     out = {}
     for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
         stages = _STAGE_IN_OP_NAME.findall(op_name)
         if stages:
             rung = _RUNG_IN_OP_NAME.findall(op_name)
             out[name] = (stages[-1], int(rung[-1]) if rung else None)
+    return out
+
+
+_HLO_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_U32_TABLE = re.compile(r"u32\[(\d+),(\d+)\]")
+
+
+def _computations(hlo_text: str) -> Dict[str, list]:
+    """{computation name: its instruction lines} of a module's text."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            cur = line.split("(")[0].split()[-1].lstrip("%")
+            comps[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    return comps
+
+
+def _split_loops(comps: Dict[str, list]) -> list:
+    """The `while` instructions whose `op_name` runs through no
+    `lgbm.<stage>` scope: the split loop (the others are loops INSIDE a
+    stage)."""
+    return [line for lines in comps.values() for line in lines
+            if re.search(r"\bwhile\(", line)
+            and not re.search(r'op_name="[^"]*lgbm\.', line)]
+
+
+def _reached_from_body(comps: Dict[str, list], loop: str) -> set:
+    """Names of the computations a `while` instruction's body reaches."""
+    todo = [re.search(r"body=%?([\w.\-]+)", loop).group(1)]
+    reached = set()
+    while todo:
+        comp = todo.pop()
+        if comp not in reached and comp in comps:
+            reached.add(comp)
+            for one, many in _HLO_CALLED.findall("\n".join(comps[comp])):
+                todo.extend([one] if one else
+                            [c.strip().lstrip("%") for c in many.split(",")])
+    return reached
+
+
+def table_copies_in_split_loop(hlo_text: str) -> Dict[str, int]:
+    """{computation: table-shaped copies in it} over the computations a
+    compiled module's split loop reaches; empty where the packed table
+    is updated in place, which is what the gauge
+    `table_copies_in_split_loop` (the sum) says. The table is the
+    largest `u32[rows,words]` of the split loop's carry; a copy is an
+    instruction `= u32[rows,words]{...} copy(`. The compiler puts one
+    where a buffer crosses a `conditional`: a whole-table copy a split
+    (PERF.md §6, PR 30). A module with no such loop reads empty."""
+    comps = _computations(hlo_text)
+    out: Dict[str, int] = {}
+    for loop in _split_loops(comps):
+        tables = _U32_TABLE.findall(loop.split(" while(")[0])
+        if not tables:
+            continue
+        rows, words = max(tables, key=lambda t: int(t[0]) * int(t[1]))
+        copy = re.compile(r"=\s*u32\[%s,%s\]\{[^}]*\}\s+copy\("
+                          % (rows, words))
+        for comp in _reached_from_body(comps, loop):
+            count = sum(1 for line in comps[comp] if copy.search(line))
+            if count:
+                out[comp] = count
     return out
 
 # -- XLA timeline (jax.profiler) under trace mode ---------------------------

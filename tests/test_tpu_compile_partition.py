@@ -3,10 +3,13 @@ attached, nothing runs): what only the TPU's compiler decides and the
 chip then pays for (PERF.md §6, PR 27). Past 2**18 indices it takes the
 slow row scatter, so no scatter may be wider than one tile; and layout
 assignment would hand the tile's words-minor layout (512 B for a 44-byte
-row) to every window-sized buffer, so none may have it. Only this file
-loads the TPU's library, inside the fixture."""
+row) to every window-sized buffer, so none may have it. And a buffer
+that crosses a `conditional` is copied on its way in and out, so the
+packed table may cross none inside the split loop (PR 30). Only this
+file loads the TPU's library, inside the fixture."""
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +73,63 @@ def test_split_scan_and_wide_rows_compile(one_chip, features, d_cols):
     jax.jit(lambda w, k: dl.partition_window(w, k, "scan")).lower(
         shaped((rows, d_cols), jnp.uint32),
         shaped((rows,), jnp.int32)).compile()
+
+
+COMPILE_LIMIT_S = 240
+
+
+@pytest.mark.parametrize("features, d_cols", [(28, 11),     # `higgs`
+                                              (67, 21)])    # `criteo-share`
+def test_packed_table_is_updated_in_place_in_the_split_loop(
+        one_chip, monkeypatch, features, d_cols):
+    """The compact core's tree program at a cell's row width, 300,000
+    rows (eight rungs of the window ladder, three over one scatter
+    tile), 15 leaves: no copy of the packed table `u32[2N, d_cols]` in
+    any computation the split loop reaches, and the table rows-minor
+    wherever it appears. With the table handed through a `conditional`
+    (the `lax.switch` over the rungs, before PR 30) the same count read
+    6, one copy in each of six of the eight branches."""
+    import numpy as np
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Dataset
+    from lightgbm_tpu.models import device_learner as dl
+    monkeypatch.setenv("LGBM_TPU_PARTITION", "scan")
+    rows = 300_000
+    # a toy learner of the cell's columns, for the per-feature arrays
+    # and the statics; the rows are shapes alone
+    r = np.random.RandomState(features)
+    cfg = Config({"objective": "binary", "num_leaves": 15, "max_bin": 255,
+                  "min_data_in_bin": 1, "verbosity": -1})
+    lrn = dl.DeviceTreeLearner(
+        cfg, Dataset(r.randn(4000, features).astype(np.float32), config=cfg,
+                     label=(r.rand(4000) > 0.5).astype(np.float64)),
+        strategy="compact")
+    grow, kwargs = lrn._grow_fn_kwargs(trivial_weights=True)
+    assert grow is dl.grow_tree_compact and kwargs["partition"] == "scan"
+    ladder = dl._size_classes(rows, step=kwargs["window_step"])
+    assert len(ladder) >= 3 and ladder[-1] > dl.SCATTER_TILE_ROWS
+
+    def shaped(a, lead=None):
+        shape = a.shape if lead is None else (lead,) + a.shape[1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=one_chip)
+
+    per_row = jnp.zeros((1,), jnp.float32)
+    meta = (lrn.f_numbins, lrn.f_missing, lrn.f_default, lrn.f_monotone,
+            lrn.f_penalty, lrn.f_categorical, lrn.f_col, lrn.f_base,
+            lrn.f_elide, lrn.hist_idx)
+    tick = time.perf_counter()
+    txt = grow.lower(
+        shaped(lrn.codes_pack, rows), shaped(lrn.codes_row, rows),
+        shaped(per_row, rows), shaped(per_row, rows), shaped(per_row, rows),
+        shaped(jnp.ones(features, bool)), *[shaped(m) for m in meta],
+        shaped(jax.random.PRNGKey(0)), **kwargs,
+        **lrn._statics()).compile().as_text()
+    assert time.perf_counter() - tick < COMPILE_LIMIT_S
+    table = r"u32\[%d,%d\]" % (2 * rows, d_cols)
+    assert any(re.search(table, line.split(" while(")[0])
+               for line in txt.splitlines() if " while(" in line), \
+        "the packed table is no loop's carry: the count below says nothing"
+    copies = telemetry.table_copies_in_split_loop(txt)
+    assert sum(copies.values()) == 0, copies
+    assert set(re.findall(table + r"(\{[01],[01])", txt)) == {"{0,1"}

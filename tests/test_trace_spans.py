@@ -138,13 +138,13 @@ HLO_TOY = """HloModule jit_step_impl, entry_computation_layout={()->f32[]}
 
 %fused_computation.1 (p: f32[4]) -> f32[4] {
   %p = f32[4]{0} parameter(0)
-  ROOT %scatter.9 = f32[4]{0} scatter(%p), metadata={op_name="jit(step_impl)/while/body/branch_2_fun/lgbm.partition/scatter"}
+  ROOT %scatter.9 = f32[4]{0} scatter(%p), metadata={op_name="jit(step_impl)/while/body/lgbm.leaf_select/rung_2/while/body/lgbm.partition/scatter"}
 }
 
 ENTRY %main (a: f32[4]) -> f32[4] {
   %a = f32[4]{0} parameter(0)
   %copy.7 = f32[4]{0} copy(%a)
-  %fusion.3 = f32[4]{0} fusion(%copy.7), kind=kLoop, calls=%fused_computation.1, metadata={op_type="scatter" op_name="jit(step_impl)/while/body/branch_2_fun/lgbm.partition/scatter" source_file="x.py" source_line=3}
+  %fusion.3 = f32[4]{0} fusion(%copy.7), kind=kLoop, calls=%fused_computation.1, metadata={op_type="scatter" op_name="jit(step_impl)/while/body/lgbm.leaf_select/rung_2/while/body/lgbm.partition/scatter" source_file="x.py" source_line=3}
   ROOT add.1 = f32[4]{0} add(%fusion.3, %a), metadata={op_name="jit(step_impl)/lgbm.split_epilogue/lgbm.split_scan/add"}
 }
 """
@@ -154,6 +154,71 @@ def test_stage_map_reads_innermost_scope_and_rung():
     assert telemetry.stage_map(HLO_TOY) == {
         "scatter.9": ("partition", 2), "fusion.3": ("partition", 2),
         "add.1": ("split_scan", None)}
+
+
+# the split loop (the one `while` under no stage) with the packed table
+# `u32[64,11]` in its carry; %s is the dispatch over two rungs
+HLO_SPLIT_LOOP = """HloModule jit_step_impl, entry_computation_layout={()->u32[64,11]}
+
+%%rung_small (p: (pred[], u32[64,11])) -> (pred[], u32[64,11]) {
+  %%p = (pred[], u32[64,11]{0,1:T(8,128)}) parameter(0)
+  %%table = u32[64,11]{0,1:T(8,128)} get-tuple-element(%%p), index=1
+%(rung_copy)s  %%window = u32[16,11]{0,1:T(8,128)} copy(%%slice.1)
+  ROOT %%tuple.1 = (pred[], u32[64,11]{0,1:T(8,128)}) tuple(%%done, %%dus.1)
+}
+
+%%rung_top (p: (pred[], u32[64,11])) -> (pred[], u32[64,11]) {
+  %%p = (pred[], u32[64,11]{0,1:T(8,128)}) parameter(0)
+  ROOT %%tuple.2 = (pred[], u32[64,11]{0,1:T(8,128)}) tuple(%%done, %%dus.2)
+}
+
+%%once (p: (pred[], u32[64,11])) -> pred[] {
+  ROOT %%todo = pred[] get-tuple-element(%%p), index=0
+}
+
+%%split_body (c: (s32[], u32[64,11], s32[64])) -> (s32[], u32[64,11], s32[64]) {
+  %%c = (s32[], u32[64,11]{0,1:T(8,128)}, s32[64]{0}) parameter(0)
+%(dispatch)s
+  ROOT %%tuple.3 = (s32[], u32[64,11]{0,1:T(8,128)}, s32[64]{0}) tuple(%%k, %%table.2, %%pos)
+}
+
+%%split_cond (c: (s32[], u32[64,11], s32[64])) -> pred[] {
+  ROOT %%more = pred[] compare(%%k, %%limit), direction=LT
+}
+
+ENTRY %%main (a: u32[64,11]) -> u32[64,11] {
+  %%a = u32[64,11]{0,1:T(8,128)} parameter(0)
+  %%copy.1 = u32[64,11]{0,1:T(8,128)} copy(%%a)
+  %%while.9 = (s32[], u32[64,11]{0,1:T(8,128)}, s32[64]{0}) while(%%tuple.0), condition=%%split_cond, body=%%split_body, metadata={op_name="jit(step_impl)/while"}
+  %%scan.2 = (s32[], f32[8]{0}) while(%%tuple.9), condition=%%once, body=%%rung_top, metadata={op_name="jit(step_impl)/lgbm.score_update/while"}
+  ROOT %%out = u32[64,11]{0,1:T(8,128)} get-tuple-element(%%while.9), index=1
+}
+"""
+_AS_CONDITIONAL = dict(
+    rung_copy="  %copy.5 = u32[64,11]{0,1:T(8,128)} copy(%table)\n",
+    dispatch="  %conditional.4 = (pred[], u32[64,11]{0,1:T(8,128)}) "
+             "conditional(%j, %arg, %arg), branch_computations="
+             "{%rung_small, %rung_top}, metadata={op_name=\"jit(step_impl)"
+             "/while/body/lgbm.leaf_select/switch\"}")
+_AS_LOOPS = dict(
+    rung_copy="",
+    dispatch="\n".join(
+        "  %%while.%d = (pred[], u32[64,11]{0,1:T(8,128)}) while(%%arg), "
+        "condition=%%once, body=%%%s, metadata={op_name=\"jit(step_impl)/while"
+        "/body/lgbm.leaf_select/rung_%d/while\"}" % (r, body, r)
+        for r, body in enumerate(("rung_small", "rung_top"))))
+
+
+@pytest.mark.parametrize("dispatch,copies", [
+    (_AS_CONDITIONAL, {"rung_small": 1}), (_AS_LOOPS, {})])
+def test_table_copies_are_counted_inside_the_split_loop_only(dispatch,
+                                                             copies):
+    """Counted: a copy of the carry's largest `u32` table in a
+    computation the split loop reaches. Not counted: the entry's one
+    copy, a window-sized copy, and the loops inside a stage."""
+    assert telemetry.table_copies_in_split_loop(
+        HLO_SPLIT_LOOP % dispatch) == copies
+    assert telemetry.table_copies_in_split_loop(HLO_TOY) == {}
 
 
 def test_every_named_scope_is_a_stage_and_every_stage_a_scope():
@@ -167,33 +232,7 @@ def test_every_named_scope_is_a_stage_and_every_stage_a_scope():
     assert used == set(telemetry.STAGES)
 
 
-def _computations(hlo_text):
-    """{computation name: its instruction lines} of a module's text."""
-    comps, cur = {}, None
-    for line in hlo_text.splitlines():
-        if line and not line[0].isspace() and line.rstrip().endswith("{"):
-            cur = line.split("(")[0].split()[-1].lstrip("%")
-            comps[cur] = []
-        elif line.startswith("}"):
-            cur = None
-        elif cur is not None:
-            comps[cur].append(line)
-    return comps
-
-
-_CALLED = re.compile(
-    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
-    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+([\w\-]+)\(")
-
-
-def _called(lines):
-    out = set()
-    for line in lines:
-        for one, many in _CALLED.findall(line):
-            out.update([one] if one else
-                       [c.strip().lstrip("%") for c in many.split(",")])
-    return out
 
 
 def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
@@ -205,6 +244,10 @@ def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
     text = step.lower(*_step_args(gbdt)).compile().as_text()
     stage_of = telemetry.stage_map(text)
     assert stage_of == step.stage_map(*_step_args(gbdt))
+    # (a count of the CPU compiler's copies here: what the TPU's leaves
+    # is tests/test_tpu_compile_partition.py's to say)
+    assert (step.table_copies(*_step_args(gbdt))
+            == telemetry.table_copies_in_split_loop(text))
     owned = {}
     for name, (stage, rung) in stage_of.items():
         owned.setdefault(stage, []).append((name, rung))
@@ -214,26 +257,21 @@ def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
     # everything heavy inside the split loop belongs to a stage: walk the
     # computations reachable from the body of the loop (the `while` whose
     # op_name is no stage's: the others are loops INSIDE a stage)
-    comps = _computations(text)
-    loops = [line for lines in comps.values() for line in lines
-             if re.search(r"\bwhile\(", line)
-             and not re.search(r'op_name="[^"]*lgbm\.', line)]
+    comps = telemetry._computations(text)
+    loops = telemetry._split_loops(comps)
     assert len(loops) == 1, loops
-    todo = [re.search(r"body=%?([\w.\-]+)", loops[0]).group(1)]
-    reached = set()
-    while todo:
-        comp = todo.pop()
-        if comp not in reached:
-            reached.add(comp)
-            todo.extend(_called(comps[comp]))
-    # (an instruction without `op_name` is the compiler's own — the
-    # pieces it cuts a cumsum into, say — and no scope can reach it)
+    reached = telemetry._reached_from_body(comps, loops[0])
+    # (an instruction without `op_name`, or with one that stops short of
+    # the loop, is the compiler's own — the pieces it cuts a cumsum into,
+    # the zeros a rung loop's carry starts from, sunk into the body as a
+    # constant — and no scope can reach it)
     unstaged, checked = [], 0
     for comp in reached:
         for line in comps[comp]:
             m = _INSTRUCTION.match(line)
-            if (m and "op_name=" in line and m.group(2) in (
-                    "scatter", "dot", "convolution", "fusion")):
+            if (m and re.search(r'op_name="[^"]*/while/body', line)
+                    and m.group(2) in (
+                        "scatter", "dot", "convolution", "fusion")):
                 checked += 1
                 if m.group(1) not in stage_of:
                     unstaged.append(line.strip()[:240])
