@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 import lightgbm_tpu as lgb
-from bench import (host_predict_raw, make_higgs_like, make_ranking_like,
-                   rank_auc)
 from lightgbm_tpu.cli import _serve
 from lightgbm_tpu.models.device_learner import DeviceTreeLearner
 from lightgbm_tpu.models.serial_learner import SerialTreeLearner
@@ -49,6 +47,126 @@ N_FEATURES = 28
 # splits per tree, so leaf outputs agree to ~1e-4 of the score scale
 # unless a near-tie gain flips a split — which the leg reports as such.
 REFERENCE_RTOL = 1e-3
+
+
+def make_higgs_like(n, f, seed=17, w=None, n_cat=0, card=64, n_classes=1):
+    """Synthetic stand-in with Higgs-like statistics: mixed informative /
+    noise features, moderately separable classes. Pass `w` to draw a new
+    sample from the SAME ground-truth function (e.g. a held-out valid set)
+    without perturbing the default stream. n_cat > 0 converts the
+    LAST n_cat columns into categorical features (cardinality `card`)
+    with per-category target effects — the Expo/Allstate-style
+    categorical-heavy shape (reference docs/Experiments.rst datasets).
+
+    `w` is a `(w_num, cat_tables)` tuple; `cat_tables` is `[]` at
+    n_cat=0."""
+    r = np.random.RandomState(seed)
+    x = r.randn(n, f).astype(np.float32)
+    if w is None:
+        w_num = r.randn(f) * (r.rand(f) > 0.4)
+        cat_tables = [r.randn(card) * 0.5 for _ in range(n_cat)]
+        w = (w_num, cat_tables)
+    w_num, cat_tables = w
+    if cat_tables:
+        # categorical columns must not leak their pre-overwrite Gaussian
+        # draws into the label (unobservable noise would depress the
+        # categorical run's AUC)
+        w_num = w_num.copy()
+        w_num[f - len(cat_tables):] = 0.0
+    logit = x @ w_num * 0.3 + 0.2 * x[:, 0] * x[:, 1] - 0.1 * x[:, 2] ** 2
+    for j in range(len(cat_tables)):
+        cats = r.randint(0, card, n)
+        x[:, f - len(cat_tables) + j] = cats
+        logit += cat_tables[j][cats]
+    if n_classes > 1:
+        # large-K multiclass variant: margin quantiles become balanced
+        # K-class labels (class 0 = lowest margin). The one-vs-rest
+        # structure keeps an AUC-style gate usable — class-0 margin vs
+        # (label == 0) is the same separability the binary label has.
+        noisy = logit + r.randn(n) * 1.5
+        edges = np.quantile(noisy, np.linspace(0, 1, n_classes + 1)[1:-1])
+        y = np.searchsorted(edges, noisy).astype(np.float64)
+        return x, y, w
+    y = (logit + r.randn(n) * 1.5 > 0).astype(np.float64)
+    return x, y, w
+
+
+def make_ranking_like(n_queries, docs_per_query, f, seed=17, w=None):
+    """Synthetic learning-to-rank set: query-grouped docs with graded
+    relevance 0..4. Per-query context vectors shift the document score
+    so ranking signal is intra-query (the shape LambdaRank exploits);
+    pass `w` to draw a held-out sample from the SAME ground truth."""
+    r = np.random.RandomState(seed)
+    n = n_queries * docs_per_query
+    x = r.randn(n, f).astype(np.float32)
+    if w is None:
+        w = r.randn(f) * (r.rand(f) > 0.4)
+    ctx = np.repeat(r.randn(n_queries, 1) * 0.5, docs_per_query, axis=0)
+    score = x @ w * 0.4 + 0.2 * x[:, 0] * x[:, 1] + ctx[:, 0] \
+        + r.randn(n) * 0.8
+    # grade into 0..4 by global quantile so every query mixes grades
+    edges = np.quantile(score, [0.5, 0.75, 0.9, 0.97])
+    y = np.digitize(score, edges).astype(np.float64)
+    group = np.full(n_queries, docs_per_query, dtype=np.int64)
+    return x, y, group, w
+
+
+def host_predict_raw(models, x):
+    """Vectorized numpy ensemble traversal (numerical + categorical
+    bitset splits; no-NaN data — exactly this script's generators). Keeps
+    ALL evaluation off the device: a mid-training predict would
+    otherwise compile a fresh ensemble program per tree-count."""
+    out = np.zeros(x.shape[0], dtype=np.float64)
+    for t in models:
+        if t.num_leaves <= 1:
+            out += float(t.leaf_value[0])
+            continue
+        sf = np.asarray(t.split_feature, dtype=np.int32)
+        thr = np.asarray(t.threshold, dtype=np.float64)
+        lc = np.asarray(t.left_child, dtype=np.int32)
+        rc = np.asarray(t.right_child, dtype=np.int32)
+        lv = np.asarray(t.leaf_value, dtype=np.float64)
+        iscat = (np.asarray(t.decision_type, dtype=np.int32) & 1) != 0
+        cat_lo = np.asarray(t.cat_boundaries, dtype=np.int64)
+        cat_words = np.asarray(t.cat_threshold or [0], dtype=np.uint32)
+        node = np.zeros(x.shape[0], dtype=np.int32)
+        active = np.ones(x.shape[0], dtype=bool)
+        while active.any():
+            idx = np.nonzero(active)[0]
+            nd = node[idx]
+            v = x[idx, sf[nd]]
+            go_left = v <= thr[nd]
+            cn = iscat[nd]
+            if cn.any():
+                # categorical bitset routing (tree._cat_contains,
+                # vectorized): out-of-range or negative values go right
+                ci = thr[nd].astype(np.int64)
+                vi = np.where(cn & (v >= 0), v, 0).astype(np.int64)
+                word = vi // 32
+                nwords = cat_lo[np.clip(ci + 1, 0, len(cat_lo) - 1)] \
+                    - cat_lo[np.clip(ci, 0, len(cat_lo) - 1)]
+                inb = cn & (v >= 0) & (word < nwords)
+                wofs = np.clip(cat_lo[np.clip(ci, 0, len(cat_lo) - 1)]
+                               + word, 0, len(cat_words) - 1)
+                bit = (cat_words[wofs] >> (vi % 32).astype(np.uint32)) & 1
+                go_left = np.where(cn, inb & (bit == 1), go_left)
+            node[idx] = np.where(go_left, lc[nd], rc[nd])
+            active[idx] = node[idx] >= 0
+        out += lv[~node]
+    return out
+
+
+def rank_auc(scores, labels):
+    """Tie-aware (mid-rank) AUC: few-tree models collapse many rows onto
+    identical score sums; ordinal ranks would credit tied pos/neg pairs
+    0-or-1 by row order instead of 0.5."""
+    _, inv, counts = np.unique(scores, return_inverse=True,
+                               return_counts=True)
+    avg_rank = np.cumsum(counts) - counts + (counts + 1) / 2.0
+    ranks = avg_rank[inv]
+    pos = labels > 0
+    return float((ranks[pos].sum() - pos.sum() * (pos.sum() + 1) / 2)
+                 / max(pos.sum() * (~pos).sum(), 1))
 
 
 def parse_args():
@@ -132,7 +250,6 @@ class Smoke:
         gbdt, learner = bst._gbdt, bst._gbdt.learner
         chose = {"learner": type(learner).__name__,
                  "strategy": learner.strategy,
-                 "partition": learner._partition_mode,
                  "fused_step": bool(gbdt._fused_step),
                  "pipeline": bool(gbdt._pipeline),
                  "grow_dispatches_per_tree": per_tree,
@@ -143,7 +260,6 @@ class Smoke:
         assert per_tree == 1.0, chose
         if not self.rehearsal and self.rows >= 65_536:
             assert chose["strategy"] == "compact", chose
-            assert chose["partition"] == "scan", chose
             assert chose["pipeline"], chose
         assert len(models) == self.iters, len(models)
         out = dict(chose, rows=self.rows, iters=self.iters,

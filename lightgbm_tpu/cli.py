@@ -309,8 +309,7 @@ def _train(params: Dict[str, str], cfg: Config) -> None:
     log.info("Finished training in %.3f seconds", time.time() - t0)
     from . import telemetry
     if telemetry.enabled():
-        # one-line JSON so CLI logs are grep-able the same way bench.py
-        # and tools/profile_iter.py outputs are
+        # one-line JSON so that CLI logs can be grepped
         import json
         log.info("telemetry summary: %s",
                  json.dumps(telemetry.telemetry_summary()))

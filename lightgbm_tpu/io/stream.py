@@ -1,7 +1,7 @@
 """Out-of-core streaming: host-resident compressed bins, chunked H2D.
 
 Every training path before this layer assumed the full binned matrix is
-device-resident; `tools/nscale_probe.py` showed the HBM wall turning
+device-resident; a dated N-scaling probe showed the HBM wall turning
 into a ~4x-worse-than-linear throughput knee at the 10.5M reference
 scale (ROADMAP item 1). The out-of-core GPU GBDT literature
 (arXiv:2005.09148, arXiv:1806.11248) recovers near-resident throughput

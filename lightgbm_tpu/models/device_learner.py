@@ -62,8 +62,7 @@ from ..telemetry import spans as telem_spans
 from ..telemetry import recorder as telem
 from ..utils import log
 from ..utils.log import LightGBMError
-from ..utils.envs import (flag, partition_mode_env, strategy_env,
-                          use_pallas_env)
+from ..utils.envs import flag, strategy_env, use_pallas_env
 from .tree import Tree
 
 NEG_INF = split_ops.NEG_INF
@@ -470,17 +469,24 @@ class _CarryC(NamedTuple):
     key: jax.Array
 
 
-def _size_classes(n: int, min_bucket: int = 4096, step: int = 4):
-    """Padded window-size ladder of the compact core's split dispatch.
-    Smaller step = tighter windows (less wasted per-split work, ~step/2
-    mean inflation) but more traced rungs (compile time); tunable via
-    LGBM_TPU_WINDOW_STEP (read once at learner init, threaded through
-    as a static so the jit cache keys on it)."""
+# Ratio of one rung of the compact core's window ladder to the next. A
+# smaller step = tighter windows (less wasted per-split work, ~step/2 mean
+# inflation) but more traced rungs (compile time). 2 measured fastest on
+# the chip (754k vs 679k row-trees/s at step 4, 1M x 255 leaves:
+# docs/DESIGN.md 6a-r3) and is what both benchmark cells run. It is no
+# static of the jitted growth programs: a test that changes it clears
+# their caches, as for SCATTER_TILE_ROWS.
+WINDOW_STEP = 2
+
+
+def _size_classes(n: int, min_bucket: int = 4096):
+    """Padded window-size ladder of the compact core's split dispatch:
+    min_bucket, then WINDOW_STEP times the last while under n, then n."""
     ws = []
     wcur = min_bucket
     while wcur < n:
         ws.append(wcur)
-        wcur *= step
+        wcur *= WINDOW_STEP
     ws.append(n)
     return ws
 
@@ -498,8 +504,8 @@ def _unpack_codes(words: jax.Array, c_cols: int, item_bits: int) -> jax.Array:
     jax.jit,
     static_argnames=("c_cols", "item_bits",
                      "num_leaves", "num_bins", "col_bins", "max_depth",
-                     "bynode_k", "use_pallas", "partition",
-                     "pool_slots", "window_step", "trivial_weights",
+                     "bynode_k", "use_pallas",
+                     "pool_slots", "trivial_weights",
                      "cat_statics", "quant_bits", "quant_renew",
                      "grow_program"))
 def grow_tree_compact(
@@ -514,8 +520,7 @@ def grow_tree_compact(
         l1: float, l2: float, max_delta_step: float,
         min_data_in_leaf: int, min_sum_hessian: float,
         min_gain_to_split: float, bynode_k: int, use_pallas: bool,
-        partition: str = "sort",
-        pool_slots: int = 0, window_step: int = 4,
+        pool_slots: int = 0,
         trivial_weights: bool = False, cat_statics=None,
         quant_bits: int = 0, quant_renew: bool = True,
         grow_program: str = "per_split"):
@@ -528,9 +533,9 @@ def grow_tree_compact(
         l1=l1, l2=l2, max_delta_step=max_delta_step,
         min_data_in_leaf=min_data_in_leaf, min_sum_hessian=min_sum_hessian,
         min_gain_to_split=min_gain_to_split, bynode_k=bynode_k,
-        use_pallas=use_pallas, partition=partition,
+        use_pallas=use_pallas,
         axis_name=None, pool_slots=pool_slots,
-        window_step=window_step, trivial_weights=trivial_weights,
+        trivial_weights=trivial_weights,
         cat_statics=cat_statics, quant_bits=quant_bits,
         quant_renew=quant_renew, grow_program=grow_program)
 
@@ -689,11 +694,8 @@ def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
     # split instead of two sequential ones — half the collective
     # latency on real ICI. XLA:CPU's collective rendezvous fatally
     # aborts on the batched form under the virtual mesh (hard 40s
-    # timeout, observed round 2), so the lever defaults to
-    # backend-keyed auto. LGBM_TPU_VOTING_BATCHED=0/1 overrides.
-    vb_env = _env("LGBM_TPU_VOTING_BATCHED", "auto")
-    voting_batched = (jax.default_backend() == "tpu"
-                      if vb_env == "auto" else vb_env == "1")
+    # timeout, observed round 2), so the choice is keyed on the backend.
+    voting_batched = jax.default_backend() == "tpu"
 
     def search2_rows(col_hist2, sg2, sh2, cnt2, mn2, mx2, keys2,
                      child_depth):
@@ -829,9 +831,8 @@ def grow_tree_compact_core(
         l1: float, l2: float, max_delta_step: float,
         min_data_in_leaf: int, min_sum_hessian: float,
         min_gain_to_split: float, bynode_k: int, use_pallas: bool,
-        partition: str = "sort",
         axis_name=None, pool_slots: int = 0, scatter_cols: int = 0,
-        feature_shards: int = 0, voting_k: int = 0, window_step: int = 4,
+        feature_shards: int = 0, voting_k: int = 0,
         trivial_weights: bool = False, cat_statics=None,
         quant_bits: int = 0, quant_renew: bool = True,
         quant_total_rows: int = 0, grow_program: str = "per_split"):
@@ -1007,7 +1008,7 @@ def grow_tree_compact_core(
         def decode_for_hist(words2d):
             return _unpack_codes(words2d[:, :cw], c_cols, item_bits)
 
-    classes = _size_classes(n, step=window_step)
+    classes = _size_classes(n)
     wmax = classes[-1]
     thresholds = jnp.asarray(np.array(classes[:-1], np.int32))
     d_cols = cw + gw + 1
@@ -1134,7 +1135,7 @@ def grow_tree_compact_core(
             # contiguous), so they return to their slots untouched
             with jax.named_scope("lgbm.partition"):
                 key3 = jnp.where(valid, jnp.where(go_left, 0, 1), 2)
-                win_sorted = partition_window(win, key3, partition)
+                win_sorted = partition_window(win, key3)
             with jax.named_scope("lgbm.table_update"):
                 data = jax.lax.dynamic_update_slice(data, win_sorted,
                                                     (begin, 0))
@@ -1417,7 +1418,7 @@ class _CarryK(NamedTuple):
     jax.jit,
     static_argnames=("c_cols", "item_bits",
                      "num_leaves", "num_bins", "col_bins", "max_depth",
-                     "bynode_k", "use_pallas", "partition",
+                     "bynode_k", "use_pallas",
                      "chunk_rows", "fuse_hist", "feature_shards",
                      "cat_statics", "trivial_weights", "quant_bits",
                      "quant_renew", "data_prebuilt", "grow_program"))
@@ -1432,7 +1433,7 @@ def grow_tree_chunk(
         l1: float, l2: float, max_delta_step: float,
         min_data_in_leaf: int, min_sum_hessian: float,
         min_gain_to_split: float, bynode_k: int, use_pallas: bool,
-        partition: str = "sort", chunk_rows: int = 65536,
+        chunk_rows: int = 65536,
         fuse_hist: bool = True, feature_shards: int = 0,
         cat_statics=None, trivial_weights: bool = False,
         quant_bits: int = 0, quant_renew: bool = True,
@@ -1446,7 +1447,7 @@ def grow_tree_chunk(
         l1=l1, l2=l2, max_delta_step=max_delta_step,
         min_data_in_leaf=min_data_in_leaf, min_sum_hessian=min_sum_hessian,
         min_gain_to_split=min_gain_to_split, bynode_k=bynode_k,
-        use_pallas=use_pallas, partition=partition, chunk_rows=chunk_rows,
+        use_pallas=use_pallas, chunk_rows=chunk_rows,
         fuse_hist=fuse_hist, feature_shards=feature_shards,
         axis_name=None, cat_statics=cat_statics,
         trivial_weights=trivial_weights, quant_bits=quant_bits,
@@ -1465,7 +1466,7 @@ def grow_tree_chunk_core(
         l1: float, l2: float, max_delta_step: float,
         min_data_in_leaf: int, min_sum_hessian: float,
         min_gain_to_split: float, bynode_k: int, use_pallas: bool,
-        partition: str = "sort", chunk_rows: int = 65536,
+        chunk_rows: int = 65536,
         fuse_hist: bool = True, feature_shards: int = 0,
         scatter_cols: int = 0, voting_k: int = 0,
         axis_name=None, cat_statics=None, trivial_weights: bool = False,
@@ -1867,7 +1868,19 @@ def grow_tree_chunk_core(
                 qmx = jnp.maximum(
                     qmx, _quant_side_maxes(win, gl, valid, cw=cw, gw=gw))
             key3 = jnp.where(gl, 0, jnp.where(valid, 1, 2))
-            win_s = partition_window(win, key3, partition)
+            # the chunk core's own partition, a stable argsort + row
+            # gather of one fixed chunk: on `higgs-train` (12M x 28 x 255,
+            # one TPU v5 lite; builder's chip runs, PR 31) it reads
+            # 579,169 row-trees/s against 446,614 with the compact core's
+            # scan (partition_window) in its place, `correct` true and
+            # the compared numbers the same digit for digit. The gather
+            # does not compile at a window of the whole table (24.4 GB
+            # for the chip's 15.75, PR 27), which is why it lives here
+            # and not in partition_window.
+            with jax.named_scope("lgbm.partition"):
+                win_s = jnp.take(
+                    win, jnp.argsort(key3.astype(jnp.int8), stable=True),
+                    axis=0)
             lc = jnp.sum(gl.astype(jnp.int32))
             vc = jnp.sum(valid.astype(jnp.int32))
             d_old = jax.lax.dynamic_slice(
@@ -2182,24 +2195,21 @@ def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
 
 
 @jax.named_scope("lgbm.partition")
-def partition_window(win: jax.Array, key3: jax.Array, partition: str,
+def partition_window(win: jax.Array, key3: jax.Array,
                      tile_rows: Optional[int] = None) -> jax.Array:
-    """Stable 3-way reorder of a (W, D) u32 window by key3 in {0,1,2} —
-    the ONE dispatch over the partition formulations (reference
-    DataPartition::Split role), shared by the compact branches and the
-    chunk passes. 'sort' = argsort+take; 'scan' = per-class exclusive
-    ranks via cumsum + one row scatter (no sort passes), tile by tile
-    where the window holds more than SCATTER_TILE_ROWS rows, and then
-    only for key-2 rows that are the window's tail (see
-    _scan_partition_tiled). `tile_rows` is for tests."""
-    if partition == "scan":
-        if tile_rows is None:
-            tile_rows = SCATTER_TILE_ROWS
-        if win.shape[0] > tile_rows:
-            return _scan_partition_tiled(win, key3, tile_rows)
-        return _scan_partition(win, key3)[0]
-    order = jnp.argsort(key3.astype(jnp.int8), stable=True)
-    return jnp.take(win, order, axis=0)
+    """Stable 3-way reorder of a (W, D) u32 window by key3 in {0,1,2}
+    (reference DataPartition::Split role), the compact core's one
+    partition on every platform: per-class exclusive ranks via cumsum +
+    one row scatter (no sort passes), tile by tile where the window
+    holds more than SCATTER_TILE_ROWS rows, and then only for key-2 rows
+    that are the window's tail (see _scan_partition_tiled). The chunk
+    core sorts its fixed chunks instead (pass B, with the reading that
+    keeps it). `tile_rows` is for tests."""
+    if tile_rows is None:
+        tile_rows = SCATTER_TILE_ROWS
+    if win.shape[0] > tile_rows:
+        return _scan_partition_tiled(win, key3, tile_rows)
+    return _scan_partition(win, key3)[0]
 
 
 @jax.named_scope("lgbm.go_left")
@@ -2555,19 +2565,6 @@ class DeviceTreeLearner:
         self.hist_chunk = int(config.hist_chunk_size or 0)
         requested = strategy or strategy_env()
         self.strategy = resolve_strategy(config, dataset, strategy)
-        # partition formulation: sort | scan (an explicit
-        # LGBM_TPU_PARTITION wins on any backend). On the TPU the compact
-        # core takes scan: at 12M x 28 x 255 on a v5e (builder's chip
-        # runs, PR 27) scan with its scatters tiled (SCATTER_TILE_ROWS)
-        # reads 2.26M row-trees/s, and the sort formulation does not
-        # compile: its row gather gets the whole packed table laid out
-        # words-minor, 24.4 GB for the chip's 15.75. The chunk core keeps
-        # sort on a dated reading alone (v5e, 2026-08-01, 1M rows:
-        # 981,580 sort against 574,412 scan); at 12M rows it reads
-        # 579,342 against the compact core's 885,4xx untiled.
-        self._partition_mode = partition_mode_env(
-            default="scan" if (jax.default_backend() == "tpu"
-                               and self.strategy == "compact") else "sort")
         if requested == "chunk" and self.strategy != "chunk":
             log.warning("chunk strategy needs the dense histogram pool; "
                         "using compact (LRU-capped) instead")
@@ -2581,10 +2578,6 @@ class DeviceTreeLearner:
                 "masked strategy at %d rows x %d leaves compiles very "
                 "slowly; compact or chunk is strongly recommended",
                 dataset.num_data, int(config.num_leaves))
-        # default 2 measured fastest on-chip (754k vs 679k row-trees/s at
-        # step 4, 1M x 255 leaves — docs/DESIGN.md 6a-r3): the tighter
-        # ladder's lower window inflation beats its extra compile time
-        self.window_step = max(2, int(_env("LGBM_TPU_WINDOW_STEP", "2")))
         self.chunk_rows = max(8192, int(_env("LGBM_TPU_CHUNK", "65536")))
         # LRU-capped histogram pool: when the dense (L,C,B,3) pool would
         # exceed the budget, the compact strategy runs with K LRU slots
@@ -2615,19 +2608,7 @@ class DeviceTreeLearner:
             else:
                 self.item_bits = 8
             self.c_cols = host_codes.shape[1]
-            # LGBM_TPU_PACK_WORDS pads the packed code section to a fixed
-            # u32-word width: row gathers on TPU are latency-bound per
-            # row, so wider rows may reach DMA bandwidth (A/B lever for
-            # the partition cost; costs memory proportionally)
-            pack_words = int(_env("LGBM_TPU_PACK_WORDS", "0"))
-            col_target = (pack_words * (32 // self.item_bits)
-                          if pack_words > 0 else None)
-            if col_target is not None and col_target < host_codes.shape[1]:
-                log.warning(
-                    "LGBM_TPU_PACK_WORDS=%d is below the natural packed "
-                    "width (%d cols); padding lever inactive",
-                    pack_words, host_codes.shape[1])
-            packed = self.pack_codes(host_codes, col_target=col_target)
+            packed = self.pack_codes(host_codes)
             # bytes a row of the working table the partition moves:
             # packed code words + gradient words (the trivial-weight,
             # unbagged layout) + the row id
@@ -2934,14 +2915,11 @@ class DeviceTreeLearner:
                 c_cols=self.c_cols, item_bits=self.item_bits,
                 chunk_rows=self.chunk_rows,
                 fuse_hist=not flag("LGBM_TPU_CHUNK_NO_FUSE_HIST"),
-                partition=self._partition_mode,
                 trivial_weights=trivial,
                 quant_bits=self.quant_bits, quant_renew=self.quant_renew)
         return grow_tree_compact, dict(
             c_cols=self.c_cols, item_bits=self.item_bits,
-            pool_slots=self.pool_slots, window_step=self.window_step,
-            trivial_weights=trivial,
-            partition=self._partition_mode,
+            pool_slots=self.pool_slots, trivial_weights=trivial,
             quant_bits=self.quant_bits, quant_renew=self.quant_renew)
 
     def _run_grow(self, grad, hess, w, base_mask, key):
@@ -3229,22 +3207,23 @@ class DeviceTreeLearner:
         over the tree's splits, and `partition_tiled_rows`, the same over
         the splits whose window — the smallest rung of the growth core's
         ladder that holds this device's share of the parent — is more
-        than one scatter tile under the `scan` partition. The masked
-        core moves no rows and counts nothing."""
+        than one scatter tile. The chunk core sorts its chunks and the
+        masked core moves no rows: neither tiles, and the masked core
+        counts nothing."""
         if self.strategy == "masked":
             return
-        local_n = getattr(self, "local_n", self.dataset.num_data)
-        ladder = np.asarray(
-            _size_classes(local_n, step=self.window_step)
-            if self.strategy == "compact" else [self.chunk_rows])
         parent = rec[:, R_LCNT].astype(np.float64) + rec[:, R_RCNT]
+        telemetry.counters.incr("partition_rows", float(parent.sum()))
+        if self.strategy != "compact":
+            return
+        local_n = getattr(self, "local_n", self.dataset.num_data)
+        ladder = np.asarray(_size_classes(local_n))
         share = np.ceil(parent * (local_n / self.dataset.num_data))
         rung = ladder[np.minimum(np.searchsorted(ladder, share),
                                  len(ladder) - 1)]
-        tiled = (rung > SCATTER_TILE_ROWS) & (self._partition_mode == "scan")
-        telemetry.counters.incr("partition_rows", float(parent.sum()))
-        telemetry.counters.incr("partition_tiled_rows",
-                                float(parent[tiled].sum()))
+        telemetry.counters.incr(
+            "partition_tiled_rows",
+            float(parent[rung > SCATTER_TILE_ROWS].sum()))
 
     def _count_missing_splits(self, rec) -> None:
         """Program counters of how often the default-direction path

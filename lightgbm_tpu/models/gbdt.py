@@ -587,7 +587,7 @@ class GBDT:
             # before k is known), stash the record handles, and replay
             # the PREVIOUS iteration's tree while this program runs on
             # device — hiding the record-fetch round trip and the host
-            # replay (tools/profile_fused.py).
+            # replay.
             with telem.phase("score_update"):
                 self.score_updater.score = score_before.at[0].set(new_score)
             with self._pend_lock:

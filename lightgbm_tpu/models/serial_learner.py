@@ -80,7 +80,7 @@ class SerialTreeLearner:
             dataset.bin_mappers[f].bin_type == BIN_CATEGORICAL
             for f in dataset.used_features)
         # XLA's fused one-hot contraction measured faster than the Pallas
-        # kernel on v5e (tools/microbench_injit.py); opt-in only.
+        # kernel on v5e (a dated reading, 2026-08-01); opt-in only.
         self._use_pallas = use_pallas_env()
         # quantized-gradient training (ops/quantize.py): per-iteration
         # int discretization, exact integer histograms, bit-exact sibling

@@ -18,7 +18,6 @@ contract without materializing the one-hot in HBM.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -35,18 +34,13 @@ def resolve_chunk_size(chunk_size: int, f: int, num_bins: int) -> int:
     """Row-chunk size for the one-hot contraction.
 
     chunk_size > 0 wins (explicit caller / Config.hist_chunk_size);
-    otherwise LGBM_TPU_HIST_CHUNK; otherwise derived from the contraction
-    shape: the (FB, C) x (C, 3) matmul under-fills the MXU when F*B is
-    small, so the chunk grows to keep ~2^22 one-hot elements per pass
-    (clamped to [2048, 32768], multiple of 256). Read at trace time —
-    the jit cache keys on the resolved static, so changing the env var
-    after a shape compiled does not retrigger.
+    otherwise derived from the contraction shape: the (FB, C) x (C, 3)
+    matmul under-fills the MXU when F*B is small, so the chunk grows to
+    keep ~2^22 one-hot elements per pass (clamped to [2048, 32768],
+    multiple of 256).
     """
     if chunk_size and int(chunk_size) > 0:
         return int(chunk_size)
-    env = os.environ.get("LGBM_TPU_HIST_CHUNK", "").strip()
-    if env:
-        return max(256, int(env))
     c = (1 << 22) // max(int(f) * int(num_bins), 1)
     c = max(_CHUNK_FLOOR, min(_CHUNK_CEIL, c))
     return -(-c // 256) * 256
@@ -64,8 +58,8 @@ def _hist_chunk(binned_chunk: jax.Array, gh_chunk: jax.Array, num_bins: int) -> 
     onehot = (binned_chunk.astype(jnp.int32)[:, :, None] == iota[None, None, :])
     # (FB, C) @ (C, 3) on the MXU. The one-hot is bf16-exact; gh is split
     # into bf16 hi + lo parts so each product is a fast single-pass bf16
-    # matmul while the sum keeps ~f32 fidelity (rel err ~8e-7 vs HIGHEST,
-    # tools/microbench_hist2.py). Plain DEFAULT would round gradients to
+    # matmul while the sum keeps ~f32 fidelity (rel err ~8e-7 vs
+    # HIGHEST). Plain DEFAULT would round gradients to
     # bf16, whose absolute error survives sibling subtraction
     # (subtract_histogram) disproportionately for small leaves; HIGHEST
     # costs ~40% more MXU time.

@@ -14,7 +14,7 @@ parts, packed side by side into ONE (C, 6) operand so a single bf16 MXU
 pass covers both halves (hi+lo recombined in f32 outside the kernel,
 rel err ~8e-7 — the same split-precision scheme as ops/histogram.py).
 A full-f32 HIGHEST-precision matmul costs ~6 bf16 passes and measured
-~3x slower end to end (tools/microbench_injit.py, round-2 kernel).
+~3x slower end to end (a dated v5e reading, round-2 kernel).
 
 Mosaic tiling rules require the last two dims of every block to be
 (8k, 128k) or span the whole array, so the codes come in TRANSPOSED (F, P)

@@ -846,13 +846,10 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
                         chunk_rows=self.chunk_rows,
                         fuse_hist=not flag("LGBM_TPU_CHUNK_NO_FUSE_HIST"),
                         scatter_cols=self.scatter_cols,
-                        partition=self._partition_mode,
                         **quant_kw, **self._statics())
         return dict(c_cols=self.c_cols, item_bits=self.item_bits,
                     pool_slots=self.pool_slots,
                     scatter_cols=self.scatter_cols,
-                    window_step=self.window_step,
-                    partition=self._partition_mode,
                     **quant_kw, **self._statics())
 
     def _sharded_tree_fn(self, with_bag_key: bool, allow_bagging=True,
@@ -1269,14 +1266,10 @@ class DeviceFeatureParallelTreeLearner(DeviceTreeLearner):
         self.shards = int(self.mesh.devices.size)
         cs = padded_shard_cols(self.c_cols, self.shards, self.item_bits)
         self._c_pad = cs * self.shards
-        # repack with word-aligned per-shard column capacity; honor the
-        # LGBM_TPU_PACK_WORDS A/B lever if it asks for an even wider row
-        import os as _os
-        pack_words = int(_os.environ.get("LGBM_TPU_PACK_WORDS", "0"))
-        env_cols = pack_words * (32 // self.item_bits)
+        # repack with word-aligned per-shard column capacity
         host_codes = np.asarray(self.codes_row)
         self.codes_pack = jnp.asarray(self.pack_codes(
-            host_codes, col_target=max(self._c_pad, env_cols)))
+            host_codes, col_target=self._c_pad))
         self.codes_row = jnp.asarray(host_codes)
         self._meta = (self.f_numbins, self.f_missing, self.f_default,
                       self.f_monotone, self.f_penalty, self.f_categorical,
@@ -1290,13 +1283,10 @@ class DeviceFeatureParallelTreeLearner(DeviceTreeLearner):
                         chunk_rows=self.chunk_rows,
                         fuse_hist=not flag("LGBM_TPU_CHUNK_NO_FUSE_HIST"),
                         feature_shards=self.shards,
-                        partition=self._partition_mode,
                         **self._statics())
         return dict(c_cols=self.c_cols, item_bits=self.item_bits,
                     pool_slots=self.pool_slots,
                     feature_shards=self.shards,
-                    window_step=self.window_step,
-                    partition=self._partition_mode,
                     **self._statics())
 
     def _sharded_tree_fn(self):

@@ -13,9 +13,8 @@ the bookkeeping on top:
   transfer bytes, collective retries, peak host RSS) with Prometheus
   text exposition (`prometheus_text`, the serving `/metrics` endpoint).
 * `recorder` — per-iteration phase breakdown (gradient, hist, split,
-  partition, score_update, record_fetch, ...) consumed by bench.py's
-  `phase_breakdown` field, tools/profile_iter.py and the
-  `record_telemetry` callback.
+  partition, score_update, record_fetch, ...) consumed by
+  `telemetry_summary()` and the `record_telemetry` callback.
 
 Inside the tree program the stages are `jax.named_scope("lgbm.<stage>")`
 (`STAGES`); `stage_map(hlo_text)` maps a compiled module's instructions
@@ -290,7 +289,7 @@ def note_grow_dispatches(dispatches: float, trees: float = 0.0) -> None:
 def telemetry_summary() -> dict:
     """One JSON-able dict with everything: mode, counters/gauges (peak
     RSS included), compile-event aggregates, and the run's phase
-    breakdown. bench.py and tools/chaos_bench.py print slices of this."""
+    breakdown. tools/chaos_bench.py prints slices of this."""
     out = {"telemetry": _mode}
     out.update(counters.snapshot())
     out["phase_breakdown"] = recorder.phase_breakdown()

@@ -5,8 +5,7 @@ hot sites inside it (gradient compute, learner dispatch, host syncs,
 score updates, collectives) with `phase(name)`. The recorder accumulates
 per-phase seconds twice: into the CURRENT iteration (reported by
 `last_iteration()`, streamed by the `record_telemetry` callback) and
-into run totals (reported by `phase_breakdown()`, consumed by bench.py
-and tools/profile_iter.py).
+into run totals (reported by `phase_breakdown()`).
 
 Canonical phase names, so breakdowns from different paths diff cleanly:
 

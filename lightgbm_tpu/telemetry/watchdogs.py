@@ -22,7 +22,8 @@ watchdog-fire demotion gate sees it for free.
 Baselines are medians over a bounded trailing window; nothing fires
 until `MIN_SAMPLES` healthy iterations exist, so warmup/compile
 iterations never alarm. Every fire lands in the event stream AND in the
-`watchdog_fires` counter, so bench.py and `/metrics` both see it.
+`watchdog_fires` counter, so `telemetry_summary()` and `/metrics` both
+see it.
 
 Configuration (`LGBM_TPU_WATCHDOGS` env): `off` disables, otherwise a
 comma list overriding the default factors, e.g.
@@ -178,8 +179,7 @@ def observe(rec: dict) -> None:
 
 
 def fired() -> Dict[str, int]:
-    """Fires per monitor since the last reset (bench.py's
-    `watchdog_fires` summary feed)."""
+    """Fires per monitor since the last reset."""
     return dict(_fired)
 
 

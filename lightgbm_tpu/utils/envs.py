@@ -26,21 +26,6 @@ def use_pallas_env() -> bool:
     return want
 
 
-def partition_mode_env(default: str = "sort") -> str:
-    """LGBM_TPU_PARTITION selects the compact window-split formulation:
-    'sort' (argsort+take) or 'scan' (destination = cumsum of the
-    partition flags + one row scatter — two linear passes, no sort).
-    `default` carries the caller's backend/strategy-aware choice
-    (device_learner: scan on TPU+compact)."""
-    mode = os.environ.get("LGBM_TPU_PARTITION", "").strip().lower()
-    if mode in ("sort", "scan"):
-        return mode
-    if mode:
-        from . import log
-        log.warning("Unknown LGBM_TPU_PARTITION=%r; using %s", mode, default)
-    return default
-
-
 def pipeline_env() -> bool:
     """LGBM_TPU_PIPELINE: overlap the fused iteration's split-record
     D2H fetch + host tree replay with the NEXT iteration's device

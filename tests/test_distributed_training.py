@@ -69,7 +69,7 @@ meta = (lrn.f_numbins, lrn.f_missing, lrn.f_default, lrn.f_monotone,
         lrn.f_elide, lrn.hist_idx)
 statics = dict(c_cols=lrn.c_cols, item_bits=lrn.item_bits,
                pool_slots=lrn.pool_slots, scatter_cols=shards,
-               window_step=lrn.window_step, **lrn._statics())
+               **lrn._statics())
 
 mesh = Mesh(np.array(jax.devices()), ("data",))
 rsh = NamedSharding(mesh, P("data", None))
@@ -112,8 +112,7 @@ if rank == 0:
         jnp.asarray(g), jnp.asarray(h), jnp.asarray(w),
         jnp.asarray(mask_np), *meta, jnp.asarray(key_np),
         c_cols=lrn.c_cols, item_bits=lrn.item_bits,
-        pool_slots=lrn.pool_slots, window_step=lrn.window_step,
-        **lrn._statics())
+        pool_slots=lrn.pool_slots, **lrn._statics())
     rec_s, k_s = jax.device_get((rec_1, k_1))
     np.testing.assert_allclose(np.asarray(tot_1), np.asarray(tot),
                                rtol=1e-5)
@@ -162,7 +161,7 @@ metac = (lrnc.f_numbins, lrnc.f_missing, lrnc.f_default, lrnc.f_monotone,
          lrnc.f_elide, lrnc.hist_idx)
 staticsc = dict(c_cols=lrnc.c_cols, item_bits=lrnc.item_bits,
                 pool_slots=lrnc.pool_slots, scatter_cols=shards,
-                window_step=lrnc.window_step, **lrnc._statics())
+                **lrnc._statics())
 assert staticsc["cat_statics"] is not None
 
 def localc(cp_l, cr_l, g_l, h_l, w_l, mask, key):
@@ -190,8 +189,7 @@ if rank == 0:
         jnp.asarray(g), jnp.asarray(h), jnp.asarray(w),
         jnp.asarray(maskc_np), *metac, jnp.asarray(key_np),
         c_cols=lrnc.c_cols, item_bits=lrnc.item_bits,
-        pool_slots=lrnc.pool_slots, window_step=lrnc.window_step,
-        **lrnc._statics())
+        pool_slots=lrnc.pool_slots, **lrnc._statics())
     recc_s, recc_cat_s, kc_s = jax.device_get((rc_1, rcc_1, kc_1))
 
 with open(out, "wb") as fh:

@@ -495,36 +495,50 @@ def test_fused_goss_device_sampling():
     assert _auc(y, pred) > 0.95
 
 
-def test_window_step2_matches_default():
-    """The tighter window-class ladder (LGBM_TPU_WINDOW_STEP=2) must be a
-    pure performance knob: identical trees to the default step-4 ladder."""
-    import os
+def test_window_step2_matches_default(monkeypatch):
+    """The window ladder is a matter of speed alone: at 20,000 rows, where
+    the ladder's constant (step 2) gives four rungs, three boosting
+    iterations grow the trees of the coarsest ladder (one rung over the
+    floor: every split of more than 4,096 rows takes the whole table as
+    its window), text for text."""
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.models import device_learner as dl
     r = np.random.RandomState(31)
-    x = r.randn(2500, 6).astype(np.float32)
-    y = (x[:, 0] - 0.5 * x[:, 2] > 0).astype(float)
+    x = r.randn(20000, 6).astype(np.float32)
+    y = (x[:, 0] - 0.5 * x[:, 2] + 0.3 * r.randn(20000) > 0).astype(float)
     params = {"objective": "binary", "num_leaves": 15,
               "verbosity": -1, "min_data_in_leaf": 5}
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    windows = set()
+    real = dl.partition_window
+
+    def spy(win, key3, tile_rows=None):
+        windows.add(win.shape[0])
+        return real(win, key3, tile_rows)
+
+    monkeypatch.setattr(dl, "partition_window", spy)
 
     def run(step):
-        os.environ["LGBM_TPU_STRATEGY"] = "compact"
+        # the step is no static of the jitted growth program, so a
+        # program traced under another ladder must not be handed back
+        dl.grow_tree_compact.clear_cache()
         if step:
-            os.environ["LGBM_TPU_WINDOW_STEP"] = step
-        try:
-            b = lgb.Booster(params=params, train_set=lgb.Dataset(x, y))
-            for _ in range(3):
-                b.update()
-            return b
-        finally:
-            os.environ.pop("LGBM_TPU_STRATEGY", None)
-            os.environ.pop("LGBM_TPU_WINDOW_STEP", None)
+            monkeypatch.setattr(dl, "WINDOW_STEP", step)
+        windows.clear()
+        b = lgb.Booster(params=params, train_set=lgb.Dataset(x, y))
+        for _ in range(3):
+            b.update()
+        assert b._gbdt.learner.strategy == "compact"
+        return [t.to_string() for t in b._gbdt.models], sorted(windows)
 
-    b4, b2 = run(None), run("2")
-    for t4, t2 in zip(b4._gbdt.models, b2._gbdt.models):
-        assert t4.num_leaves == t2.num_leaves
-        for i in range(t4.num_leaves - 1):
-            assert int(t4.split_feature[i]) == int(t2.split_feature[i])
-            assert int(t4.threshold_in_bin[i]) == int(t2.threshold_in_bin[i])
+    assert dl.WINDOW_STEP == 2
+    fine, fine_windows = run(None)
+    assert fine_windows == dl._size_classes(20000) == [
+        4096, 8192, 16384, 20000]
+    coarse, coarse_windows = run(1 << 30)
+    dl.grow_tree_compact.clear_cache()
+    assert coarse_windows == [4096, 20000]
+    assert len(fine) == 3 and fine == coarse
 
 
 def test_lru_histogram_pool_matches_dense():

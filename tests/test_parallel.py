@@ -348,7 +348,7 @@ def test_hostloop_voting_multichunk_window():
     PV-Tree) with a root window larger than the histogram chunk size:
     exercises the scanned multi-chunk build_histogram INSIDE the
     learner's shard_map hist_fn — the path a zeros-seeded scan carry
-    broke (caught by tools/mesh_scaling_probe.py, round 5)."""
+    broke (caught by a mesh scaling probe, round 5)."""
     from lightgbm_tpu.parallel.learners import VotingParallelTreeLearner
     x, y = make_binary(6000, 28)
     b = _train(x, y, "voting", rounds=2, num_leaves=4, top_k=20)

@@ -1,6 +1,7 @@
-"""The sort-free partition (cumsum ranks + one row scatter) is the TPU
-default on the compact strategy; CPU runs default to argsort+take, so
-this is where the scan formulation is held to the same trees."""
+"""The sort-free partition (cumsum ranks + one row scatter, tile by tile
+over SCATTER_TILE_ROWS) is the compact core's one partition on every
+platform: held here to a stable argsort's order, tiled and untiled, and
+to the trees of a core that moves no rows."""
 import functools
 
 import numpy as np
@@ -9,10 +10,10 @@ import jax.numpy as jnp
 import pytest
 
 
-@functools.partial(jax.jit, static_argnames=("partition", "tile_rows"))
-def _partition_jit(win, key3, partition, tile_rows):
+@functools.partial(jax.jit, static_argnames=("tile_rows",))
+def _partition_jit(win, key3, tile_rows):
     from lightgbm_tpu.models.device_learner import partition_window
-    return partition_window(win, key3, partition, tile_rows=tile_rows)
+    return partition_window(win, key3, tile_rows=tile_rows)
 
 
 def _toy(seed, n, f=6):
@@ -25,9 +26,8 @@ def _toy(seed, n, f=6):
     return x, y, g, h
 
 
-def _grow_compact(x, y, g, h, mode):
-    """Tree text of one DeviceTreeLearner(strategy="compact") tree under
-    the partition mode the environment gives."""
+def _grow_compact(x, y, g, h):
+    """Tree text of one DeviceTreeLearner(strategy="compact") tree."""
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import Dataset
     from lightgbm_tpu.models.device_learner import DeviceTreeLearner
@@ -35,21 +35,34 @@ def _grow_compact(x, y, g, h, mode):
                   "min_data_in_leaf": 20, "verbosity": -1})
     lrn = DeviceTreeLearner(cfg, Dataset(x, config=cfg, label=y),
                             strategy="compact")
-    assert lrn._partition_mode == mode
     return lrn.train(g, h).to_string()
 
 
 def test_compact_learner_identical_trees_with_scan_partition(monkeypatch):
-    toy = _toy(23, 3000)
+    """With nothing set in the environment, on any backend, the compact
+    core partitions by the scan, and grows the tree of the masked core,
+    which moves no rows: text for text under exact-arithmetic gradients,
+    where the cores' different summation orders give the same sums."""
+    from test_chunk_strategy import exact_grads, grow_tree_with
+    from lightgbm_tpu.models import device_learner as dl
+    monkeypatch.delenv("LGBM_TPU_STRATEGY", raising=False)
+    r = np.random.RandomState(23)
+    x, y, _, _ = _toy(23, 3000)
+    g, h = exact_grads(r, 3000)
+    scanned = []
+    real = dl._scan_partition
 
-    def grow(mode):
-        if mode:
-            monkeypatch.setenv("LGBM_TPU_PARTITION", mode)
-        else:
-            monkeypatch.delenv("LGBM_TPU_PARTITION", raising=False)
-        return _grow_compact(*toy, mode or "sort")
+    def spy(win, key3):
+        scanned.append(win.shape[0])
+        return real(win, key3)
 
-    assert grow("scan") == grow(None)
+    monkeypatch.setattr(dl, "_scan_partition", spy)
+    # traced anew, so that the spy sees this learner's windows
+    dl.grow_tree_compact.clear_cache()
+    compact = grow_tree_with(monkeypatch, "compact", x, y, g, h)
+    dl.grow_tree_compact.clear_cache()
+    assert sorted(set(scanned)) == dl._size_classes(3000) == [3000]
+    assert compact == grow_tree_with(monkeypatch, "masked", x, y, g, h)
 
 
 def _keys(pattern, w, rng):
@@ -83,11 +96,13 @@ def test_tiled_scan_partition_equals_untiled(w, t, d_cols, pattern):
     win = jnp.asarray(rng.randint(0, 2**32, size=(w, d_cols),
                                   dtype=np.uint64).astype(np.uint32))
     key3 = jnp.asarray(_keys(pattern, w, rng))
-    tiled = _partition_jit(win, key3, "scan", t)
+    tiled = _partition_jit(win, key3, t)
     np.testing.assert_array_equal(
-        np.asarray(tiled), np.asarray(_partition_jit(win, key3, "scan", w)))
+        np.asarray(tiled), np.asarray(_partition_jit(win, key3, w)))
+    # the reference: the rows in a stable sort's order of their keys
     np.testing.assert_array_equal(
-        np.asarray(tiled), np.asarray(_partition_jit(win, key3, "sort", w)))
+        np.asarray(tiled),
+        np.asarray(win)[np.argsort(np.asarray(key3), kind="stable")])
 
 
 def test_compact_learner_identical_trees_with_tiled_scan(monkeypatch):
@@ -95,7 +110,6 @@ def test_compact_learner_identical_trees_with_tiled_scan(monkeypatch):
     tiled branches grow the untiled scan's trees, text for text."""
     from lightgbm_tpu.models import device_learner as dl
     toy = _toy(29, 5000)
-    monkeypatch.setenv("LGBM_TPU_PARTITION", "scan")
     tiled_windows = []
     real = dl._scan_partition_tiled
 
@@ -110,7 +124,7 @@ def test_compact_learner_identical_trees_with_tiled_scan(monkeypatch):
         # program traced under another tile must not be handed back
         dl.grow_tree_compact.clear_cache()
         monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", tile_rows)
-        return _grow_compact(*toy, "scan")
+        return _grow_compact(*toy)
 
     untiled = grow(1 << 18)
     assert tiled_windows == []

@@ -158,8 +158,7 @@ def test_pallas_quantized_kernel_matches_xla():
 # chunk-size satellite
 # ---------------------------------------------------------------------------
 
-def test_resolve_chunk_size(monkeypatch):
-    monkeypatch.delenv("LGBM_TPU_HIST_CHUNK", raising=False)
+def test_resolve_chunk_size():
     # explicit wins
     assert hist_ops.resolve_chunk_size(1024, 28, 64) == 1024
     # large F*B keeps the historical floor
@@ -167,9 +166,6 @@ def test_resolve_chunk_size(monkeypatch):
     # small F*B derives a larger chunk (MXU fill), clamped + 256-aligned
     small = hist_ops.resolve_chunk_size(0, 4, 16)
     assert small > 2048 and small <= 32768 and small % 256 == 0
-    # env override
-    monkeypatch.setenv("LGBM_TPU_HIST_CHUNK", "4096")
-    assert hist_ops.resolve_chunk_size(0, 28, 256) == 4096
 
 
 def test_chunk_size_does_not_change_histogram():

@@ -50,14 +50,12 @@ def _cond_dispatch(pred, body, state):
     return jax.lax.cond(pred, body, lambda s: s, state)
 
 
-def _serial_case(params, n, *, trivial, partition=None, pool_slots=None):
+def _serial_case(params, n, *, trivial, pool_slots=None):
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import Dataset
     from lightgbm_tpu.models import device_learner as dl
 
     def grow(monkeypatch):
-        if partition:
-            monkeypatch.setenv("LGBM_TPU_PARTITION", partition)
         x, y, g, h = _toy(n, n)
         cfg = Config(dict({"objective": "binary", "num_leaves": 15,
                            "max_bin": 63, "min_data_in_leaf": 20,
@@ -77,7 +75,7 @@ def _serial_case(params, n, *, trivial, partition=None, pool_slots=None):
         assert int(k) == 14
         # a split on every rung of the ladder
         parents = rec[:int(k), dl.R_LCNT] + rec[:int(k), dl.R_RCNT]
-        ladder = np.asarray(dl._size_classes(n, step=lrn.window_step))
+        ladder = np.asarray(dl._size_classes(n))
         assert set(np.searchsorted(ladder, parents)) == set(
             range(len(ladder)))
         return {"rec": rec, "leaf_id": leaf_id, "k": k, "totals": totals}
@@ -104,8 +102,8 @@ def _sharded_case(tree_learner):
 CASES = {
     # the cells' path: float rows, all-ones weights, four rungs
     "float": _serial_case({}, 20000, trivial=True),
-    # the scan partition (the TPU's default) inside the rungs
-    "float_scan": _serial_case({}, 5000, trivial=False, partition="scan"),
+    # per-row weights: the masked full-window histogram stays in the rungs
+    "float_scan": _serial_case({}, 5000, trivial=False),
     # `qmax2` among the rung's results
     "quantized_renew": _serial_case(
         {"quantized_grad": True, "grad_bits": 8}, 5000, trivial=True),

@@ -35,7 +35,7 @@ def test_tiled_partition_compiles_to_tile_sized_scatters(one_chip, rows):
     from lightgbm_tpu.models import device_learner as dl
     d_cols = 11
     assert rows > dl.SCATTER_TILE_ROWS
-    txt = jax.jit(lambda w, k: dl.partition_window(w, k, "scan")).lower(
+    txt = jax.jit(lambda w, k: dl.partition_window(w, k)).lower(
         jax.ShapeDtypeStruct((rows, d_cols), jnp.uint32, sharding=one_chip),
         jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
     ).compile().as_text()
@@ -70,7 +70,7 @@ def test_split_scan_and_wide_rows_compile(one_chip, features, d_cols):
         min_gain_to_split=0.0).compile().as_text()
     assert "f32[%d,256,3]" % features in txt
     rows = 2 * dl.SCATTER_TILE_ROWS
-    jax.jit(lambda w, k: dl.partition_window(w, k, "scan")).lower(
+    jax.jit(lambda w, k: dl.partition_window(w, k)).lower(
         shaped((rows, d_cols), jnp.uint32),
         shaped((rows,), jnp.int32)).compile()
 
@@ -94,7 +94,6 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import Dataset
     from lightgbm_tpu.models import device_learner as dl
-    monkeypatch.setenv("LGBM_TPU_PARTITION", "scan")
     rows = 300_000
     # a toy learner of the cell's columns, for the per-feature arrays
     # and the statics; the rows are shapes alone
@@ -106,8 +105,8 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
                      label=(r.rand(4000) > 0.5).astype(np.float64)),
         strategy="compact")
     grow, kwargs = lrn._grow_fn_kwargs(trivial_weights=True)
-    assert grow is dl.grow_tree_compact and kwargs["partition"] == "scan"
-    ladder = dl._size_classes(rows, step=kwargs["window_step"])
+    assert grow is dl.grow_tree_compact
+    ladder = dl._size_classes(rows)
     assert len(ladder) >= 3 and ladder[-1] > dl.SCATTER_TILE_ROWS
 
     def shaped(a, lead=None):

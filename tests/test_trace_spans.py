@@ -312,11 +312,11 @@ def test_setup_stage_counters_are_positive_and_inside_construct():
             <= construct_s)
 
 
-@pytest.mark.parametrize("tile_rows,partition", [
-    (None, None),          # the defaults off the TPU: sort, nothing tiled
-    (4096, "scan")])       # the tile forced under the two upper rungs
+@pytest.mark.parametrize("tile_rows", [
+    None,          # the shipped tile: no rung of a toy ladder is over it
+    4096])         # the tile forced under the two upper rungs
 def test_partition_row_counters_follow_the_split_records(
-        monkeypatch, tile_rows, partition):
+        monkeypatch, tile_rows):
     """`partition_rows` rises by the parent rows of the tree's splits;
     `partition_tiled_rows` by those whose rung is over one scatter tile:
     0 at toy size, the splits of more than 4096 rows with the tile
@@ -324,8 +324,7 @@ def test_partition_row_counters_follow_the_split_records(
     partition inside the fused step."""
     from lightgbm_tpu.models import device_learner as dl
     monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
-    if partition:
-        monkeypatch.setenv("LGBM_TPU_PARTITION", partition)
+    if tile_rows:
         monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", tile_rows)
     bst = _booster(n=10000, num_leaves=15)
     gbdt = bst._gbdt
@@ -337,9 +336,9 @@ def test_partition_row_counters_follow_the_split_records(
     parents = np.asarray(tree.internal_count[:tree.num_leaves - 1])
     assert tree.num_leaves == 15 and parents.max() == 10000
     assert counters.get("partition_rows") == parents.sum()
-    tiled = parents[parents > tile_rows].sum() if partition else 0
+    tiled = parents[parents > tile_rows].sum() if tile_rows else 0
     assert counters.get("partition_tiled_rows") == tiled
-    if partition:
+    if tile_rows:
         assert 0 < tiled < parents.sum()
 
 

@@ -33,7 +33,7 @@ exactly:
   taking the replica down, and ``/healthz`` answers throughout.
 
 Emits ONE JSON line (``chaos_soak``); exit code 0 iff every invariant
-held. The measured line is committed as CHAOS_r01.json.
+held.
 
 Usage: python tools/chaos_soak.py [--seed 1]
 Env:   SOAK_ROWS (1200), SOAK_FEATURES (8), SOAK_ITERS (6),

@@ -23,9 +23,8 @@ Generated from `lightgbm_tpu/params_schema.py` by
 `tools/gen_parameters_doc.py` — edit the schema, not this file.
 
 TPU-specific runtime knobs (environment variables, not params): see
-`docs/DESIGN.md` (`LGBM_TPU_STRATEGY`, `LGBM_TPU_WINDOW_STEP`,
-`LGBM_TPU_PACK_WORDS`, `LGBM_TPU_PALLAS`, `LGBM_TPU_DP_REDUCE`,
-`LGBM_TPU_VOTING_BATCHED`, `LGBM_TPU_HOST_LEARNER`). Fault-tolerance
+`docs/DESIGN.md` (`LGBM_TPU_STRATEGY`, `LGBM_TPU_PALLAS`,
+`LGBM_TPU_DP_REDUCE`, `LGBM_TPU_HOST_LEARNER`). Fault-tolerance
 knobs (`on_nonfinite`, `resume`, `snapshot_keep`, `checkpoint_freq`,
 and the `LGBM_TPU_FAULT_SPEC` / `LGBM_TPU_COLLECTIVE_RETRIES` env
 vars): see `docs/Reliability.md`. Observability knobs (`telemetry` and

@@ -5,7 +5,7 @@ Trains a small model, loads it into the serving stack (registry warm-up +
 micro-batcher), then drives closed-loop traffic from several client
 threads and reports tail latency and row throughput.
 
-Prints ONE JSON line in the bench.py record shape: {"metric", "value",
+Prints ONE JSON line: {"metric", "value",
 "unit", "vs_baseline"} plus diagnostics ("p50_ms", "p95_ms", "p99_ms",
 "compiles_after_warm", "backend", ...). vs_baseline is null: the source
 paper benchmarks training only; this record seeds the serving baseline.

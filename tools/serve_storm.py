@@ -21,8 +21,7 @@ throughput ceiling is its flush cadence (``max_batch`` rows every
 ``max_delay_ms``), far below the CPU's predict limit for a tiny model,
 so adding replicas genuinely adds capacity until the host saturates —
 the same shape a TPU pod fleet shows when replicas are accelerator-
-bound. The committed curve lives in ``FLEET_r01.json``
-(``--out`` writes it).
+bound. ``--out`` writes the curve to a file.
 
 Replicas share one export cache directory, so replica 2..N restore
 replica 1's compiled predictors — fleet builds are compile-once.
@@ -31,7 +30,7 @@ Usage::
 
     python tools/serve_storm.py                      # 1,2,3 replicas
     python tools/serve_storm.py --replicas 2 --secs 2 --clients 6
-    python tools/serve_storm.py --out FLEET_r01.json
+    python tools/serve_storm.py --out fleet_curve.json
 
 Env: STORM_FEATURES (16), STORM_ROWS (2000) size the demo model.
 """
@@ -317,8 +316,7 @@ def main() -> None:
     ap.add_argument("--max-delay-ms", type=float, default=20.0)
     ap.add_argument("--queue-rows", type=int, default=24)
     ap.add_argument("--out", default="",
-                    help="write the full curve JSON here "
-                         "(the committed artifact is FLEET_r01.json)")
+                    help="write the full curve JSON here")
     args = ap.parse_args()
     counts = [int(v) for v in args.replicas.split(",") if v]
     curve = storm_curve(
