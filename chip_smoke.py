@@ -37,8 +37,8 @@ from lightgbm_tpu.ops.pallas import histogram_kernel as pallas_hist
 from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
 from lightgbm_tpu.telemetry import counters
 
-DEFAULT_LEGS = ("train255", "train63", "reference", "predict", "serve",
-                "kernels", "cache", "fourchip")
+DEFAULT_LEGS = ("histogram", "train255", "train63", "reference", "predict",
+                "serve", "kernels", "cache", "fourchip")
 ALL_LEGS = ("categorical", "lambdarank", "multiclass", "quantized",
             "stream")
 N_FEATURES = 28
@@ -243,6 +243,50 @@ class Smoke:
         return path
 
     # -- default legs --------------------------------------------------
+    def leg_histogram(self):
+        """The factored one-hot contraction's VALUES against NumPy
+        float64 (a product shape the TPU compiler has not been seen to
+        get right is not covered by a CPU test: PERF.md §6, PR 29), at
+        XLA's default flags, so a gradient remainder the compiler folded
+        away fails here too. Whole chunks and a ragged window whose last
+        rows are padding (gh 0, arbitrary codes)."""
+        r = np.random.RandomState(5)
+        out = {}
+        for f, bins in ((28, 256), (67, 256), (5, 64), (9, 255)):
+            chunk = 256 if self.rehearsal else hist_ops.resolve_chunk_size(
+                0, f, bins)
+            for rows in (4 * chunk, 3 * chunk + 777):
+                codes = r.randint(0, bins, (rows, f)).astype(np.uint8)
+                gh = np.stack([r.randn(rows), r.rand(rows) + 0.1,
+                               np.ones(rows)], axis=1).astype(np.float32)
+                ghq = np.stack([r.randint(-127, 128, rows),
+                                r.randint(0, 128, rows),
+                                np.ones(rows, np.int64)], axis=1).astype(np.int8)
+                if rows % chunk:
+                    gh[-300:] = 0
+                    ghq[-300:] = 0
+                want, mass = np.zeros((2, f, bins, 3))
+                want_q = np.zeros((f, bins, 3), np.int64)
+                for j in range(f):
+                    np.add.at(want[j], codes[:, j], gh.astype(np.float64))
+                    np.add.at(mass[j], codes[:, j], np.abs(gh, dtype=np.float64))
+                    np.add.at(want_q[j], codes[:, j], ghq.astype(np.int64))
+                got = np.asarray(hist_ops.build_histogram(
+                    jnp.asarray(codes), jnp.asarray(gh), bins,
+                    chunk_size=chunk))
+                # a bf16 head and a bf16 remainder keep 16 bits of each
+                # addend; the float32 sums add less than that again
+                excess = float(np.max(np.abs(got - want) - 2.0 ** -16 * mass))
+                assert excess <= 1e-6, (f, bins, rows, excess)
+                got_q = np.asarray(hist_ops.build_histogram_quantized(
+                    jnp.asarray(codes), jnp.asarray(ghq), bins,
+                    chunk_size=chunk))
+                assert np.array_equal(got_q, want_q), (f, bins, rows)
+                out[f"{f}x{bins}_rows{rows}"] = {
+                    "float_max_abs_err": float(np.abs(got - want).max()),
+                    "int8": "equal"}
+        return out
+
     def _train_leg(self, max_bin):
         x, y, xv, yv = self.higgs(self.rows)
         bst, models, per_tree = self.train(

@@ -2251,10 +2251,14 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     (program-size pinning). On the same five arguments, `step.lower(...)`
     lowers the program, `step.stage_map(...)` compiles it too and maps
     its instructions to the `lgbm.<stage>` scopes (telemetry.stage_map),
-    and `step.table_copies(...)` counts the copies of the packed table
+    `step.table_copies(...)` counts the copies of the packed table
     inside the compiled split loop, by computation
-    (telemetry.table_copies_in_split_loop) — for a reader of a device
-    trace and for tests, never on the hot path. A warm persistent
+    (telemetry.table_copies_in_split_loop), and
+    `step.hist_plane_elems(...)` sizes the one-hot plane the histogram
+    products read, in elements a row
+    (telemetry.hist_plane_elems_per_row; it feeds the gauge of that
+    name) — for a reader of a device trace and for tests, never on the
+    hot path. A warm persistent
     compile cache hands back the executable as it was compiled, so a map
     from a cache entry older than the scopes comes back empty."""
     def step(*args):
@@ -2272,6 +2276,13 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     step.stage_map = lambda *args: telemetry.stage_map(compiled_text(*args))
     step.table_copies = lambda *args: telemetry.table_copies_in_split_loop(
         compiled_text(*args))
+
+    def hist_plane_elems(*args):
+        elems = telemetry.hist_plane_elems_per_row(compiled_text(*args))
+        telemetry.counters.set_gauge("hist_plane_elems_per_row", elems)
+        return elems
+
+    step.hist_plane_elems = hist_plane_elems
     return step
 
 

@@ -4,16 +4,23 @@ Role of the reference's hottest loops — Bin::ConstructHistogram
 (reference: src/io/dense_bin.hpp:71-195, 4-way unrolled scalar scatter) and
 the OpenCL kernels (src/treelearner/ocl/histogram256.cl, local-memory float
 atomics). TPUs have no fast scatter-atomics, so the TPU-native formulation is
-a one-hot contraction on the MXU: for a row chunk C,
+a one-hot contraction on the MXU. The one-hot is FACTORED: a bin code is
+`hi * LO_BINS + lo`, and
 
-    hist[f*B+b, k] += sum_n onehot[n, f*B+b] * gh[n, k]
+    hist[f, hi*LO_BINS + lo, k] = sum_n [hi_n,f == hi] [lo_n,f == lo] gh[n, k]
 
-i.e. a (FB, C) x (C, 3) matmul per chunk, accumulated over chunks with
-lax.scan. The (gradient, hessian, count) triple rides the tiny K=3 axis;
-padding rows carry gh = 0 so buckets can be padded freely.
+so a row chunk C is, per group of G = 128 / LO_BINS features, ONE product
+(C, K*G*b)^T x (C, 128): the right side is the group's `lo` one-hot (a
+full MXU tile wide), the left side its `hi` one-hot times the K operand
+columns, and the histogram is the f == f' diagonal blocks of the result.
+A row builds F * (LO_BINS + b*K) plane elements, not F * num_bins, and no
+product is 3 columns wide. The raw products are accumulated over chunks
+with lax.scan and the diagonal is taken once after it. The (gradient,
+hessian, count) triple rides the K axis; padding rows carry gh = 0 so
+buckets can be padded freely.
 
 A fused Pallas kernel (ops/pallas/histogram_kernel.py) implements the same
-contract without materializing the one-hot in HBM.
+contract with the unfactored one-hot in VMEM.
 """
 from __future__ import annotations
 
@@ -24,54 +31,159 @@ import jax.numpy as jnp
 
 from .pallas import histogram_kernel as _pallas_hist
 
-# floor of the derived chunk ladder: shapes with F*B >= 4M/floor elements
-# resolve to exactly this, keeping the historical behavior bit-identical
+# floor and ceiling of the derived chunk ladder (rows a scan step)
 _CHUNK_FLOOR = 2048
 _CHUNK_CEIL = 32768
+# plane elements a scan step may build: 4,096 rows at 67 x 256 and 8,192
+# at 28 x 256 are each cell's fastest, and at 8,192 x 67 x 256 the step's
+# planes leave the chip's fast memory and a row costs 3.4x (PERF.md §6)
+_CHUNK_PLANE_ELEMS = 3 << 23
+
+_MXU_COLS = 128
+# bins the low part of a code counts (code = hi * LO_BINS + lo), so a
+# product's right side holds _MXU_COLS / LO_BINS features. Chosen on the
+# chip at both cells' widths (PERF.md §6, PR 32), as SCATTER_TILE_ROWS was
+LO_BINS = 64
+
+
+def _factors(f: int, num_bins: int):
+    """(groups, features a group, hi values) of the factored one-hot."""
+    per_group = _MXU_COLS // LO_BINS
+    return -(-f // per_group), per_group, -(-num_bins // LO_BINS)
 
 
 def resolve_chunk_size(chunk_size: int, f: int, num_bins: int) -> int:
     """Row-chunk size for the one-hot contraction.
 
     chunk_size > 0 wins (explicit caller / Config.hist_chunk_size);
-    otherwise derived from the contraction shape: the (FB, C) x (C, 3)
-    matmul under-fills the MXU when F*B is small, so the chunk grows to
-    keep ~2^22 one-hot elements per pass (clamped to [2048, 32768],
-    multiple of 256).
+    otherwise derived from the width of the two planes a row builds,
+    F * (LO_BINS + 6 * hi values): the longest power of two (the window
+    ladder's rungs are powers of two, so none is padded) whose planes
+    stay within _CHUNK_PLANE_ELEMS, so that a scan step's products are
+    long against its fixed costs (the accumulator read and written once
+    a step), clamped to [2048, 32768].
     """
     if chunk_size and int(chunk_size) > 0:
         return int(chunk_size)
-    c = (1 << 22) // max(int(f) * int(num_bins), 1)
-    c = max(_CHUNK_FLOOR, min(_CHUNK_CEIL, c))
-    return -(-c // 256) * 256
+    groups, per_group, hi_bins = _factors(f, num_bins)
+    width = groups * per_group * (LO_BINS + 6 * hi_bins)
+    c = max(_CHUNK_PLANE_ELEMS // width, 1)
+    c = 1 << (c.bit_length() - 1)
+    return max(_CHUNK_FLOOR, min(_CHUNK_CEIL, c))
+
+
+def _factored_product(binned_chunk: jax.Array, operand: jax.Array,
+                      num_bins: int, acc_dtype) -> jax.Array:
+    """The factored one-hot contraction of one chunk, un-rearranged.
+
+    binned_chunk: (C, F) int bin codes; a code outside [0, num_bins)
+                  lands in no kept bin
+    operand:      (C, K) columns to sum by bin, in the product's type
+    returns       (groups, K*G*b, G*LO_BINS) acc_dtype: per feature group
+                  the product of every (column, feature, hi) with every
+                  (feature', lo); `_unfactor` keeps feature == feature'
+    """
+    c, f = binned_chunk.shape
+    k = operand.shape[1]
+    groups, per_group, hi_bins = _factors(f, num_bins)
+    codes = binned_chunk.astype(jnp.int32)
+    if groups * per_group != f:
+        codes = jnp.pad(codes, ((0, 0), (0, groups * per_group - f)))
+    codes = codes.reshape(c, groups, per_group)
+    # arithmetic shift: a negative code keeps a negative hi and matches none
+    hi = codes >> (LO_BINS.bit_length() - 1)
+    lo = codes & (LO_BINS - 1)
+    lo_hot = (lo[..., None] == jnp.arange(LO_BINS, dtype=jnp.int32)
+              ).astype(operand.dtype)
+    hi_hot = hi[..., None] == jnp.arange(hi_bins, dtype=jnp.int32)
+    # the operand's columns outermost: with K innermost the TPU compiler
+    # writes the hi plane out once more, broadcast over K (PERF.md §6, PR 32)
+    left = jnp.where(hi_hot.reshape(c, groups, 1, per_group * hi_bins),
+                     operand[:, None, :, None], jnp.zeros((), operand.dtype))
+    return jnp.einsum(
+        "cgm,cgn->gmn", left.reshape(c, groups, k * per_group * hi_bins),
+        lo_hot.reshape(c, groups, per_group * LO_BINS),
+        preferred_element_type=acc_dtype)
+
+
+def _unfactor(raw: jax.Array, f: int, num_bins: int) -> jax.Array:
+    """(F, num_bins, K) histogram out of `_factored_product`'s sum: the
+    feature == feature' diagonal blocks, bins back in code order."""
+    groups, per_group, hi_bins = _factors(f, num_bins)
+    k = raw.shape[1] // (per_group * hi_bins)
+    blocks = raw.reshape(groups, k, per_group, hi_bins, per_group, LO_BINS)
+    diag = jnp.diagonal(blocks, axis1=2, axis2=4)     # (groups, K, b, a, G)
+    hist = diag.transpose(0, 4, 2, 3, 1).reshape(
+        groups * per_group, hi_bins * LO_BINS, k)
+    return hist[:f, :num_bins]
 
 
 def _hist_chunk(binned_chunk: jax.Array, gh_chunk: jax.Array, num_bins: int) -> jax.Array:
-    """One-hot contraction for one chunk.
+    """Raw factored product of one float chunk.
 
-    binned_chunk: (C, F) int8/int16 bin codes
+    binned_chunk: (C, F) int8/uint8/int16 bin codes
     gh_chunk:     (C, 3) f32 (grad, hess, valid-count)
-    returns       (F, B, 3) f32 partial histogram
+    returns       `_factored_product`'s f32 result over six columns: the
+                  bf16 head of gh beside its bf16 remainder
     """
-    c, f = binned_chunk.shape
-    iota = jnp.arange(num_bins, dtype=jnp.int32)
-    onehot = (binned_chunk.astype(jnp.int32)[:, :, None] == iota[None, None, :])
-    # (FB, C) @ (C, 3) on the MXU. The one-hot is bf16-exact; gh is split
-    # into bf16 hi + lo parts so each product is a fast single-pass bf16
-    # matmul while the sum keeps ~f32 fidelity (rel err ~8e-7 vs
-    # HIGHEST). Plain DEFAULT would round gradients to
-    # bf16, whose absolute error survives sibling subtraction
-    # (subtract_histogram) disproportionately for small leaves; HIGHEST
-    # costs ~40% more MXU time.
-    onehot2d = onehot.reshape(c, f * num_bins).astype(jnp.bfloat16)
-    gh_hi = gh_chunk.astype(jnp.bfloat16)
-    gh_lo = (gh_chunk - gh_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    dn = (((0,), (0,)), ((), ()))
-    hist = (jax.lax.dot_general(onehot2d, gh_hi, dimension_numbers=dn,
-                                preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(onehot2d, gh_lo, dimension_numbers=dn,
-                                  preferred_element_type=jnp.float32))
-    return hist.reshape(f, num_bins, 3)
+    # gh is split into a bf16 head + remainder so the product is a fast
+    # single-pass bf16 matmul while the sum keeps ~f32 fidelity (rel err
+    # ~8e-7 vs HIGHEST). Plain DEFAULT would round gradients to bf16,
+    # whose absolute error survives sibling subtraction
+    # (subtract_histogram) disproportionately for small leaves. The head
+    # is a reduce_precision, which no flag lets the compiler fold away:
+    # as float32(bfloat16(gh)) it is gh itself under XLA's default
+    # --xla_allow_excess_precision, and the remainder 0 (PERF.md §7).
+    head = jax.lax.reduce_precision(gh_chunk, exponent_bits=8,
+                                    mantissa_bits=7)
+    operand = jnp.concatenate([head, gh_chunk - head],
+                              axis=1).astype(jnp.bfloat16)
+    return _factored_product(binned_chunk, operand, num_bins, jnp.float32)
+
+
+def _hist_chunk_q(binned_chunk: jax.Array, ghq_chunk: jax.Array,
+                  num_bins: int) -> jax.Array:
+    """Raw factored product of one integer chunk.
+
+    binned_chunk: (C, F) int bin codes
+    ghq_chunk:    (C, 3) int8/int32 [qg, qh, valid]
+    returns       `_factored_product`'s int32 result, EXACT: the planes
+                  are in the operand's type (i8 rides the MXU's native
+                  int8 path) and the int32 accumulator does not round, so
+                  there is no head and remainder.
+    """
+    return _factored_product(binned_chunk, ghq_chunk, num_bins, jnp.int32)
+
+
+def _sum_chunks(chunk_product, binned_rows: jax.Array, gh: jax.Array,
+                num_bins: int, chunk_size: int) -> jax.Array:
+    """`chunk_product` summed over the row chunks of a padded window,
+    then un-rearranged: (F, num_bins, K)."""
+    p, f = binned_rows.shape
+    chunk_size = resolve_chunk_size(chunk_size, f, num_bins)
+    if p <= chunk_size:
+        return _unfactor(chunk_product(binned_rows, gh, num_bins), f,
+                         num_bins)
+    n_chunks = (p + chunk_size - 1) // chunk_size
+    pad = n_chunks * chunk_size - p
+    if pad:
+        binned_rows = jnp.pad(binned_rows, ((0, pad), (0, 0)))
+        gh = jnp.pad(gh, ((0, pad), (0, 0)))
+    binned_rows = binned_rows.reshape(n_chunks, chunk_size, f)
+    gh = gh.reshape(n_chunks, chunk_size, gh.shape[1])
+
+    def body(acc, chunk):
+        b, g = chunk
+        return acc + chunk_product(b, g, num_bins), None
+
+    # the carry is seeded from the FIRST chunk (not zeros) so its type
+    # carries the data's varying-manual-axes when this runs inside a
+    # shard_map region (a replicated zeros carry + varying per-chunk
+    # additions fails shard_map's carry type check); outside shard_map
+    # it is the same arithmetic with one add saved
+    init = chunk_product(binned_rows[0], gh[0], num_bins)
+    raw, _ = jax.lax.scan(body, init, (binned_rows[1:], gh[1:]))
+    return _unfactor(raw, f, num_bins)
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk_size", "use_pallas"))
@@ -87,30 +199,8 @@ def build_histogram(binned_rows: jax.Array, gh: jax.Array, num_bins: int,
     """
     if use_pallas:
         return _pallas_hist.build_histogram_pallas(binned_rows, gh, num_bins)
-    p, f = binned_rows.shape
-    chunk_size = resolve_chunk_size(chunk_size, f, num_bins)
-    if p <= chunk_size:
-        return _hist_chunk(binned_rows, gh, num_bins)
-    n_chunks = (p + chunk_size - 1) // chunk_size
-    pad = n_chunks * chunk_size - p
-    if pad:
-        binned_rows = jnp.pad(binned_rows, ((0, pad), (0, 0)))
-        gh = jnp.pad(gh, ((0, pad), (0, 0)))
-    binned_rows = binned_rows.reshape(n_chunks, chunk_size, f)
-    gh = gh.reshape(n_chunks, chunk_size, 3)
-
-    def body(acc, chunk):
-        b, g = chunk
-        return acc + _hist_chunk(b, g, num_bins), None
-
-    # the carry is seeded from the FIRST chunk (not zeros) so its type
-    # carries the data's varying-manual-axes when this runs inside a
-    # shard_map region (a replicated zeros carry + varying per-chunk
-    # additions fails shard_map's carry type check); outside shard_map
-    # it is the same arithmetic with one add saved
-    init = _hist_chunk(binned_rows[0], gh[0], num_bins)
-    hist, _ = jax.lax.scan(body, init, (binned_rows[1:], gh[1:]))
-    return hist
+    hist = _sum_chunks(_hist_chunk, binned_rows, gh, num_bins, chunk_size)
+    return hist[..., :3] + hist[..., 3:]
 
 
 def accumulate_histogram(acc: jax.Array, binned_rows: jax.Array,
@@ -142,29 +232,6 @@ def subtract_histogram(parent: jax.Array, child: jax.Array) -> jax.Array:
     return parent - child
 
 
-def _hist_chunk_q(binned_chunk: jax.Array, ghq_chunk: jax.Array,
-                  num_bins: int) -> jax.Array:
-    """Integer one-hot contraction for one chunk.
-
-    binned_chunk: (C, F) int bin codes
-    ghq_chunk:    (C, 3) int8/int32 [qg, qh, valid]
-    returns       (F, B, 3) int32 EXACT partial histogram
-
-    ONE matmul where the float path needs the bf16 hi/lo pair: the
-    one-hot is cast to the operand dtype (i8 rides the MXU's native int8
-    path) and the int32 accumulator is exact, so there is no split-
-    precision correction pass and no rounding of the per-bin sums.
-    """
-    c, f = binned_chunk.shape
-    iota = jnp.arange(num_bins, dtype=jnp.int32)
-    onehot = (binned_chunk.astype(jnp.int32)[:, :, None] == iota[None, None, :])
-    onehot2d = onehot.reshape(c, f * num_bins).astype(ghq_chunk.dtype)
-    dn = (((0,), (0,)), ((), ()))
-    hist = jax.lax.dot_general(onehot2d, ghq_chunk, dimension_numbers=dn,
-                               preferred_element_type=jnp.int32)
-    return hist.reshape(f, num_bins, 3)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "chunk_size", "use_pallas"))
 def build_histogram_quantized(binned_rows: jax.Array, ghq: jax.Array,
@@ -182,27 +249,7 @@ def build_histogram_quantized(binned_rows: jax.Array, ghq: jax.Array,
     if use_pallas:
         return _pallas_hist.build_histogram_pallas_quantized(
             binned_rows, ghq, num_bins)
-    p, f = binned_rows.shape
-    chunk_size = resolve_chunk_size(chunk_size, f, num_bins)
-    if p <= chunk_size:
-        return _hist_chunk_q(binned_rows, ghq, num_bins)
-    n_chunks = (p + chunk_size - 1) // chunk_size
-    pad = n_chunks * chunk_size - p
-    if pad:
-        binned_rows = jnp.pad(binned_rows, ((0, pad), (0, 0)))
-        ghq = jnp.pad(ghq, ((0, pad), (0, 0)))
-    binned_rows = binned_rows.reshape(n_chunks, chunk_size, f)
-    ghq = ghq.reshape(n_chunks, chunk_size, 3)
-
-    def body(acc, chunk):
-        b, g = chunk
-        return acc + _hist_chunk_q(b, g, num_bins), None
-
-    # carry seeded from the FIRST chunk for the same shard_map varying-
-    # manual-axes reason as the float path above
-    init = _hist_chunk_q(binned_rows[0], ghq[0], num_bins)
-    hist, _ = jax.lax.scan(body, init, (binned_rows[1:], ghq[1:]))
-    return hist
+    return _sum_chunks(_hist_chunk_q, binned_rows, ghq, num_bins, chunk_size)
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "bucket",

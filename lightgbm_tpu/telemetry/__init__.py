@@ -22,7 +22,8 @@ to them, which is how a device trace (whose events carry instruction
 names and nothing else) is read by stage, and
 `table_copies_in_split_loop(hlo_text)` counts the copies of the packed
 table the compiler left inside the split loop (none, while the table
-is updated in place).
+is updated in place), and `hist_plane_elems_per_row(hlo_text)` sizes the
+one-hot planes the histogram stages write out (F x 256 a row unfactored).
 
 Built on top of those, the flight-recorder layer: `events` (durable
 structured per-iteration JSONL stream, `LGBM_TPU_EVENTS=path`),
@@ -46,6 +47,7 @@ See docs/Observability.md.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 from typing import Dict, Optional, Tuple
@@ -61,7 +63,7 @@ __all__ = ["counters", "recorder", "spans", "span", "events", "watchdogs",
            "telemetry_summary", "phase_breakdown", "prometheus_text",
            "record_iteration", "reset", "xla_trace_active",
            "note_grow_dispatches", "STAGES", "stage_map",
-           "table_copies_in_split_loop"]
+           "table_copies_in_split_loop", "hist_plane_elems_per_row"]
 
 MODES = ("off", "summary", "trace")
 _mode = "off"
@@ -166,6 +168,68 @@ def table_copies_in_split_loop(hlo_text: str) -> Dict[str, int]:
             if count:
                 out[comp] = count
     return out
+
+
+_HIST_STAGES = ("root_hist", "child_hist")
+_FUSION_CALL = re.compile(r"\bfusion\(([^)]*)\).*\bcalls=%?([\w.\-]+)")
+_PRODUCT = re.compile(r"\bconvolution\(%?([\w.\-]+),.*\bdim_labels=(\w+)_")
+_ARRAY_DIMS = re.compile(r"\w+\[(\d+(?:,\d+)*)\]")
+
+
+def _result_dims(lines: list) -> Dict[str, list]:
+    """{instruction name: the dimensions of the one array it produces}
+    over a computation's lines (an instruction producing a tuple is left
+    out: no product reads a tuple)."""
+    out = {}
+    for line in lines:
+        name, _, rest = line.strip().partition(" = ")
+        found = _ARRAY_DIMS.match(rest)
+        if found:
+            name = name.replace("ROOT ", "").lstrip("%")
+            out[name] = [int(d) for d in found.group(1).split(",")]
+    return out
+
+
+def hist_plane_elems_per_row(hlo_text: str) -> int:
+    """The widest array a histogram product of a compiled module reads
+    from memory, in elements a histogrammed row: over the fusions under
+    a `lgbm.root_hist` / `lgbm.child_hist` scope that hold a
+    `convolution` (the TPU compiler's form of the one-hot contraction),
+    the rows are the length the convolution contracts, and over the
+    fusion's operands with a dimension of that length the count is the
+    operand's elements over the rows. What a fusion computes inside it
+    is never stored, so this is the one-hot plane as it is written out
+    and read back: F x 256 with the unfactored one-hot; with the
+    factored one (ops/histogram.py) F x LO_BINS, the `lo` codes
+    broadcast over their columns, and the codes' own F once the
+    compiler builds the planes inside the product's fusion. 0 for a module with no such product (a CPU
+    module's contraction is a `dot`) (PERF.md §6, PR 32)."""
+    comps = _computations(hlo_text)
+    widest = 0
+    for lines in comps.values():
+        dims_here = None
+        for line in lines:
+            found = _HLO_INSTRUCTION.match(line)
+            call = found and _FUSION_CALL.search(line)
+            if not call:
+                continue
+            stages = _STAGE_IN_OP_NAME.findall(found.group(2))
+            body = comps.get(call.group(2), [])
+            product = _PRODUCT.search("\n".join(body))
+            if (not stages or stages[-1] not in _HIST_STAGES
+                    or not product):
+                continue
+            lhs = _result_dims(body).get(product.group(1))
+            if not lhs:
+                continue
+            rows = lhs[product.group(2).index("f")]
+            if dims_here is None:
+                dims_here = _result_dims(lines)
+            for operand in call.group(1).split(","):
+                dims = dims_here.get(operand.strip().lstrip("%"), [])
+                if rows in dims:
+                    widest = max(widest, math.prod(dims) // rows)
+    return widest
 
 # -- XLA timeline (jax.profiler) under trace mode ---------------------------
 # Opt-in via LGBM_TPU_XLA_TRACE=<dir>: entering trace mode starts a

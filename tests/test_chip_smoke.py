@@ -45,8 +45,8 @@ def test_smoke_cpu_rehearsal_passes_every_single_device_leg(tmp_path):
     report = json.loads(lines[-2])
     assert report["rehearsal"] is True
     legs = report["legs"]
-    for name in ("train255", "train63", "reference", "predict", "serve",
-                 "kernels", "cache"):
+    for name in ("histogram", "train255", "train63", "reference", "predict",
+                 "serve", "kernels", "cache"):
         assert isinstance(legs[name], dict), (name, legs[name])
     assert legs["fourchip"].startswith("did not run: 1 device")
     assert legs["cache"]["dir"] == str(tmp_path / "cc")

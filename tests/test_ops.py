@@ -53,6 +53,77 @@ def test_histogram_chunked_matches():
     np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-3)
 
 
+def _histogram_case(f, num_bins, rows, code_dtype, seed):
+    """Codes, float and int8 operands and their float64 / int64 sums.
+    The last rows are padding: operand 0 and arbitrary codes (negative
+    ones where the type has them)."""
+    r = np.random.RandomState(seed)
+    codes = r.randint(0, num_bins, size=(rows, f)).astype(code_dtype)
+    gh = np.stack([r.randn(rows), r.rand(rows), np.ones(rows)],
+                  axis=1).astype(np.float32)
+    ghq = np.stack([r.randint(-127, 128, rows), r.randint(0, 128, rows),
+                    np.ones(rows, np.int64)], axis=1).astype(np.int8)
+    pad = max(1, rows // 10)
+    gh[-pad:] = 0
+    ghq[-pad:] = 0
+    if np.issubdtype(code_dtype, np.signedinteger):
+        codes[-pad:] = -1 - codes[-pad:]
+    want = np.zeros((f, num_bins, 3))
+    want_q = np.zeros((f, num_bins, 3), np.int64)
+    kept = codes[:-pad].astype(np.int64)
+    for j in range(f):
+        np.add.at(want[j], kept[:, j], gh[:-pad].astype(np.float64))
+        np.add.at(want_q[j], kept[:, j], ghq[:-pad].astype(np.int64))
+    return codes, gh, ghq, want, want_q
+
+
+_HIST_CHUNK = 64
+# (F, num_bins) x rows below, at and above one chunk (the last neither a
+# multiple of it nor of 8); the codes' type goes round with the case
+_HIST_GRID = [
+    pytest.param(f, b, rows, (np.uint8, np.int16, np.int8)[
+        (i + j + k) % (3 if b <= 128 else 2)], id=f"{f}x{b}-rows{rows}")
+    for i, f in enumerate((1, 5, 28, 67))
+    for j, b in enumerate((2, 16, 63, 255, 256))
+    for k, rows in enumerate((40, 64, 150))]
+
+
+@pytest.mark.parametrize("f,num_bins,rows,code_dtype", _HIST_GRID)
+def test_histogram_grid_matches_float64(f, num_bins, rows, code_dtype):
+    codes, gh, _, want, _ = _histogram_case(f, num_bins, rows, code_dtype,
+                                            seed=f * num_bins + rows)
+    got = np.asarray(hist_ops.build_histogram(
+        jnp.asarray(codes), jnp.asarray(gh), num_bins=num_bins,
+        chunk_size=_HIST_CHUNK))
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("f,num_bins,rows,code_dtype", _HIST_GRID)
+def test_quantized_histogram_grid_is_exact(f, num_bins, rows, code_dtype):
+    codes, _, ghq, _, want_q = _histogram_case(
+        f, num_bins, rows, code_dtype, seed=f * num_bins + rows)
+    got = np.asarray(hist_ops.build_histogram_quantized(
+        jnp.asarray(codes), jnp.asarray(ghq), num_bins=num_bins,
+        chunk_size=_HIST_CHUNK))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want_q)
+
+
+@pytest.mark.parametrize("f,num_bins", [(28, 256), (5, 64)])
+def test_histogram_at_the_derived_chunk(f, num_bins):
+    """Two chunks and a ragged third at the chunk the shape resolves to."""
+    chunk = hist_ops.resolve_chunk_size(0, f, num_bins)
+    codes, gh, ghq, want, want_q = _histogram_case(
+        f, num_bins, 2 * chunk + 77, np.uint8, seed=f)
+    got = np.asarray(hist_ops.build_histogram(
+        jnp.asarray(codes), jnp.asarray(gh), num_bins=num_bins))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+    got_q = np.asarray(hist_ops.build_histogram_quantized(
+        jnp.asarray(codes), jnp.asarray(ghq), num_bins=num_bins))
+    np.testing.assert_array_equal(got_q, want_q)
+
+
 def test_subtraction():
     r = np.random.RandomState(2)
     parent = r.randn(4, 8, 3).astype(np.float32)

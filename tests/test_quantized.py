@@ -161,11 +161,13 @@ def test_pallas_quantized_kernel_matches_xla():
 def test_resolve_chunk_size():
     # explicit wins
     assert hist_ops.resolve_chunk_size(1024, 28, 64) == 1024
-    # large F*B keeps the historical floor
-    assert hist_ops.resolve_chunk_size(0, 28, 256) == 2048
-    # small F*B derives a larger chunk (MXU fill), clamped + 256-aligned
-    small = hist_ops.resolve_chunk_size(0, 4, 16)
-    assert small > 2048 and small <= 32768 and small % 256 == 0
+    # derived from the factored planes' width: a power of two, the two
+    # cells' fastest on the chip (PERF.md §6, PR 32), the floor for a
+    # wide shape and the ceiling for a narrow one
+    assert hist_ops.resolve_chunk_size(0, 28, 256) == 8192
+    assert hist_ops.resolve_chunk_size(0, 67, 256) == 4096
+    assert hist_ops.resolve_chunk_size(0, 500, 256) == 2048
+    assert hist_ops.resolve_chunk_size(0, 4, 16) == 32768
 
 
 def test_chunk_size_does_not_change_histogram():

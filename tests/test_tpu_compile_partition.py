@@ -5,14 +5,18 @@ slow row scatter, so no scatter may be wider than one tile; and layout
 assignment would hand the tile's words-minor layout (512 B for a 44-byte
 row) to every window-sized buffer, so none may have it. And a buffer
 that crosses a `conditional` is copied on its way in and out, so the
-packed table may cross none inside the split loop (PR 30). Only this
-file loads the TPU's library, inside the fixture."""
+packed table may cross none inside the split loop (PR 30). And the
+histogram's one-hot is factored, so no plane of F x 256 elements a row
+is written out, and the gradients' bf16 remainder survives XLA's default
+flags (PR 32). Only this file loads the TPU's library, inside the
+fixture."""
 import os
 import re
 import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 
@@ -75,6 +79,57 @@ def test_split_scan_and_wide_rows_compile(one_chip, features, d_cols):
         shaped((rows,), jnp.int32)).compile()
 
 
+def _histogram_module(one_chip, features, rows):
+    """`build_histogram` at 256 bins under the root's scope, compiled
+    for the described chip at the flags this process has (XLA's
+    defaults: the suite sets none that touch precision)."""
+    from lightgbm_tpu.ops import histogram as hist_ops
+
+    def root_hist(codes, gh):
+        with jax.named_scope("lgbm.root_hist"):
+            return hist_ops.build_histogram(codes, gh, 256)
+
+    return jax.jit(root_hist).lower(
+        jax.ShapeDtypeStruct((rows, features), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, 3), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("features", [28,       # `higgs`
+                                      67])      # `criteo-share`
+def test_histogram_writes_no_plane_of_256_bins_a_feature(one_chip, features):
+    """At a cell's width: no array of chunk x F x 256 elements anywhere
+    in the module (the unfactored one-hot, `pred[2048,F,256]` before
+    PR 32), and the widest array a product reads is under F x 128
+    elements a row (F x 256 before; F x 64, rounded up to whole feature
+    groups, with LO_BINS = 64: the `lo` codes broadcast over their 64
+    columns)."""
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.ops import histogram as hist_ops
+    chunk = hist_ops.resolve_chunk_size(0, features, 256)
+    txt = _histogram_module(one_chip, features, 4 * chunk)
+    sizes = {int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"\w+\[(\d+(?:,\d+)+)\]", txt)}
+    assert chunk * features * 256 not in sizes
+    assert max(sizes) < chunk * features * 128
+    per_row = telemetry.hist_plane_elems_per_row(txt)
+    assert 0 < per_row < features * 128, per_row
+
+
+def test_gradient_remainder_survives_default_flags(one_chip):
+    """The six-column operand is [head | gh - head] with the head a
+    `reduce-precision`, which `--xla_allow_excess_precision=true` (the
+    default) may not fold away: written as float32(bfloat16(gh)) the
+    head was gh itself and the remainder a constant 0 (PERF.md §7,
+    fault 1)."""
+    txt = _histogram_module(one_chip, 28, 4096)
+    heads = re.findall(r"%([\w.\-]+) = f32\[[\d,]+\]\S* reduce-precision\("
+                       r"[^)]*\), exponent_bits=8, mantissa_bits=7", txt)
+    assert heads
+    assert any(re.search(r" subtract\(%[\w.\-]+, %" + re.escape(h) + r"\)", txt)
+               for h in heads), "no gh - head in the module"
+
+
 COMPILE_LIMIT_S = 240
 
 
@@ -89,7 +144,6 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
     wherever it appears. With the table handed through a `conditional`
     (the `lax.switch` over the rungs, before PR 30) the same count read
     6, one copy in each of six of the eight branches."""
-    import numpy as np
     from lightgbm_tpu import telemetry
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import Dataset

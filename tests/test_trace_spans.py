@@ -221,6 +221,43 @@ def test_table_copies_are_counted_inside_the_split_loop_only(dispatch,
     assert telemetry.table_copies_in_split_loop(HLO_TOY) == {}
 
 
+# a histogram product as the TPU compiler writes it: a fusion round a
+# `convolution` that contracts the rows; %s are the plane's dimensions
+# after the 2,048 rows and the stage's scope
+HLO_HIST_PRODUCT = """HloModule jit_step_impl, entry_computation_layout={()->f32[8]}
+
+%%fused_computation.5 (p0: pred[2048,%(plane)s], p1: bf16[2048,6], p2: f32[8]) -> f32[8] {
+  %%p0 = pred[2048,%(plane)s]{0,1:T(8,128)(4,1)} parameter(0)
+  %%p1 = bf16[2048,6]{0,1} parameter(1)
+  %%p2 = f32[8]{0} parameter(2)
+  %%fusion.2 = bf16[2048,%(plane)s]{0,1:T(8,128)(2,1)} fusion(%%p0, %%p1), kind=kLoop, calls=%%fused_computation.4
+  ROOT %%convolution.1 = f32[8]{0} convolution(%%fusion.2, %%p1), dim_labels=fb_io->bf, metadata={op_name="jit(step_impl)/%(scope)s/dot_general"}
+}
+
+ENTRY %%main (a: pred[2048,%(plane)s], g: bf16[2048,6], acc: f32[8]) -> f32[8] {
+  %%a = pred[2048,%(plane)s]{0,1:T(8,128)(4,1)} parameter(0)
+  %%g = bf16[2048,6]{0,1} parameter(1)
+  %%acc = f32[8]{0} parameter(2)
+  %%pool = f32[255,67,256,3]{3,2,1,0} broadcast(%%acc), metadata={op_name="jit(step_impl)/%(scope)s/broadcast"}
+  ROOT %%fusion.9 = f32[8]{0} fusion(%%a, %%g, %%acc), kind=kOutput, calls=%%fused_computation.5, metadata={op_name="jit(step_impl)/%(scope)s/dot_general"}
+}
+"""
+
+
+@pytest.mark.parametrize("plane,scope,per_row", [
+    ("17152", "while/body/lgbm.child_hist", 17152),     # 67 x 256 unfactored
+    ("17,192", "lgbm.root_hist", 17 * 192),             # factored, LO_BINS 32
+    ("17152", "lgbm.split_scan", 0)])                   # no histogram's
+def test_hist_plane_elems_per_row_reads_the_products_operands(plane, scope,
+                                                              per_row):
+    """The widest operand with the contracted length among its
+    dimensions, over that length; neither the gradient columns nor an
+    array the product does not read (the histogram pool) count."""
+    text = HLO_HIST_PRODUCT % {"plane": plane, "scope": scope}
+    assert telemetry.hist_plane_elems_per_row(text) == per_row
+    assert telemetry.hist_plane_elems_per_row(HLO_TOY) == 0
+
+
 def test_every_named_scope_is_a_stage_and_every_stage_a_scope():
     used = set()
     for root, _dirs, files in os.walk(os.path.join(REPO, "lightgbm_tpu")):
@@ -248,6 +285,10 @@ def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
     # is tests/test_tpu_compile_partition.py's to say)
     assert (step.table_copies(*_step_args(gbdt))
             == telemetry.table_copies_in_split_loop(text))
+    # (the CPU compiler's contraction is a `dot`: nothing to size)
+    assert (step.hist_plane_elems(*_step_args(gbdt))
+            == telemetry.hist_plane_elems_per_row(text)
+            == telemetry.counters.get("hist_plane_elems_per_row", -1))
     owned = {}
     for name, (stage, rung) in stage_of.items():
         owned.setdefault(stage, []).append((name, rung))
