@@ -80,7 +80,6 @@ def main(argv=None, root=None, allow_cpu=False):
     if str(CODE_ROOT) not in sys.path:
         sys.path.insert(0, str(CODE_ROOT))
     from benchmark import drive, spec
-    from benchmark.reference.gbdt_reference import Reference
     from benchmark.traffic import train
     cell = spec.load_cell(Path(root) if root else CODE_ROOT, args.workload)
     spec.apply_xla_flags(cell["config"])
@@ -91,10 +90,11 @@ def main(argv=None, root=None, allow_cpu=False):
         train_set = state.pop("booster").train_set
         gc.collect()
         narrowed = train.boost(train_set, dict(
-            state["params"], feature_fraction=0.5), state["steps"], {})
+            state["params"], feature_fraction=0.5), state["steps"], {},
+            state["ref"])
         del narrowed["booster"], train_set
         gc.collect()
-        reference = Reference(state["x"], state["y"], state["params"], seed)
+        reference = train.build_reference(state, seed)
         out = variants(reference, state["outputs"], seed)
         out["half_features"] = reference.follow(narrowed["outputs"])
         print(json.dumps({"workload": args.workload, "seed": seed,

@@ -1,8 +1,8 @@
 """Finds, by the names in `BENCHMARK.json`, the files that belong to one
 cell: its workload file, its configuration, its traffic mix, its table
-generator and the readers of its per-layer metrics. Data files are read
-from `root` (the checkout that holds `BENCHMARK.json`); code is the
-harness's own."""
+generator, its plain reference and the readers of its per-layer metrics.
+Data files are read from `root` (the checkout that holds
+`BENCHMARK.json`); code is the harness's own."""
 import importlib
 import json
 import os
@@ -61,6 +61,12 @@ def load_generator(name):
     return importlib.import_module(f"benchmark.datagen.{name}")
 
 
+def load_reference(name):
+    """The plain reference a configuration names under `reference`
+    (`reference/<name>.py`: `Reference`, `Outputs`, `TREE_KEYS`)."""
+    return importlib.import_module(f"benchmark.reference.{name}")
+
+
 def load_layer_metric(name):
     """The reader module of one per-layer metric, found by its name
     (`.` in a metric's name is `__` in the file's)."""
@@ -68,7 +74,8 @@ def load_layer_metric(name):
         "benchmark.layer_metrics." + name.replace(".", "__"))
 
 
-def layer_metric_names():
+def layer_metric_names(here=HERE):
+    """The metrics that have a reader file under `here`/layer_metrics."""
     return sorted(p.stem.replace("__", ".")
-                  for p in (HERE / "layer_metrics").glob("*.py")
+                  for p in (Path(here) / "layer_metrics").glob("*.py")
                   if not p.stem.startswith("_"))

@@ -7,6 +7,13 @@ Set-up builds ONE booster, drives it from the seed through its first
 `checked_steps` iterations (they compile and warm the pipeline, and they
 are the steps the reference follows), and hands that same booster to the
 window. Reports `train_row_trees_per_s` and `setup_s`.
+
+One loop for every objective. What an objective needs besides the table
+comes with the table: a generator may return `(x, y, fields)`, `fields`
+the `lgb.Dataset` keyword arguments made from the seed (`group`,
+`weight`), and a configuration may name its plain reference
+(`reference`, the module `reference/<name>.py`; `gbdt_reference` where
+it names none). `benchmark/README.md` has the contract of both.
 """
 import gc
 import shutil
@@ -17,7 +24,6 @@ import numpy as np
 
 from benchmark import spec, trace_reduce, work_model
 from benchmark.drive import device_record, find_devices, timed
-from benchmark.reference.gbdt_reference import TREE_KEYS, Outputs, Reference
 
 WORK_KEYS = ("num_leaves", "left_child", "right_child", "internal_count",
              "leaf_count")
@@ -35,12 +41,20 @@ def tree_arrays(tree, keys):
     return out
 
 
+def table_and_fields(table):
+    """`(x, y, fields)` of what a generator returned: `(x, y)`, or
+    `(x, y, fields)` with the Dataset's further keyword arguments."""
+    x, y = table[:2]
+    return x, y, dict(table[2]) if len(table) > 2 else {}
+
+
 def first_steps(cell, seed, phases):
     """Set-up: the table from the seed, the Dataset, ONE booster, and its
     first `checked_steps` iterations with the score row after each."""
     import lightgbm_tpu as lgb
 
     conf = cell["config"]
+    ref = spec.load_reference(conf.get("reference", "gbdt_reference"))
     params = dict(conf["params"])
     params.update(cell["workload"].get("params", {}))
     rows, features = int(conf["rows"]), int(conf["features"])
@@ -48,7 +62,8 @@ def first_steps(cell, seed, phases):
 
     with timed(phases, "datagen_s"):
         gen = spec.load_generator(conf["generator"])
-        x, y = gen.generate(seed, rows, features, conf["generator_params"])
+        x, y, fields = table_and_fields(
+            gen.generate(seed, rows, features, conf["generator_params"]))
     with timed(phases, "dataset_construct_s"):
         # Bin boundaries come from the table's first `bins_rows` rows,
         # in the order of the configuration's fixed `bins_seed`, as a
@@ -56,21 +71,25 @@ def first_steps(cell, seed, phases):
         # a feature, the bin of zero) into its tree program as constants,
         # so boundaries found on each seed's own sample of the rows would
         # make every seed a new program to compile.
-        sample = gen.generate(int(conf["bins_seed"]), int(conf["bins_rows"]),
-                              features, conf["generator_params"])
-        bins_from = lgb.Dataset(*sample, params=dict(params)).construct()
+        bx, by, bins_fields = table_and_fields(
+            gen.generate(int(conf["bins_seed"]), int(conf["bins_rows"]),
+                         features, conf["generator_params"]))
+        bins_from = lgb.Dataset(bx, by, params=dict(params),
+                                **bins_fields).construct()
         train_set = lgb.Dataset(x, y, reference=bins_from,
-                                params=dict(params))
+                                params=dict(params), **fields)
         train_set.construct()
-    state = boost(train_set, params, steps, phases)
-    state.update(x=x, y=y, params=params, rows=rows, features=features,
-                 steps=steps)
+    state = boost(train_set, params, steps, phases, ref)
+    state.update(x=x, y=y, fields=fields, params=params, rows=rows,
+                 features=features, steps=steps, ref=ref)
     return state
 
 
-def boost(train_set, params, steps, phases):
+def boost(train_set, params, steps, phases, ref):
     """ONE booster on `train_set`, driven through its first `steps`
-    iterations; the score row is read back after each."""
+    iterations; the score row is read back after each. `ref` is the
+    configuration's reference module: it says which of a tree's arrays
+    it follows (`TREE_KEYS`) and takes them as its `Outputs`."""
     import jax
     import lightgbm_tpu as lgb
 
@@ -85,15 +104,24 @@ def boost(train_set, params, steps, phases):
             scores.append(np.asarray(
                 jax.device_get(gbdt.score_updater.score))[0].copy())
             step_s.append(time.perf_counter() - tick)
-        first_trees = [tree_arrays(t, TREE_KEYS)
+        first_trees = [tree_arrays(t, ref.TREE_KEYS)
                        for t in gbdt.models[:steps]]
-    return {"booster": booster, "outputs": Outputs(first_trees, scores),
+    return {"booster": booster,
+            "outputs": ref.Outputs(first_trees, scores),
             "step_s": min(step_s)}
+
+
+def build_reference(state, seed):
+    """The configuration's reference on the table the Dataset was made
+    of; it is handed the Dataset's `fields` only where there are any."""
+    extra = {"fields": state["fields"]} if state["fields"] else {}
+    return state["ref"].Reference(state["x"], state["y"], state["params"],
+                                  seed, **extra)
 
 
 def check_first_steps(state, seed):
     """The reference over the first steps: the numbers compared."""
-    reference = Reference(state["x"], state["y"], state["params"], seed)
+    reference = build_reference(state, seed)
     readings = reference.follow(state["outputs"])
     readings["steps_missing"] = state["steps"] - min(
         len(state["outputs"].trees), len(state["outputs"].scores))

@@ -1,24 +1,23 @@
 """`criteo-share` and its cell `criteo-train` (PR 29): the table's
 generator, the cell's files, and a rehearsal of the cell on the CPU at a
-tiny size, with the program's gauge and counters that came with it (no
-per-layer metric reads them yet: PERF.md §7). CPU, tiny sizes: counts
+tiny size, with the program's gauge and counters that came with it and
+the two per-layer metrics that read them (PR 33). CPU, tiny sizes: counts
 and arithmetic only. The float32 cancellation the cell guards
 needs sums of ~1e6 and is `tests/test_split_small_child.py`'s to catch.
 """
-import contextlib
-import io
 import json
 
 import numpy as np
 import pytest
 
+import bench_rehearsal
 from bench_rehearsal import ROOT
 
-from benchmark import run, spec
+from benchmark import spec
 from benchmark.datagen import criteo_like
 
 CONF = json.loads((ROOT / "benchmark/configs/criteo-share.json").read_text())
-BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+BENCH = bench_rehearsal.load_bench(ROOT)
 GEN = CONF["generator_params"]
 F = CONF["features"]
 ROWS = 300_000
@@ -115,24 +114,21 @@ def test_cell_resolves_and_states_its_deployment():
         "leaf_count_mismatch", "leaf_value_gap", "loss_gap",
         "update_norm_gap", "split_gain_shortfall", "steps_missing",
         "compiles_in_window", "nonfinite_score"}
-    # no per-layer metric lists its cells: the new cell reports them all
-    assert cell["per_layer"] == BENCH["per_layer"]
-    assert not any("workloads" in m for m in BENCH["per_layer"])
+    # the cell reports every per-layer metric that lists no cells or
+    # lists it, `missing_split_share` (its table has NaN columns) among
+    # them
+    bench_rehearsal.check_workloads_lists_are_sound(ROOT)
+    assert "missing_split_share" in [m["name"] for m in cell["per_layer"]]
 
 
 def test_new_entries_are_appended_and_nothing_moved():
-    """What the benchmark had stands where it stood; this PR's
-    configuration and cell come after it, and `per_layer` is as it was
-    (its last entry is pinned as the last by PR 27's own test)."""
-    assert [c["name"] for c in BENCH["configs"]] == ["higgs", "criteo-share"]
-    assert [w["name"] for w in BENCH["workloads"]] == ["higgs-train",
-                                                       "criteo-train"]
-    assert BENCH["workloads"][1] == {
-        "name": "criteo-train", "config": "criteo-share",
-        "traffic": "train_window", "chips": 1,
-        "why": BENCH["workloads"][1]["why"]}
-    names = [m["name"] for m in BENCH["per_layer"]]
-    assert len(names) == 16 and names[-1] == "tiled_partition_row_share"
+    """What the benchmark had stands where it stood, as a prefix: the
+    two configurations, the two cells and the sixteen per-layer names in
+    their order. Whatever follows is free, and is held to what the
+    harness needs of it: files found by name, `reduced` and `source` the
+    configuration file's, limits present."""
+    bench_rehearsal.check_what_stands_is_a_prefix(ROOT)
+    bench_rehearsal.check_every_file_is_found_by_name(ROOT)
 
 
 # -- a rehearsal of the cell ---------------------------------------------
@@ -151,6 +147,10 @@ def tiny_criteo_root(tmp_path_factory):
                       .read_text())
     work["config"] = "tiny-criteo"
     bench = dict(BENCH)
+    # a metric listed for `criteo-train` is listed for its tiny cell
+    bench["per_layer"] = [
+        dict(m, workloads=[TINY]) if "criteo-train" in m.get("workloads", [])
+        else m for m in BENCH["per_layer"]]
     bench["configs"] = [{"name": "tiny-criteo", "source": conf["source"],
                          "file": "benchmark/configs/tiny-criteo.json",
                          "reduced": ["rows"], "why": "tiny rehearsal"}]
@@ -169,14 +169,7 @@ def tiny_criteo_root(tmp_path_factory):
 
 
 def run_tiny(root, trace=0):
-    out = io.StringIO()
-    with contextlib.redirect_stdout(out):
-        rc = run.main(["--workload", TINY, "--seed", str(SEED),
-                       "--seconds", "0.5", "--trace", str(trace)],
-                      root=root, allow_cpu=True)
-    assert rc == 0
-    return json.loads([ln for ln in out.getvalue().splitlines()
-                       if ln.startswith("{")][-1])
+    return bench_rehearsal.run_cell(root, TINY, SEED, trace=trace)
 
 
 def failing(line):
@@ -198,6 +191,12 @@ def test_rehearsal_reads_correct_and_feeds_the_new_counters(
     assert counters.get("table_bytes_per_row") == 84.0
     assert 0 < counters.get("splits_on_missing_feature") \
         < counters.get("splits")
+    # and the traced line holds the two metrics that read them
+    assert line["metrics"]["table_bytes_per_row"] == {"value": 84.0,
+                                                      "unit": "B"}
+    assert line["metrics"]["missing_split_share"] == {
+        "value": 100 * counters.get("splits_on_missing_feature")
+        / counters.get("splits"), "unit": "%"}
 
 
 def test_rehearsal_comes_out_false_under_a_planted_fault(

@@ -1,19 +1,17 @@
 """CPU rehearsal of the harness at a tiny size, and the shape of
 `BENCHMARK.json`."""
 import json
-import re
 import subprocess
 import sys
 
 import pytest
 
+import bench_rehearsal
 from bench_rehearsal import ROOT, tiny_root  # noqa: F401 (a fixture)
 
-from benchmark import drive, run, spec
+from benchmark import run, spec
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+BENCH = bench_rehearsal.load_bench(ROOT)
 
 
 def last_line(capsys):
@@ -68,58 +66,13 @@ def test_no_accelerator_is_an_error_and_prints_no_line(tiny_root):
     assert "no accelerator" in proc.stderr
 
 
-def names_and_units():
-    for section in ("configs", "workloads", "end_to_end", "per_layer"):
-        for entry in BENCH[section]:
-            yield section, entry
-
-
 def test_names_units_and_key_sets():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    seen = set()
-    for section, entry in names_and_units():
-        assert NAME.match(entry["name"]), entry
-        assert (section, entry["name"]) not in seen
-        seen.add((section, entry["name"]))
-        if "unit" in entry:
-            assert UNIT.match(entry["unit"]), entry
-            assert entry["better"] in ("lower", "higher")
-    for c in BENCH["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert all(NAME.match(k) for k in c["reduced"])
-    for w in BENCH["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
-    for m in BENCH["end_to_end"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better",
-                                          "bound", "source"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0 < m["bound"] <= 0.1
-    e2e = {m["name"] for m in BENCH["end_to_end"]}
-    assert "setup_s" in e2e
-    for m in BENCH["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better",
-                                          "source", "layer", "moves"}
-        assert m["moves"] in e2e
-    assert 1 <= BENCH["run_seconds"] <= 51
+    bench_rehearsal.check_names_units_and_key_sets(ROOT)
 
 
 def test_every_file_is_found_by_name():
-    for w in BENCH["workloads"]:
-        cell = spec.load_cell(ROOT, w["name"])
-        assert cell["config"]["name"] == w["config"]
-        assert hasattr(drive.load_traffic(cell["traffic"]["kind"]), "run")
-        if "generator" in cell["config"]:
-            assert hasattr(spec.load_generator(
-                cell["config"]["generator"]), "generate")
-        conf = next(c for c in BENCH["configs"] if c["name"] == w["config"])
-        assert conf["reduced"] == cell["config"]["reduced"]
-        assert conf["source"] == cell["config"]["source"]
-        assert cell["workload"]["limits"]
-    declared = {m["name"]: m for m in BENCH["per_layer"]}
-    assert set(spec.layer_metric_names()) == set(declared)
-    for name, m in declared.items():
-        mod = spec.load_layer_metric(name)
-        assert (mod.LAYER, mod.UNIT, mod.SOURCE, mod.MOVES) == (
-            m["layer"], m["unit"], m["source"], m["moves"])
+    bench_rehearsal.check_every_file_is_found_by_name(ROOT)
+    # the harness loads the readers the check read, from the same files
+    for name in spec.layer_metric_names():
+        assert spec.load_layer_metric(name).__file__ == \
+            bench_rehearsal.reader_module(ROOT, name).__file__

@@ -2,13 +2,10 @@
 that go through the tiled partition, read from the program's counters
 `partition_tiled_rows` / `partition_rows`. CPU, tiny size: counts only,
 and the default partition off the TPU is `sort`, which never tiles."""
-import contextlib
-import io
-import json
-
+import bench_rehearsal
 from bench_rehearsal import ROOT, tiny_root  # noqa: F401 (a fixture)
 
-from benchmark import run, spec
+from benchmark import spec
 
 NAME = "tiled_partition_row_share"
 
@@ -34,11 +31,12 @@ def test_reader_gives_the_share_of_the_counted_rows(monkeypatch):
 
 
 def test_per_layer_list_and_reader_files_match_one_to_one():
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench = bench_rehearsal.load_bench(ROOT)
     declared = [m["name"] for m in bench["per_layer"]]
     assert sorted(declared) == spec.layer_metric_names()
-    assert declared[-1] == NAME         # appended, nothing moved
-    entry = bench["per_layer"][-1]
+    # held by name: where it stands among the sixteen is
+    # `check_what_stands_is_a_prefix`'s, and later entries come after it
+    entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
     mod = spec.load_layer_metric(NAME)
     assert entry == {"name": NAME, "unit": mod.UNIT, "better": "higher",
                      "source": mod.SOURCE, "layer": mod.LAYER,
@@ -59,16 +57,19 @@ def test_traced_line_of_a_tiling_learner_holds_the_share(
     monkeypatch.setenv("LGBM_TPU_PARTITION", "scan")
     monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", 4096)
     counters.reset()
-    out = io.StringIO()
-    with contextlib.redirect_stdout(out):
-        rc = run.main(["--workload", "tiny-train", "--seed",
-                       str(2**31 + 27), "--seconds", "1", "--trace", "1"],
-                      root=tiny_root, allow_cpu=True)
-    assert rc == 0
-    line = json.loads([ln for ln in out.getvalue().splitlines()
-                       if ln.startswith("{")][-1])
+    # something for `missing_split_share` to read, were it asked: it lists
+    # its cells and the tiny cell is in no list, so it is not
+    counters.incr("splits_on_missing_feature", 5.0)
+    line = bench_rehearsal.run_cell(tiny_root, "tiny-train", 2**31 + 27,
+                                    seconds=1, trace=1)
     assert line["correct"] is True
     share = line["metrics"][NAME]
     assert share["unit"] == "%" and 0 < share["value"] < 100
     assert share["value"] == (100 * counters.get("partition_tiled_rows")
                               / counters.get("partition_rows"))
+    # the packed row of 28 one-byte codes: 7 code words, 3 gradient
+    # words, the row id
+    assert line["metrics"]["table_bytes_per_row"] == {"value": 44.0,
+                                                      "unit": "B"}
+    assert counters.get("splits") > 0
+    assert "missing_split_share" not in line["metrics"]
