@@ -2257,8 +2257,12 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     `step.hist_plane_elems(...)` sizes the one-hot plane the histogram
     products read, in elements a row
     (telemetry.hist_plane_elems_per_row; it feeds the gauge of that
-    name) — for a reader of a device trace and for tests, never on the
-    hot path. A warm persistent
+    name), and `step.rank_pair_plane_elems(...)` the widest pair plane
+    stored under a `rank_bucket_<L>` scope of `lgbm.gradients`, in
+    elements (telemetry.rank_pair_plane_elems; the gauge of that name:
+    0 where the compiler builds the ranking objective's planes inside
+    its fusions) — for a reader of a device trace and for tests, never
+    on the hot path. A warm persistent
     compile cache hands back the executable as it was compiled, so a map
     from a cache entry older than the scopes comes back empty."""
     def step(*args):
@@ -2283,6 +2287,13 @@ def fused_step_surface(step_impl, make_args, obj_keys):
         return elems
 
     step.hist_plane_elems = hist_plane_elems
+
+    def rank_pair_plane_elems(*args):
+        elems = telemetry.rank_pair_plane_elems(compiled_text(*args))
+        telemetry.counters.set_gauge("rank_pair_plane_elems", elems)
+        return elems
+
+    step.rank_pair_plane_elems = rank_pair_plane_elems
     return step
 
 

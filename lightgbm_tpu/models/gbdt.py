@@ -282,7 +282,9 @@ class GBDT:
         if self.objective is None and cfg.objective != "none":
             self.objective = create_objective(cfg.objective, cfg)
         if self.objective is not None:
-            self.objective.init(train_set.metadata, train_set.num_data)
+            with telemetry.spans.stage("setup_objective_init_seconds",
+                                       "objective_init"):
+                self.objective.init(train_set.metadata, train_set.num_data)
             self.num_class = self.objective.num_model_per_iteration
         else:
             self.num_class = max(1, cfg.num_class)

@@ -577,13 +577,58 @@ class MulticlassOVA(Objective):
 
 
 # ----------------------------------------------------------------------
+# Live pair elements of one slice of a bucket: a slice holds as many whole
+# queries as fit under it (at least one), so no tensor the gradient pass
+# builds is larger than this many elements, whatever the table. Chosen
+# on the chip, where the pass reads the same from 2**21 to 2**24 (the
+# compiler builds the planes inside its fusions) and 2**23 holds two
+# queries of the longest bucket `msltr` has (PERF.md §6, PR 34); a
+# constant, not a parameter.
+PAIR_SLICE_ELEMS = 1 << 23
+MIN_BUCKET_LEN = 8
+
+
+def bucket_length(count: int) -> int:
+    """Padded length of a query of `count` documents: the power of two
+    over it, from MIN_BUCKET_LEN up."""
+    return max(MIN_BUCKET_LEN, 1 << (int(count) - 1).bit_length())
+
+
 class LambdarankNDCG(Objective):
     """LambdaRank with NDCG weighting (reference: rank_objective.hpp:23).
 
-    TPU-native formulation: queries are padded into (Q, L) segment tensors;
-    the per-query pairwise lambda accumulation (rank_objective.hpp:83-190)
-    becomes masked (L, L) outer products batched over query chunks. The
-    sigmoid table is replaced by the exact sigmoid (accuracy >= table).
+    The equations are the reference's (rank_objective.hpp:83-190), all
+    pairs of different labels of every query, with the exact sigmoid in
+    the place of its table (accuracy >= table). The layout is the
+    chip's:
+
+    * Queries are grouped into buckets by padded length
+      (`bucket_length`); a bucket of B queries at length L evaluates
+      B x L x L pair positions. Queries that can give no pair (one
+      document, or all labels alike) stay out of the pair work and get
+      zero gradients. The plan is made once, in `init`, from the query
+      boundaries and labels.
+    * A bucket's queries go through in slices of a fixed count
+      (`lax.map`), so the live pair tensors stay under
+      PAIR_SLICE_ELEMS elements at every table size.
+    * A query's rows are contiguous, so a bucket reads its scores,
+      labels and gains as one slice a query from the per-row vectors and
+      holds only (start, count, 1 / max DCG) a query: flat attributes
+      `_rank_start_<L>`, `_rank_count_<L>`, `_rank_inv_max_dcg_<L>`,
+      every one named by `device_buffer_names` whatever its size, so the
+      fused step takes them as jit arguments and two tables with the
+      same query lengths compile to one program.
+    * No sort: a document's rank is the count of the query's documents
+      that beat it (a higher score, or an equal one earlier in the
+      query: the stable descending order), one more reduction over the
+      pair plane, which also spares the inverse permutation.
+    * Each document's lambda is the sum over its partners of a signed
+      pair plane (c_ij = lambda_ij where l_i > l_j, -lambda_ji where
+      l_i < l_j), so the plane is reduced along one axis only.
+    * The write-back to rows is one gather: every row knows its place
+      in the buckets' concatenated output (`_rank_row_pos`; rows of
+      queries without pairs point at one trailing zero), where lambda
+      and hessian lie side by side.
     """
     name = "lambdarank"
 
@@ -600,93 +645,171 @@ class LambdarankNDCG(Objective):
         if qb is None:
             log.fatal("Lambdarank tasks require query information")
         self.query_boundaries = np.asarray(qb, dtype=np.int64)
+        starts = self.query_boundaries[:-1]
         counts = np.diff(self.query_boundaries)
         self.num_queries = len(counts)
-        lmax = int(counts.max())
-        # pad to a lane-friendly length
-        self.pad_len = max(8, 1 << (lmax - 1).bit_length())
-        q, L = self.num_queries, self.pad_len
-        idx = np.zeros((q, L), dtype=np.int32)
-        mask = np.zeros((q, L), dtype=bool)
-        for i in range(q):
-            c = counts[i]
-            idx[i, :c] = np.arange(self.query_boundaries[i],
-                                   self.query_boundaries[i + 1])
-            mask[i, :c] = True
-        self._idx = jnp.asarray(idx)
-        self._mask = jnp.asarray(mask)
-        labels = np.where(mask, self.label[idx.clip(0, num_data - 1)], 0.0)
-        # max DCG at top-k per query (reference DCGCalculator::CalMaxDCGAtK)
-        inv_max_dcg = np.zeros(q)
-        gains = self.label_gain[labels.astype(np.int32)]
-        discounts = 1.0 / np.log2(np.arange(L) + 2.0)
-        for i in range(q):
-            srt = np.sort(gains[i][mask[i]])[::-1]
-            k = min(self.optimize_pos_at, len(srt))
-            m = float(np.sum(srt[:k] * discounts[:k]))
-            inv_max_dcg[i] = 1.0 / m if m > 0 else 0.0
-        self._inv_max_dcg = jnp.asarray(inv_max_dcg, dtype=jnp.float32)
-        self._gains = jnp.asarray(gains, dtype=jnp.float32)
-        self._labels_pad = jnp.asarray(labels, dtype=jnp.float32)
-        self._discount = jnp.asarray(discounts, dtype=jnp.float32)
+        qid = np.repeat(np.arange(self.num_queries), counts)
+        gains = self.label_gain[self.label.astype(np.int32)]
+
+        # max DCG at top-k per query (reference DCGCalculator::
+        # CalMaxDCGAtK): gains sorted descending inside each query
+        order = np.lexsort((-gains, qid))
+        place = np.arange(num_data) - starts[qid]
+        top = place < self.optimize_pos_at
+        max_dcg = np.bincount(
+            qid[top], gains[order][top] / np.log2(place[top] + 2.0),
+            self.num_queries)
+        inv_max_dcg = np.divide(1.0, max_dcg, out=np.zeros_like(max_dcg),
+                                where=max_dcg > 0)
+
+        # a query gives a pair only where two of its labels differ
+        lo = np.minimum.reduceat(self.label, starts)
+        hi = np.maximum.reduceat(self.label, starts)
+        can_pair = (counts > 1) & (hi > lo)
+        lengths = np.array([bucket_length(c) for c in counts], np.int64)
+
+        self._gain_dev = _to_f32(gains)
+        self._buckets = []        # (L, queries a slice, slices)
+        slot = np.zeros(self.num_queries, np.int64)
+        filled = evaluated = 0
+        for L in np.unique(lengths[can_pair]).tolist():
+            members = np.nonzero(can_pair & (lengths == L))[0]
+            per = min(len(members), max(1, PAIR_SLICE_ELEMS // (L * L)))
+            slices = -(-len(members) // per)
+
+            def padded(values, dtype):
+                out = np.zeros(slices * per, dtype)
+                out[:len(members)] = values[members]
+                return jnp.asarray(out)
+
+            setattr(self, f"_rank_start_{L}", padded(starts, np.int32))
+            setattr(self, f"_rank_count_{L}", padded(counts, np.int32))
+            setattr(self, f"_rank_inv_max_dcg_{L}",
+                    padded(inv_max_dcg, np.float32))
+            self._buckets.append((L, per, slices))
+            # where a query's L outputs start in the buckets'
+            # concatenated output
+            slot[members] = filled + np.arange(len(members)) * L
+            filled += slices * per * L
+            evaluated += slices * per * L * L
+        # rows of queries without pairs point at the trailing zero
+        self._rank_row_pos = jnp.asarray(
+            np.where(can_pair[qid], slot[qid] + place, filled), jnp.int32)
+        self.max_bucket_len = max((b[0] for b in self._buckets), default=0)
+
+        from ..telemetry import counters
+        counters.set_gauge("rank_queries", self.num_queries)
+        counters.set_gauge("rank_queries_without_pairs",
+                           int(np.sum(~can_pair)))
+        counters.set_gauge("rank_buckets", len(self._buckets))
+        counters.set_gauge("rank_pair_positions_real",
+                           int(np.sum(counts[can_pair] ** 2)))
+        counters.set_gauge("rank_pair_positions_evaluated", evaluated)
+        counters.set_gauge("rank_pair_slice_elems", PAIR_SLICE_ELEMS)
         self._grad_fn = jax.jit(self._gradients_impl)
 
-    def _gradients_impl(self, score):
-        q, L = self._idx.shape
-        s = score[self._idx] * self._mask  # (Q, L)
-        s = jnp.where(self._mask, s, -jnp.inf)
-        order = jnp.argsort(-s, axis=1)  # rank -> doc position within query
-        s_srt = jnp.take_along_axis(s, order, axis=1)
-        lbl_srt = jnp.take_along_axis(self._labels_pad, order, axis=1)
-        gain_srt = jnp.take_along_axis(self._gains, order, axis=1)
-        valid_srt = jnp.take_along_axis(self._mask, order, axis=1)
-        disc = self._discount[None, :] * valid_srt  # (Q, L) discount by rank
+    def device_buffer_names(self):
+        """Every buffer `_gradients_impl` reads, whatever its size: a
+        bucket of a few queries is a jit argument like the rest."""
+        names = ["_label_dev", "_gain_dev", "_rank_row_pos"]
+        if self._weight_dev is not None:
+            names.append("_weight_dev")
+        for L, _, _ in self._buckets:
+            names += [f"_rank_start_{L}", f"_rank_count_{L}",
+                      f"_rank_inv_max_dcg_{L}"]
+        return sorted(names)
 
-        best = s_srt[:, 0]
-        nvalid = jnp.sum(valid_srt, axis=1).astype(jnp.int32)
-        worst = jnp.take_along_axis(
-            s_srt, jnp.maximum(nvalid - 1, 0)[:, None], axis=1)[:, 0]
+    def _slice_gradients(self, L, score, label, gain, start, count, inv):
+        """(S, L, 2): lambda and hessian of the S queries of one slice.
+        `start`, `count`, `inv` are (S,); the row vectors are padded by
+        the longest bucket so a slice of L never runs off their end.
+        In a pair plane (S, L, L) the document whose lambda is summed
+        runs along the last axis and its partner along the middle one,
+        which the sums run over."""
+        def rows_of(vec):
+            return jax.vmap(
+                lambda s: jax.lax.dynamic_slice(vec, (s,), (L,)))(start)
 
-        # pair tensors over rank positions (i=high, j=low)
-        delta_s = s_srt[:, :, None] - s_srt[:, None, :]
-        pair_ok = (valid_srt[:, :, None] & valid_srt[:, None, :]
-                   & (lbl_srt[:, :, None] > lbl_srt[:, None, :]))
-        dcg_gap = gain_srt[:, :, None] - gain_srt[:, None, :]
-        paired_disc = jnp.abs(disc[:, :, None] - disc[:, None, :])
-        delta_ndcg = dcg_gap * paired_disc * self._inv_max_dcg[:, None, None]
+        def mine(v):
+            return v[:, None, :]
+
+        def theirs(v):
+            return v[:, :, None]
+
+        at = jnp.arange(L, dtype=jnp.int32)
+        valid = at[None, :] < count[:, None]                      # (S, L)
+        s = rows_of(score)
+        lbl = jnp.where(valid, rows_of(label), -1.0)
+        g = rows_of(gain)
+        place = at[None, :]
+
+        # rank = how many documents of the query beat this one: the
+        # place in the stable descending sort, with no sort
+        beats = theirs(valid) & (
+            (theirs(s) > mine(s))
+            | ((theirs(s) == mine(s)) & (theirs(place) < mine(place))))
+        rank = jnp.sum(beats, axis=1, dtype=jnp.int32)
+        disc = jnp.where(valid, 1.0 / jnp.log2(2.0 + rank.astype(jnp.float32)),
+                         0.0)
+
+        delta_s = mine(s) - theirs(s)
+        higher = mine(lbl) > theirs(lbl)
+        paired = mine(valid) & theirs(valid) & (mine(lbl) != theirs(lbl))
+        delta_ndcg = (jnp.abs(mine(g) - theirs(g))
+                      * jnp.abs(mine(disc) - theirs(disc))
+                      * inv[:, None, None])
         if self.norm:
-            norm_ok = (best != worst)[:, None, None]
+            best = jnp.max(jnp.where(valid, s, -jnp.inf), axis=1)
+            worst = jnp.min(jnp.where(valid, s, jnp.inf), axis=1)
             delta_ndcg = jnp.where(
-                norm_ok, delta_ndcg / (0.01 + jnp.abs(delta_s)), delta_ndcg)
-        p = 1.0 / (1.0 + jnp.exp(self.sigmoid * delta_s))  # GetSigmoid(delta)
-        p_lambda = -self.sigmoid * delta_ndcg * p
-        p_hess = self.sigmoid * self.sigmoid * delta_ndcg * p * (1.0 - p)
-        p_lambda = jnp.where(pair_ok, p_lambda, 0.0)
-        p_hess = jnp.where(pair_ok, p_hess, 0.0)
-
-        lam_srt = jnp.sum(p_lambda, axis=2) - jnp.sum(p_lambda, axis=1)
-        hes_srt = jnp.sum(p_hess, axis=2) + jnp.sum(p_hess, axis=1)
+                (best != worst)[:, None, None],
+                delta_ndcg / (0.01 + jnp.abs(delta_s)), delta_ndcg)
+        # a document's share of its pair with a partner: lambda_ij where
+        # it holds the higher label, -lambda_ji where the lower
+        toward = jnp.where(higher, delta_s, -delta_s)
+        p = 1.0 / (1.0 + jnp.exp(self.sigmoid * toward))  # GetSigmoid
+        pull = jnp.where(paired, self.sigmoid * delta_ndcg * p, 0.0)
+        lam = jnp.sum(jnp.where(higher, -pull, pull), axis=1)
+        hes = jnp.sum(pull * (self.sigmoid * (1.0 - p)), axis=1)
         if self.norm:
-            sum_lambdas = -2.0 * jnp.sum(p_lambda, axis=(1, 2))
+            # -2 x the sum of lambda_ij over the query's pairs: every
+            # pair stands twice in the plane, once from each side
+            sum_lambdas = jnp.sum(pull, axis=(1, 2))
             factor = jnp.where(
                 sum_lambdas > 0,
                 jnp.log2(1.0 + sum_lambdas) / jnp.maximum(sum_lambdas, 1e-20),
                 1.0)
-            lam_srt = lam_srt * factor[:, None]
-            hes_srt = hes_srt * factor[:, None]
+            lam = lam * factor[:, None]
+            hes = hes * factor[:, None]
+        return jnp.stack((lam, hes), axis=2)
 
-        # unsort back to doc positions, then scatter to flat rows
-        inv_order = jnp.argsort(order, axis=1)
-        lam = jnp.take_along_axis(lam_srt, inv_order, axis=1)
-        hes = jnp.take_along_axis(hes_srt, inv_order, axis=1)
-        grad = jnp.zeros_like(score).at[self._idx.reshape(-1)].add(
-            jnp.where(self._mask, lam, 0.0).reshape(-1))
-        hess = jnp.zeros_like(score).at[self._idx.reshape(-1)].add(
-            jnp.where(self._mask, hes, 0.0).reshape(-1))
-        if self._weight_dev is not None:
-            grad = grad * self._weight_dev
-            hess = hess * self._weight_dev
-        return grad, hess
+    def _gradients_impl(self, score):
+        tail = jnp.zeros((self.max_bucket_len,), jnp.float32)
+        score_p, label_p, gain_p = (
+            jnp.concatenate((v, tail))
+            for v in (score, self._label_dev, self._gain_dev))
+        parts = []
+        for L, per, slices in self._buckets:
+            with jax.named_scope(f"rank_bucket_{L}"):
+                start, count, inv = (
+                    getattr(self, f"_rank_{k}_{L}").reshape(slices, per)
+                    for k in ("start", "count", "inv_max_dcg"))
+
+                def one(xs, L=L):
+                    return self._slice_gradients(
+                        L, score_p, label_p, gain_p, *xs)
+
+                if slices == 1:
+                    out = one((start[0], count[0], inv[0]))
+                else:
+                    out = jax.lax.map(one, (start, count, inv))
+                parts.append(out.reshape(-1, 2))
+        # lambda and hessian side by side, so that a row's two numbers
+        # come with one gathered index (5.7 ns a row on the chip for
+        # 30.3 as two gathers and 15.9 as a tiled scatter; PR 34)
+        parts.append(jnp.zeros((1, 2), jnp.float32))
+        both = jnp.take(jnp.concatenate(parts), self._rank_row_pos, axis=0)
+        return self._apply_weight(both[:, 0], both[:, 1])
 
     def get_gradients(self, score):
         import jax.core as _core

@@ -63,7 +63,8 @@ __all__ = ["counters", "recorder", "spans", "span", "events", "watchdogs",
            "telemetry_summary", "phase_breakdown", "prometheus_text",
            "record_iteration", "reset", "xla_trace_active",
            "note_grow_dispatches", "STAGES", "stage_map",
-           "table_copies_in_split_loop", "hist_plane_elems_per_row"]
+           "table_copies_in_split_loop", "hist_plane_elems_per_row",
+           "rank_pair_plane_elems"]
 
 MODES = ("off", "summary", "trace")
 _mode = "off"
@@ -230,6 +231,43 @@ def hist_plane_elems_per_row(hlo_text: str) -> int:
                 if rows in dims:
                     widest = max(widest, math.prod(dims) // rows)
     return widest
+
+
+_RANK_BUCKET_IN_OP_NAME = re.compile(r"\brank_bucket_(\d+)")
+
+
+def rank_pair_plane_elems(hlo_text: str) -> int:
+    """The widest pair plane a compiled module stores, in elements:
+    over the instructions under `lgbm.gradients` whose `op_name` runs
+    through a `rank_bucket_<L>` scope (objectives/objective.py) and
+    that are not inside a fusion (what a fusion computes inside it is
+    never stored), the largest result whose last two dimensions are
+    both L. The objective's PAIR_SLICE_ELEMS bounds it whatever the
+    table; 0 where the compiler builds every plane inside a fusion, and
+    for a module with no such scope."""
+    comps = _computations(hlo_text)
+    fused = {call.group(2) for lines in comps.values() for line in lines
+             for call in [_FUSION_CALL.search(line)] if call}
+    widest = 0
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            found = _HLO_INSTRUCTION.match(line)
+            bucket = found and _RANK_BUCKET_IN_OP_NAME.findall(
+                found.group(2))
+            if not bucket or "gradients" not in _STAGE_IN_OP_NAME.findall(
+                    found.group(2)):
+                continue
+            rest = line.partition(" = ")[2]
+            result = rest[:rest.index(")") + 1] if rest.startswith("(") \
+                else rest.split(" ", 1)[0]
+            for dims in _ARRAY_DIMS.findall(result):
+                dims = [int(d) for d in dims.split(",")]
+                if dims[-2:] == [int(bucket[-1])] * 2:
+                    widest = max(widest, math.prod(dims))
+    return widest
+
 
 # -- XLA timeline (jax.profiler) under trace mode ---------------------------
 # Opt-in via LGBM_TPU_XLA_TRACE=<dir>: entering trace mode starts a

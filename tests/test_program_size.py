@@ -60,7 +60,56 @@ def test_lambdarank_fused_program_has_no_dataset_constants():
     # n=50k: one embedded f32 vector adds ~0.4 MB over the ~0.25 MB
     # clean program
     assert size < 500_000, f"fused program grew to {size/1e6:.2f} MB"
-    assert "_idx" in keys and "_labels_pad" in keys
+    # one bucket (every query 50 documents -> 64): its three buffers
+    # and the per-row vectors are all jit arguments
+    assert {"_rank_start_64", "_rank_count_64", "_rank_inv_max_dcg_64",
+            "_rank_row_pos", "_label_dev", "_gain_dev"} <= set(keys)
+
+
+def _ranking_step_text(seed):
+    """StableHLO of the fused step on 3,004 queries, one of them long
+    (700 documents: a bucket of one query, every buffer of it a handful
+    of elements), with labels drawn from `seed`."""
+    counts = np.concatenate(([700], np.full(3000, 16), [1, 1, 5]))
+    n = int(counts.sum())
+    rng = np.random.RandomState(seed)
+    x = np.random.RandomState(0).randn(n, 6).astype(np.float32)
+    y = rng.randint(0, 4, n).astype(np.float64)
+    cfg = Config({"objective": "lambdarank", "num_leaves": 15,
+                  "verbosity": -1})
+    ds = Dataset(x, config=cfg, label=y)
+    ds.metadata.set_group(counts)
+    lrn = DeviceTreeLearner(cfg, ds, strategy="chunk")
+    obj = create_objective("lambdarank", cfg)
+    obj.init(ds.metadata, n)
+    step = lrn.make_fused_step(obj)
+    args = (jnp.zeros((n,), jnp.float32), jnp.ones((6,), bool),
+            jax.random.PRNGKey(0), jax.random.PRNGKey(1), jnp.float32(0.1))
+    return step.lower(*args).as_text(), step, args, obj
+
+
+def test_lambdarank_buckets_are_parameters_and_tables_share_a_program():
+    """Every bucket's buffers are jit arguments whatever their size, so
+    the module holds no per-dataset constant: two tables with the same
+    query lengths and other labels lower to the same text (one compile
+    cache key), and the text stays small."""
+    text_a, step, args, obj = _ranking_step_text(1)
+    text_b, _, _, _ = _ranking_step_text(2)
+    assert text_a == text_b
+    assert len(text_a) < 900_000, f"{len(text_a) / 1e6:.2f} MB"
+    assert [b[0] for b in obj._buckets] == [8, 16, 1024]
+    keys = set(step.obj_keys)
+    for L in (8, 16, 1024):
+        assert {f"_rank_start_{L}", f"_rank_count_{L}",
+                f"_rank_inv_max_dcg_{L}"} <= keys
+    assert obj._rank_start_1024.size == 1       # far under 256 elements
+    # and the compiled step stores no pair plane over the live bound
+    from lightgbm_tpu.objectives.objective import PAIR_SLICE_ELEMS
+    from lightgbm_tpu.telemetry import counters
+    plane = step.rank_pair_plane_elems(*args)
+    assert 0 < plane <= PAIR_SLICE_ELEMS
+    assert counters.get("rank_pair_plane_elems") == plane
+    assert 3000 * 16 * 16 <= PAIR_SLICE_ELEMS     # one slice holds them
 
 
 def test_objective_buffer_names_cover_per_row_arrays():

@@ -8,8 +8,9 @@ that crosses a `conditional` is copied on its way in and out, so the
 packed table may cross none inside the split loop (PR 30). And the
 histogram's one-hot is factored, so no plane of F x 256 elements a row
 is written out, and the gradients' bf16 remainder survives XLA's default
-flags (PR 32). Only this file loads the TPU's library, inside the
-fixture."""
+flags (PR 32). And the ranking objective's pair planes stay under the
+slice bound, at `msltr`'s widths too (PR 34). Only this file loads the
+TPU's library, inside the fixture."""
 import os
 import re
 import time
@@ -53,7 +54,8 @@ def test_tiled_partition_compiles_to_tile_sized_scatters(one_chip, rows):
 
 
 @pytest.mark.parametrize("features, d_cols", [(28, 11),     # `higgs`
-                                              (67, 21)])    # `criteo-share`
+                                              (67, 21),     # `criteo-share`
+                                              (137, 39)])   # `msltr`
 def test_split_scan_and_wide_rows_compile(one_chip, features, d_cols):
     """The split scan over a cell's (features, 256 bins) plane, with both
     children summed from the bins (PR 29), and one tiled partition of the
@@ -96,7 +98,8 @@ def _histogram_module(one_chip, features, rows):
 
 
 @pytest.mark.parametrize("features", [28,       # `higgs`
-                                      67])      # `criteo-share`
+                                      67,       # `criteo-share`
+                                      137])     # `msltr`
 def test_histogram_writes_no_plane_of_256_bins_a_feature(one_chip, features):
     """At a cell's width: no array of chunk x F x 256 elements anywhere
     in the module (the unfactored one-hot, `pred[2048,F,256]` before
@@ -128,6 +131,54 @@ def test_gradient_remainder_survives_default_flags(one_chip):
     assert heads
     assert any(re.search(r" subtract\(%[\w.\-]+, %" + re.escape(h) + r"\)", txt)
                for h in heads), "no gh - head in the module"
+
+
+def test_ranking_gradient_pass_stores_no_pair_plane_over_its_bound(one_chip):
+    """`LambdarankNDCG`'s gradient pass for the described chip, on 3,000
+    queries of 1 to 120 documents and one of 1,251 (the 2,048 bucket,
+    `msltr`'s longest), every buffer an argument as in the fused step:
+    it compiles, and the widest pair plane the compiler stores stays
+    under PAIR_SLICE_ELEMS (on the chip it builds the planes inside its
+    fusions: what is stored is the L x L tie-break triangle)."""
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.models.device_learner import swapped_attrs
+    from lightgbm_tpu.objectives import objective as objective_mod
+    r = np.random.default_rng(7)
+    counts = np.concatenate((
+        [1251, 1], np.rint(np.exp(r.normal(3.0, 0.8, 3000))).clip(1, 120)
+    )).astype(np.int64)
+    n = int(counts.sum())
+
+    class Meta:
+        label = r.integers(0, 5, n).astype(np.float64)
+        weight = None
+        query_boundaries = np.concatenate(([0], np.cumsum(counts)))
+
+    obj = objective_mod.create_objective(
+        "lambdarank", Config({"objective": "lambdarank", "verbosity": -1}))
+    obj.init(Meta, n)
+    assert obj.max_bucket_len == 2048
+    keys = obj.device_buffer_names()
+
+    def gradients(bufs, score):
+        with swapped_attrs(obj, keys, bufs), \
+                jax.named_scope("lgbm.gradients"):
+            return obj._gradients_impl(score)
+
+    def shaped(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    txt = jax.jit(gradients).lower(
+        tuple(shaped(getattr(obj, k)) for k in keys),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    ).compile().as_text()
+    assert "rank_bucket_2048" in txt and "rank_bucket_8" in txt
+    plane = telemetry.rank_pair_plane_elems(txt)
+    assert plane <= objective_mod.PAIR_SLICE_ELEMS, plane
+    # by stage: every bucket's instructions fall under `gradients`
+    stages = {stage for stage, _ in telemetry.stage_map(txt).values()}
+    assert stages == {"gradients"}
 
 
 COMPILE_LIMIT_S = 240
