@@ -249,7 +249,11 @@ class Smoke:
         get right is not covered by a CPU test: PERF.md §6, PR 29), at
         XLA's default flags, so a gradient remainder the compiler folded
         away fails here too. Whole chunks and a ragged window whose last
-        rows are padding (gh 0, arbitrary codes)."""
+        rows are padding (gh 0, arbitrary codes); and, of both, a range
+        that starts past the first chunk and ends inside the last, summed
+        over its own chunks alone (`build_histogram_range`, the compact
+        core's child histogram): to float64 like the others, and equal
+        to the whole window's sum with the rows outside it masked."""
         r = np.random.RandomState(5)
         out = {}
         for f, bins in ((28, 256), (67, 256), (5, 64), (9, 255)):
@@ -282,9 +286,43 @@ class Smoke:
                     jnp.asarray(codes), jnp.asarray(ghq), bins,
                     chunk_size=chunk))
                 assert np.array_equal(got_q, want_q), (f, bins, rows)
+                begin, count = chunk + 123, rows - chunk - 123 - 450
+                inside = ((np.arange(rows) >= begin)
+                          & (np.arange(rows) < begin + count))[:, None]
+                want_r, mass_r = np.zeros((2, f, bins, 3))
+                want_rq = np.zeros((f, bins, 3), np.int64)
+                for j in range(f):
+                    np.add.at(want_r[j], codes[:, j],
+                              (gh * inside).astype(np.float64))
+                    np.add.at(mass_r[j], codes[:, j],
+                              np.abs(gh * inside, dtype=np.float64))
+                    np.add.at(want_rq[j], codes[:, j],
+                              (ghq * inside).astype(np.int64))
+                ranged = jax.jit(
+                    lambda c, g, b, n, quantized:
+                    hist_ops.build_histogram_range(
+                        hist_ops.rows_loader(c, g), rows, b, n, f, bins,
+                        quantized=quantized, chunk_size=chunk),
+                    static_argnames="quantized")
+                got_r = np.asarray(ranged(jnp.asarray(codes), jnp.asarray(gh),
+                                          begin, count, quantized=False))
+                excess_r = float(np.max(np.abs(got_r - want_r)
+                                        - 2.0 ** -16 * mass_r))
+                assert excess_r <= 1e-6, (f, bins, rows, excess_r)
+                whole = np.asarray(hist_ops.build_histogram(
+                    jnp.asarray(codes), jnp.asarray(gh * inside), bins,
+                    chunk_size=chunk))
+                assert np.array_equal(got_r, whole), (f, bins, rows)
+                got_rq = np.asarray(ranged(
+                    jnp.asarray(codes), jnp.asarray(ghq), begin, count,
+                    quantized=True))
+                assert np.array_equal(got_rq, want_rq), (f, bins, rows)
                 out[f"{f}x{bins}_rows{rows}"] = {
                     "float_max_abs_err": float(np.abs(got - want).max()),
-                    "int8": "equal"}
+                    "int8": "equal",
+                    "range_float_max_abs_err":
+                        float(np.abs(got_r - want_r).max()),
+                    "range_vs_whole": "equal", "range_int8": "equal"}
         return out
 
     def _train_leg(self, max_bin):

@@ -491,6 +491,52 @@ def _size_classes(n: int, min_bucket: int = 4096):
     return ws
 
 
+def rung_rows(pcount, lphys, left_small, ladder, tile_rows: int, chunk: int,
+              bounded: bool = True):
+    """(rows run, rows needed) by the two step loops of the compact
+    core's rungs over a tree's splits, on the host from what a rung
+    holds: `pcount` the parent's rows on this device, `lphys` those that
+    go left, `left_small` whether the left child is the one that is
+    histogrammed. Run: the rows of the partition's tiles (the whole
+    window where it is one scatter) plus the rows of the histogram's
+    chunks (the half window, or the whole one where the smaller side
+    does not fit it; all of it where it is one product), with the loops'
+    trip counts as `_scan_partition_tiled` and
+    ops/histogram.py::build_histogram_range read them from the split;
+    `bounded=False` gives what the loops ran when they went to the
+    rung's static width (before PR 35). Needed: the parent's rows plus
+    the histogrammed child's, the work model's own counts. A pooled
+    miss's second histogram is not in the records and is not counted."""
+    pcount = np.asarray(pcount, np.int64)
+    lphys = np.asarray(lphys, np.int64)
+    ladder = np.asarray(ladder, np.int64)
+    wsz = ladder[np.minimum(np.searchsorted(ladder, pcount), len(ladder) - 1)]
+    s_begin = np.where(left_small, 0, lphys)
+    s_count = np.where(left_small, lphys, pcount - lphys)
+    tiles = wsz // tile_rows
+    if bounded:
+        # whole tiles up to the last that holds a row of the leaf; the
+        # ragged last step of the top rung only past them
+        tiled = np.where(pcount > tiles * tile_rows, wsz,
+                         -(-pcount // tile_rows) * tile_rows)
+    else:
+        tiled = wsz
+    partition = np.where(wsz <= tile_rows, wsz, tiled)
+    half = (wsz + 1) // 2
+    in_half = s_count <= half
+    rows = np.where(in_half, half, wsz)
+    off = np.where(in_half, s_begin - np.clip(s_begin, 0, wsz - half),
+                   s_begin)
+    n_chunks = -(-rows // chunk)
+    if bounded:
+        first = np.minimum(off // chunk, n_chunks - 1)
+        stop = np.minimum(-(-(off + s_count) // chunk), n_chunks)
+        n_chunks = np.maximum(stop - first, 1)
+    histogram = np.where(rows <= chunk, rows, n_chunks * chunk)
+    return (float(partition.sum() + histogram.sum()),
+            float((pcount + s_count).sum()))
+
+
 def _unpack_codes(words: jax.Array, c_cols: int, item_bits: int) -> jax.Array:
     """(W, CW) u32 packed codes -> (W, c_cols) i32."""
     per = 32 // item_bits
@@ -1026,7 +1072,8 @@ def grow_tree_compact_core(
         [data0, jnp.zeros((wmax, d_cols), jnp.uint32)], axis=0)
 
     # ---- root ------------------------------------------------------------
-    from ..ops.histogram import build_histogram, build_histogram_quantized
+    from ..ops.histogram import (build_histogram, build_histogram_quantized,
+                                 build_histogram_range, window_chunk)
     with jax.named_scope("lgbm.root_hist"):
         if quant:
             r0_g, r0_h = q_ratios(root_max) if renew else q_ratios(None)
@@ -1158,37 +1205,54 @@ def grow_tree_compact_core(
                 s_begin = jnp.where(left_small, 0, lphys)
                 s_count = jnp.where(left_small, lphys, rphys)
 
-                def win_hist(rows2d, vbool):
-                    """Histogram of a row window restricted to `vbool` rows —
-                    the one layout dispatch (float triple vs packed int)."""
+                def win_operands(rows2d, vbool):
+                    """(codes, operand) of packed rows for the histogram
+                    of their `vbool` rows: the one layout dispatch (float
+                    triple vs packed int)."""
                     s_codes = decode_for_hist(rows2d[:, :cw])
                     if quant:
-                        ghq = _quant_win_operand(
+                        return s_codes, _quant_win_operand(
                             rows2d, vbool, cw=cw, gw=gw, quant_bits=quant_bits,
                             qcap_op=qcap_op, r_g=rq_g, r_h=rq_h)
-                        return build_histogram_quantized(
-                            s_codes, ghq, col_bins, use_pallas=use_pallas)
-                    s_gh = jax.lax.bitcast_convert_type(
+                    return s_codes, jax.lax.bitcast_convert_type(
                         rows2d[:, cw:cw + 3], jnp.float32) \
                         * vbool.astype(jnp.float32)[:, None]
-                    return build_histogram(s_codes, s_gh, col_bins,
-                                           use_pallas=use_pallas)
+
+                def win_hist(first_row, rows, range_begin, range_count):
+                    """Histogram of the rows [range_begin, range_begin +
+                    range_count) of the `rows` rows of the sorted window
+                    from `first_row`: the chunks that meet the range
+                    are read out of the packed window, decoded and
+                    contracted one by one (build_histogram_range), so a
+                    rung works for the child's rows, not for its window.
+                    The Pallas kernel takes the stretch whole."""
+                    if use_pallas:
+                        j = jnp.arange(rows, dtype=jnp.int32)
+                        s_codes, s_gh = win_operands(
+                            jax.lax.dynamic_slice(win_sorted, (first_row, 0),
+                                                  (rows, d_cols)),
+                            (j >= range_begin)
+                            & (j < range_begin + range_count))
+                        build = (build_histogram_quantized if quant
+                                 else build_histogram)
+                        return build(s_codes, s_gh, col_bins, use_pallas=True)
+
+                    def load(row0, size, keep):
+                        return win_operands(
+                            window_chunk(win_sorted, first_row + row0, size,
+                                         rows % size != 0), keep)
+
+                    return build_histogram_range(
+                        load, rows, range_begin, range_count, hist_cols,
+                        col_bins, quantized=quant)
 
                 def hist_half(_):
                     start = jnp.clip(s_begin, 0, wsz - half)
-                    off = s_begin - start
-                    sw = jax.lax.dynamic_slice(win_sorted, (start, 0),
-                                               (half, d_cols))
-                    j = jnp.arange(half, dtype=jnp.int32)
-                    return win_hist(sw, (j >= off) & (j < off + s_count))
+                    return win_hist(start, half, s_begin - start, s_count)
 
                 def hist_range(range_begin, range_count):
-                    # masked full-window pass over [range_begin,
-                    # range_begin + range_count)
-                    j = jnp.arange(wsz, dtype=jnp.int32)
-                    return win_hist(win_sorted,
-                                    (j >= range_begin)
-                                    & (j < range_begin + range_count))
+                    # the full window: a side the half window cannot hold
+                    return win_hist(0, wsz, range_begin, range_count)
 
                 if trivial_weights and axis_name is None:
                     # all-ones weights single-chip: record counts equal
@@ -2163,11 +2227,15 @@ def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
     input has them. No write is clamped: a tile's class-0 piece starts
     at or before the tile itself, and its class-1 write starts at the
     earlier tiles' class-0 and class-1 rows plus the later tiles'
-    class-0 rows, at most rows - size."""
+    class-0 rows, at most rows - size. By the same precondition the
+    loop ends with the last tile that holds a row of class 0 or 1: a
+    tile of key-2 rows alone writes nothing (both its masks are empty),
+    so the work goes with the leaf's rows and not with the window."""
     rows, d = win.shape
     tiles, rem = divmod(rows, tile_rows)
     win = _rows_minor(win)
     n0 = jnp.sum((key3 == 0).astype(jnp.int32))
+    live = jnp.sum((key3 < 2).astype(jnp.int32))
 
     def step(acc, start, size):
         out, o0, o1 = acc
@@ -2187,10 +2255,12 @@ def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
         return out, o0 + c0, o1 + c1
 
     acc = jax.lax.fori_loop(
-        0, tiles, lambda i, acc: step(acc, i * tile_rows, tile_rows),
+        0, jnp.minimum((live + tile_rows - 1) // tile_rows, tiles),
+        lambda i, acc: step(acc, i * tile_rows, tile_rows),
         (win, jnp.int32(0), n0))
     if rem:     # only the top rung, n itself, is not a power of two
-        acc = step(acc, tiles * tile_rows, rem)
+        acc = run_once_if(live > tiles * tile_rows,
+                          lambda acc: step(acc, tiles * tile_rows, rem), acc)
     return acc[0]
 
 
@@ -3231,7 +3301,9 @@ class DeviceTreeLearner:
         ladder that holds this device's share of the parent — is more
         than one scatter tile. The chunk core sorts its chunks and the
         masked core moves no rows: neither tiles, and the masked core
-        counts nothing."""
+        counts nothing. Beside them, for the compact core, what its
+        rungs' step loops ran and what the splits needed, in rows:
+        `rung_rows_run` and `rung_rows_needed` (`rung_rows`)."""
         if self.strategy == "masked":
             return
         parent = rec[:, R_LCNT].astype(np.float64) + rec[:, R_RCNT]
@@ -3246,6 +3318,16 @@ class DeviceTreeLearner:
         telemetry.counters.incr(
             "partition_tiled_rows",
             float(parent[rung > SCATTER_TILE_ROWS].sum()))
+        # exact on one device's full-data trees (the records' counts are
+        # the rows); under sharding or bagging an estimate by the share
+        from ..ops.histogram import resolve_chunk_size
+        run, needed = rung_rows(
+            share, np.minimum(np.rint(rec[:, R_LCNT] * (share / parent)),
+                              share),
+            rec[:, R_LCNT] <= rec[:, R_RCNT], ladder, SCATTER_TILE_ROWS,
+            resolve_chunk_size(0, self.c_cols, self.col_device_bins))
+        telemetry.counters.incr("rung_rows_run", run)
+        telemetry.counters.incr("rung_rows_needed", needed)
 
     def _count_missing_splits(self, rec) -> None:
         """Program counters of how often the default-direction path

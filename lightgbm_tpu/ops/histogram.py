@@ -186,6 +186,74 @@ def _sum_chunks(chunk_product, binned_rows: jax.Array, gh: jax.Array,
     return _unfactor(raw, f, num_bins)
 
 
+def window_chunk(x: jax.Array, row0, size: int, ragged: bool) -> jax.Array:
+    """Rows [row0, row0 + size) of `x`, each at its own place, for a
+    traced row0. `ragged` (static) says the slice may overrun `x`: the
+    last chunk of a window that is no multiple of the chunk. It is then
+    read from further up and rolled back, so that the rows `x` has keep
+    their places in the chunk (and its sums their order) and the places
+    past the end of `x` hold rows no range includes."""
+    if not ragged:
+        return jax.lax.dynamic_slice_in_dim(x, row0, size)
+    at = jnp.minimum(row0, x.shape[0] - size)
+    return jnp.roll(jax.lax.dynamic_slice_in_dim(x, at, size), at - row0,
+                    axis=0)
+
+
+def build_histogram_range(load, rows: int, begin, count, num_features: int,
+                          num_bins: int, quantized: bool = False,
+                          chunk_size: int = 0) -> jax.Array:
+    """`build_histogram` (or, `quantized`, `build_histogram_quantized`)
+    of the rows [begin, begin + count) of a window of `rows` rows
+    (traced; the range lies inside the window), for the work of those
+    rows and not of the window: `_sum_chunks` over the chunks that meet
+    the range alone. The other chunks' rows carry gh = 0 and add exact
+    zeros; the chunk grid is `_sum_chunks`' own, multiples of the chunk
+    from the window's first row, and the chunks that are summed are
+    summed in its order, so the sum is the whole window's, bit for bit.
+    `load(row0, size, keep)` gives the chunk's (codes, operand) from its
+    first row, its (static) length and the rows of it the range holds,
+    the operand zero on every other row; the window is never held whole.
+    The carry is seeded from the first summed chunk, as `_sum_chunks`
+    seeds its own, and an empty range sums that one chunk of zeros. The
+    trip count differs from shard to shard under shard_map, so no
+    collective may enter `load`."""
+    chunk_product = _hist_chunk_q if quantized else _hist_chunk
+    chunk = resolve_chunk_size(chunk_size, num_features, num_bins)
+    begin = jnp.asarray(begin, jnp.int32)
+    end = begin + jnp.asarray(count, jnp.int32)
+
+    def product(row0, size):
+        j = row0 + jnp.arange(size, dtype=jnp.int32)
+        return chunk_product(*load(row0, size, (j >= begin) & (j < end)),
+                             num_bins)
+
+    if rows <= chunk:
+        raw = product(jnp.int32(0), rows)
+    else:
+        n_chunks = -(-rows // chunk)
+        first = jnp.minimum(begin // chunk, n_chunks - 1)
+        stop = jnp.minimum((end + chunk - 1) // chunk, n_chunks)
+        raw = jax.lax.fori_loop(
+            first + 1, stop,
+            lambda i, acc: acc + product(i * chunk, chunk),
+            product(first * chunk, chunk))
+    hist = _unfactor(raw, num_features, num_bins)
+    return hist if quantized else hist[..., :3] + hist[..., 3:]
+
+
+def rows_loader(binned_rows: jax.Array, gh: jax.Array):
+    """`build_histogram_range`'s `load` for a window held as arrays:
+    (P, F) codes and a (P, K) operand."""
+    def load(row0, size, keep):
+        ragged = binned_rows.shape[0] % size != 0
+        return (window_chunk(binned_rows, row0, size, ragged),
+                jnp.where(keep[:, None],
+                          window_chunk(gh, row0, size, ragged),
+                          jnp.zeros((), gh.dtype)))
+    return load
+
+
 @functools.partial(jax.jit, static_argnames=("num_bins", "chunk_size", "use_pallas"))
 def build_histogram(binned_rows: jax.Array, gh: jax.Array, num_bins: int,
                     chunk_size: int = 0, use_pallas: bool = False) -> jax.Array:

@@ -110,6 +110,78 @@ def test_quantized_histogram_grid_is_exact(f, num_bins, rows, code_dtype):
     np.testing.assert_array_equal(got, want_q)
 
 
+# (begin, count) in a window of 5 chunks of 64 rows and, ragged, of 4 and
+# 37 rows more: no row, rows of one chunk, over one and over three chunk
+# edges, from a chunk's first row, up to the window's last row (a right
+# child), into the ragged last chunk, and the whole window
+_HIST_RANGES = [(100, 0), (0, 0), (70, 30), (40, 60), (60, 140), (128, 64),
+                (200, 120), (1, 319), (0, 320)]
+_RAGGED_RANGES = [(290, 67), (330, 27), (0, 357), (256, 0)]
+
+
+def _ranged(codes, operand, rows, begin, count, num_bins, quantized):
+    import jax
+    return np.asarray(jax.jit(
+        lambda c, g, b, n: hist_ops.build_histogram_range(
+            hist_ops.rows_loader(c, g), rows, b, n, codes.shape[1],
+            num_bins, quantized=quantized, chunk_size=_HIST_CHUNK))(
+        jnp.asarray(codes), jnp.asarray(operand), begin, count))
+
+
+@pytest.mark.parametrize("rows,begin,count",
+                         [(320,) + r for r in _HIST_RANGES]
+                         + [(357,) + r for r in _RAGGED_RANGES])
+def test_ranged_histogram_equals_the_masked_whole_window(rows, begin, count):
+    """`build_histogram_range` sums the chunks that meet the range and
+    no other, on the whole window's chunk grid and in its order: bit
+    for bit the whole-window histogram with the other rows' operand 0,
+    float32 and int32, the ragged last chunk (read from further up and
+    rolled back) included."""
+    codes, gh, ghq, _, _ = _histogram_case(5, 63, rows, np.uint8,
+                                           seed=rows + begin)
+    pad = max(1, rows // 10)                  # no padding rows here
+    gh[-pad:], ghq[-pad:] = gh[:pad], ghq[:pad]
+    inside = ((np.arange(rows) >= begin)
+              & (np.arange(rows) < begin + count))[:, None]
+    whole = np.asarray(hist_ops.build_histogram(
+        jnp.asarray(codes), jnp.asarray(gh * inside), num_bins=63,
+        chunk_size=_HIST_CHUNK))
+    got = _ranged(codes, gh, rows, begin, count, 63, False)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, whole)
+    assert (count > 0) == bool(np.any(got))
+    whole_q = np.asarray(hist_ops.build_histogram_quantized(
+        jnp.asarray(codes), jnp.asarray(ghq * inside.astype(np.int8)),
+        num_bins=63, chunk_size=_HIST_CHUNK))
+    got_q = _ranged(codes, ghq, rows, begin, count, 63, True)
+    assert got_q.dtype == np.int32
+    np.testing.assert_array_equal(got_q, whole_q)
+
+
+@pytest.mark.parametrize("begin,count,chunks",
+                         [(100, 0, 1), (70, 30, 1), (40, 60, 2),
+                          (60, 140, 4), (128, 64, 1), (0, 320, 5),
+                          (290, 67, 2)])
+def test_ranged_histogram_runs_the_chunks_of_the_range(monkeypatch, begin,
+                                                       count, chunks):
+    """The loop's trip count is read from the range: run eagerly (a
+    Python loop), it contracts the chunks that hold a row of the range,
+    and one chunk of zeros where the range is empty."""
+    import jax
+    rows = 357 if begin + count > 320 else 320
+    codes, gh, _, _, _ = _histogram_case(5, 63, rows, np.uint8, seed=9)
+    calls = []
+    real = hist_ops._hist_chunk
+    monkeypatch.setattr(
+        hist_ops, "_hist_chunk",
+        lambda c, g, b: calls.append(c.shape[0]) or real(c, g, b))
+    with jax.disable_jit():
+        hist_ops.build_histogram_range(
+            hist_ops.rows_loader(jnp.asarray(codes), jnp.asarray(gh)), rows,
+            begin, count, 5, 63, chunk_size=_HIST_CHUNK)
+    assert calls == [_HIST_CHUNK] * chunks
+
+
 @pytest.mark.parametrize("f,num_bins", [(28, 256), (5, 64)])
 def test_histogram_at_the_derived_chunk(f, num_bins):
     """Two chunks and a ragged third at the chunk the shape resolves to."""

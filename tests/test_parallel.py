@@ -86,6 +86,31 @@ def test_data_parallel_matches_serial_structurally():
                                rtol=1e-3, atol=1e-4)
 
 
+def test_data_parallel_skewed_shards_match_serial(monkeypatch):
+    """The rungs' step loops take their trip counts from the shard's own
+    rows: with the rows sorted by the strongest feature a split sends
+    whole shards to one side (a local parent of no row beside one of
+    thousands), the tile and the chunk forced under the local windows
+    so that both loops run, and the trees are the serial learner's."""
+    from lightgbm_tpu.models import device_learner as dl
+    from lightgbm_tpu.ops import histogram as hist_ops
+    monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", 512)
+    monkeypatch.setattr(hist_ops, "_CHUNK_FLOOR", 128)
+    monkeypatch.setattr(hist_ops, "_CHUNK_CEIL", 128)
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    dl.grow_tree_compact.clear_cache()
+    x, y = make_binary(8 * 4500 - 3, 6)
+    order = np.argsort(x[:, 0], kind="stable")
+    x, y = x[order], y[order]
+    bs = _train(x, y, "serial", rounds=2)
+    bd = _train(x, y, "data", rounds=2)
+    dl.grow_tree_compact.clear_cache()
+    assert bd.learner.local_n == 4500 and bd.learner.strategy == "compact"
+    assert_trees_structurally_equal(bs, bd, 2, "skewed data-parallel")
+    feats = {int(f) for t in bd.models[:2] for f in t.split_feature[:14]}
+    assert 0 in feats          # a split that parts the shards
+
+
 def test_data_parallel_uses_device_learner():
     from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
     x, y = make_binary(1000, 6)

@@ -85,6 +85,28 @@ def test_integer_histogram_exact_sums(bits):
             assert np.array_equal(ref, hq[fi, :, lane]), (fi, lane)
 
 
+@pytest.mark.parametrize("begin,count", [(0, 0), (700, 200), (500, 1100),
+                                         (2500, 1500), (0, 4000)])
+@pytest.mark.parametrize("bits", [8, 16])
+def test_integer_histogram_of_a_row_range_exact_sums(bits, begin, count):
+    """The ranged sum (the compact core's child histogram) in the
+    integer domain: the chunks that meet [begin, begin + count) of a
+    window of 7 chunks and a ragged eighth, equal to an int64
+    scatter-add over those rows EXACTLY, int8 and int32 operands."""
+    codes, _, _, _, ghq, _, _ = _quantized_inputs(bits=bits)
+    got = np.asarray(jax.jit(
+        lambda c, g, b, n: hist_ops.build_histogram_range(
+            hist_ops.rows_loader(c, g), 4000, b, n, 6, 32, quantized=True,
+            chunk_size=512))(codes, ghq, begin, count), dtype=np.int64)
+    assert got.shape == (6, 32, 3)
+    cn = np.asarray(codes)[begin:begin + count]
+    ghn = np.asarray(ghq, dtype=np.int64)[begin:begin + count]
+    ref = np.zeros((6, 32, 3), np.int64)
+    for fi in range(6):
+        np.add.at(ref[fi], cn[:, fi], ghn)
+    np.testing.assert_array_equal(got, ref)
+
+
 @pytest.mark.parametrize("bits", [8, 16])
 def test_quantized_vs_f64_reference_error_bound(bits):
     """Property: per-bin |dequantized - f64 reference| <= cnt_bin / s

@@ -184,8 +184,21 @@ def test_ranking_gradient_pass_stores_no_pair_plane_over_its_bound(one_chip):
 COMPILE_LIMIT_S = 240
 
 
+def _loop_conditions(txt, stage):
+    """The ROOT line of the condition of every `while` under a
+    `lgbm.<stage>` scope of a module's text."""
+    from lightgbm_tpu import telemetry
+    comps = telemetry._computations(txt)
+    return [next(c for c in comps[re.search(r"condition=%?([\w.\-]+)",
+                                            line).group(1)] if "ROOT" in c)
+            for lines in comps.values() for line in lines
+            if " while(" in line
+            and re.search(r'op_name="[^"]*lgbm\.%s[^"]*"' % stage, line)]
+
+
 @pytest.mark.parametrize("features, d_cols", [(28, 11),     # `higgs`
-                                              (67, 21)])    # `criteo-share`
+                                              (67, 21),     # `criteo-share`
+                                              (137, 39)])   # `msltr`
 def test_packed_table_is_updated_in_place_in_the_split_loop(
         one_chip, monkeypatch, features, d_cols):
     """The compact core's tree program at a cell's row width, 300,000
@@ -194,8 +207,14 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
     any computation the split loop reaches, and the table rows-minor
     wherever it appears. With the table handed through a `conditional`
     (the `lax.switch` over the rungs, before PR 30) the same count read
-    6, one copy in each of six of the eight branches."""
+    6, one copy in each of six of the eight branches. And a rung works
+    for the leaf's rows (PR 35): the tile loop and the chunk loop end
+    where a carried count says, not at a constant of the rung's width,
+    the chunks are decoded one by one (no `s32[half window, F]`, nor the
+    chunks stacked, under `lgbm.child_hist`) and the products still read
+    a plane under F x 128 elements a row."""
     from lightgbm_tpu import telemetry
+    from lightgbm_tpu.ops import histogram as hist_ops
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import Dataset
     from lightgbm_tpu.models import device_learner as dl
@@ -237,3 +256,22 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
     copies = telemetry.table_copies_in_split_loop(txt)
     assert sum(copies.values()) == 0, copies
     assert set(re.findall(table + r"(\{[01],[01])", txt)) == {"{0,1"}
+    assert 0 < telemetry.hist_plane_elems_per_row(txt) < features * 128
+    # one tile loop (and the top rung's ragged step) a tiled rung, one
+    # chunk loop a rung whose half window is over a chunk
+    chunk = hist_ops.resolve_chunk_size(0, features, 256)
+    tiled = sum(w > dl.SCATTER_TILE_ROWS for w in ladder)
+    chunked = sum((w + 1) // 2 > chunk for w in ladder)
+    for stage, loops in (("partition", tiled + 1), ("child_hist", chunked)):
+        roots = _loop_conditions(txt, stage)
+        assert len(roots) == loops, (stage, roots)
+        for root in roots:
+            compared = re.search(r" compare\(([^)]*)\)", root)
+            assert " get-tuple-element(" in root or (
+                compared and "constant" not in compared.group(1)), root
+    decoded = [
+        (dims, line) for line in txt.splitlines() if "lgbm.child_hist" in line
+        for dims in re.findall(r"= s32\[(\d+(?:,\d+)*),%d\]" % features,
+                               line)
+        if np.prod([int(d) for d in dims.split(",")]) > chunk]
+    assert not decoded, decoded[:3]
