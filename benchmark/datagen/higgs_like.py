@@ -1,11 +1,14 @@
 """Higgs-shaped table: dense float32 columns, a binary label from a fixed
 ground-truth function plus noise.
 
-Copied from `bench.py::make_higgs_like` (numeric columns only), with two
-changes. The columns are drawn as float32 directly, in parallel blocks
-(the legacy `RandomState.randn(...).astype` costs six times as long).
-And the ground-truth weights come from `params["truth_seed"]`, not from
-`--seed`: every seed then draws fresh rows from the SAME population, so
+The label model is that of the yardstick from before the chip
+(`bench.py::make_higgs_like`, numeric columns only; PR 31 took the file
+out, and `chip_smoke.py::make_higgs_like` is what is left of it), with
+two changes. The columns are drawn as float32 directly, in parallel
+blocks (the legacy `RandomState.randn(...).astype` costs six times as
+long). And neither the ground-truth weights nor the rows come from
+`--seed`: the weights are `params["truth_seed"]`'s and the table is
+`params["table_seed"]`'s, which every seed shuffles (`_blocks.py`), so
 the trees, and with them the work of an iteration, are alike from seed
 to seed.
 """

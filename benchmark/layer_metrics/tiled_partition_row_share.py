@@ -3,8 +3,8 @@ the tiled partition (windows of more than one scatter tile), from the
 program's own counters `partition_tiled_rows` / `partition_rows`, summed
 over every tree of the process up to the read. A program without the
 counters reads nothing, and so does one that has tiled no row (the
-masked core, the `sort` partition, windows of one tile): the line holds
-no metric at 0."""
+masked core, which moves none; the chunk core, which sorts its chunks;
+windows of one tile): the line holds no metric at 0."""
 LAYER = "tree program"
 UNIT = "%"
 SOURCE = "program_counter"

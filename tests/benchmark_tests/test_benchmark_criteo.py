@@ -87,10 +87,16 @@ def test_stated_shares_hold(table, group):
     assert 0.01 < float(y.mean()) < 0.08          # a few per cent click
 
 
-def test_cell_resolves_and_states_its_deployment():
-    cell = spec.load_cell(ROOT, "criteo-train")
+def check_cell_resolves_and_states_its_deployment(root):
+    """What `BENCHMARK.json` and `spec.load_cell` say of `criteo-share`
+    and `criteo-train` under `root`, found by name
+    (`test_benchmark_appends.py` runs this on a copy with entries
+    appended too)."""
+    bench = bench_rehearsal.load_bench(root)
+    cell = spec.load_cell(root, "criteo-train")
     conf, entry = cell["config"], next(
-        c for c in BENCH["configs"] if c["name"] == "criteo-share")
+        c for c in bench["configs"] if c["name"] == "criteo-share")
+    gen = conf["generator_params"]
     assert cell["chips"] == 1 and cell["traffic"]["kind"] == "train"
     assert conf["reduced"] == entry["reduced"] == ["rows"]
     assert conf["source"] == entry["source"] and len(conf["source"]) <= 200
@@ -104,7 +110,7 @@ def test_cell_resolves_and_states_its_deployment():
                                    "learning_rate", "metric", "verbosity"}
     assert conf["rows"] in range(8_000_000, 10_000_001, 500_000)
     assert conf["rows"] < pub["rows"]
-    assert sum(GEN[f"{g}_cols"] for g in criteo_like.GROUPS) == 67
+    assert sum(gen[f"{g}_cols"] for g in criteo_like.GROUPS) == 67
     for key in ("deployment", "reduced_why", "assumed", "guarantees",
                 "xla_flags_why", "bins_seed", "bins_rows"):
         assert conf[key], key
@@ -117,16 +123,22 @@ def test_cell_resolves_and_states_its_deployment():
     # the cell reports every per-layer metric that lists no cells or
     # lists it, `missing_split_share` (its table has NaN columns) among
     # them
-    bench_rehearsal.check_workloads_lists_are_sound(ROOT)
+    bench_rehearsal.check_workloads_lists_are_sound(root)
     assert "missing_split_share" in [m["name"] for m in cell["per_layer"]]
+
+
+def test_cell_resolves_and_states_its_deployment():
+    check_cell_resolves_and_states_its_deployment(ROOT)
 
 
 def test_new_entries_are_appended_and_nothing_moved():
     """What the benchmark had stands where it stood, as a prefix: the
-    two configurations, the two cells and the sixteen per-layer names in
-    their order. Whatever follows is free, and is held to what the
-    harness needs of it: files found by name, `reduced` and `source` the
-    configuration file's, limits present."""
+    configurations, the cells and the per-layer names of
+    `bench_rehearsal.STANDS` in their order. Whatever follows is free,
+    and is held to what the harness needs of it: files found by name,
+    `reduced` and `source` the configuration file's, limits present.
+    (Both are `STRUCTURE` checks, which the appended copy meets in
+    `test_benchmark_appends.py`.)"""
     bench_rehearsal.check_what_stands_is_a_prefix(ROOT)
     bench_rehearsal.check_every_file_is_found_by_name(ROOT)
 

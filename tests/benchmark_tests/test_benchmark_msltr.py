@@ -33,12 +33,16 @@ def test_structure_holds_with_the_new_entries(check):
     bench_rehearsal.STRUCTURE[check](ROOT)
 
 
-def test_cell_resolves_and_states_its_deployment():
-    cell = spec.load_cell(ROOT, "msltr-train")
+def check_cell_resolves_and_states_its_deployment(root):
+    """What `BENCHMARK.json` and `spec.load_cell` say of `msltr` and
+    `msltr-train` under `root`, found by name: `test_benchmark_appends.py`
+    runs every `check_*(root)` of this file on a copy with entries
+    appended, so nothing here may lean on a place or a length."""
+    bench = bench_rehearsal.load_bench(root)
+    cell = spec.load_cell(root, "msltr-train")
     conf = cell["config"]
-    entry = next(c for c in BENCH["configs"] if c["name"] == "msltr")
-    assert BENCH["configs"][-1] == entry
-    assert BENCH["workloads"][-1]["name"] == "msltr-train"
+    gen = conf["generator_params"]
+    entry = next(c for c in bench["configs"] if c["name"] == "msltr")
     assert cell["chips"] == 1 and cell["traffic"]["kind"] == "train"
     assert conf["reduced"] == entry["reduced"] == ["rows"]
     assert conf["source"] == entry["source"] and len(conf["source"]) < 200
@@ -53,8 +57,8 @@ def test_cell_resolves_and_states_its_deployment():
         "objective", "num_leaves", "learning_rate", "max_bin",
         "min_data_in_leaf", "min_sum_hessian_in_leaf", "metric",
         "verbosity"}
-    assert conf["rows"] == GEN["table_rows"] == 3_771_125 > pub["rows"]
-    assert GEN["queries"] == 31_531 and GEN["longest"] == 1_251
+    assert conf["rows"] == gen["table_rows"] == 3_771_125 > pub["rows"]
+    assert gen["queries"] == 31_531 and gen["longest"] == 1_251
     assert conf["reference"] == "lambdarank_reference"
     for key in ("deployment", "reduced_why", "assumed", "guarantees",
                 "xla_flags_why", "bins_seed", "bins_rows"):
@@ -68,8 +72,12 @@ def test_cell_resolves_and_states_its_deployment():
     names = [m["name"] for m in cell["per_layer"]]
     assert set(READERS) <= set(names) and "missing_split_share" not in names
     for other in ("higgs-train", "criteo-train"):
-        theirs = [m["name"] for m in spec.load_cell(ROOT, other)["per_layer"]]
+        theirs = [m["name"] for m in spec.load_cell(root, other)["per_layer"]]
         assert not set(READERS) & set(theirs)
+
+
+def test_cell_resolves_and_states_its_deployment():
+    check_cell_resolves_and_states_its_deployment(ROOT)
 
 
 def test_reference_imports_nothing_of_the_program():

@@ -1,7 +1,11 @@
 """`tiled_partition_row_share` (PR 27): the share of partitioned rows
 that go through the tiled partition, read from the program's counters
-`partition_tiled_rows` / `partition_rows`. CPU, tiny size: counts only,
-and the default partition off the TPU is `sort`, which never tiles."""
+`partition_tiled_rows` / `partition_rows`. CPU, tiny size: counts only;
+the partition is the chip's on every platform (the scan, tiled over
+`SCATTER_TILE_ROWS`; PR 31), and the tiny cell's windows are under one
+tile until the test shrinks it."""
+from pathlib import Path
+
 import bench_rehearsal
 from bench_rehearsal import ROOT, tiny_root  # noqa: F401 (a fixture)
 
@@ -24,20 +28,21 @@ def test_reader_gives_the_share_of_the_counted_rows(monkeypatch):
     monkeypatch.setattr(counters, "get",
                         lambda key, default=0: have.get(key, default))
     assert spec.load_layer_metric(NAME).read({}) == 75.0
-    # rows counted and none tiled (the `sort` partition, small windows):
+    # rows counted and none tiled (the chunk core, windows of one tile):
     # nothing, since the harness prints no metric at 0
     have["partition_tiled_rows"] = 0.0
     assert spec.load_layer_metric(NAME).read({}) is None
 
 
-def test_per_layer_list_and_reader_files_match_one_to_one():
-    bench = bench_rehearsal.load_bench(ROOT)
+def check_per_layer_list_and_reader_files_match_one_to_one(root):
+    bench = bench_rehearsal.load_bench(root)
     declared = [m["name"] for m in bench["per_layer"]]
-    assert sorted(declared) == spec.layer_metric_names()
-    # held by name: where it stands among the sixteen is
-    # `check_what_stands_is_a_prefix`'s, and later entries come after it
+    assert sorted(declared) == spec.layer_metric_names(
+        Path(root) / "benchmark")
+    # held by name: where it stands is `check_what_stands_is_a_prefix`'s,
+    # and later entries come after it
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    mod = spec.load_layer_metric(NAME)
+    mod = bench_rehearsal.reader_module(root, NAME)
     assert entry == {"name": NAME, "unit": mod.UNIT, "better": "higher",
                      "source": mod.SOURCE, "layer": mod.LAYER,
                      "moves": mod.MOVES}
@@ -45,16 +50,19 @@ def test_per_layer_list_and_reader_files_match_one_to_one():
         "tree program", "program_counter", "train_row_trees_per_s")
 
 
+def test_per_layer_list_and_reader_files_match_one_to_one():
+    check_per_layer_list_and_reader_files_match_one_to_one(ROOT)
+
+
 def test_traced_line_of_a_tiling_learner_holds_the_share(
         tiny_root, monkeypatch):  # noqa: F811
     """The masked learner the tiny cell gets by default moves no rows and
-    counts nothing; the compact core under `scan`, with the tile forced
+    counts nothing; the compact core, with the tile forced
     under the upper rungs of its ladder (4096, 8192, 16384, 20000),
     counts every split's parent rows and tiles those over 4096."""
     from lightgbm_tpu.models import device_learner as dl
     from lightgbm_tpu.telemetry import counters
     monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
-    monkeypatch.setenv("LGBM_TPU_PARTITION", "scan")
     monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", 4096)
     counters.reset()
     # something for `missing_split_share` to read, were it asked: it lists
