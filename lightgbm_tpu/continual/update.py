@@ -66,7 +66,7 @@ def _encode_bundle_block(inner, codes: np.ndarray) -> np.ndarray:
             continue
         for j, base in zip(col.features, col.bases):
             m = inner.bin_mappers[inner.used_features[j]]
-            encode_bundle(out[:, ci], codes[:, j].astype(np.int32),
+            encode_bundle(out[:, ci], codes[:, j],
                           base, m.default_bin)
     return out
 

@@ -5,9 +5,11 @@ Equivalent surface to the reference Dataset/DatasetLoader/Metadata
 TPU-first storage decision: instead of per-group Bin objects (dense/sparse/
 4-bit variants, src/io/*_bin.hpp), the binned matrix is ONE dense (N, F)
 uint8/uint16 device array — XLA-friendly static shape, rows gatherable for
-leaf-wise histogram work. Sparse inputs are densified through binning (bins
-are small ints; the zero bin is the default bin, so sparsity costs only
-storage, which EFB-style bundling can reclaim later).
+leaf-wise histogram work. Sparse inputs are binned from their nonzeros:
+where the EFB plan bundles anything, the (N, C) bundled codes are built
+straight from the CSC columns and the per-feature (N, F) view is made
+only when something asks for it (`binned`); otherwise the nonzeros are
+scattered into the (N, F) code matrix.
 """
 from __future__ import annotations
 
@@ -16,12 +18,14 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..config import Config
+from ..telemetry import counters as telemetry_counters
 from ..telemetry import spans as telem_spans
 from ..utils import log
 from .binning import (BIN_CATEGORICAL, BIN_NUMERICAL, MISSING_NAN,
                       MISSING_NONE, MISSING_ZERO, BinMapper,
                       load_forced_bounds, mapper_from_sample_column,
                       resolve_ignore_set)
+from .bundling import bundle_codes, encode_bundle
 
 
 def resolve_categorical_set(spec, feature_names) -> set:
@@ -145,20 +149,52 @@ class Dataset:
             self.max_num_bins = max(
                 [self.bin_mappers[i].num_bin for i in self.used_features], default=1)
 
-        with telem_spans.stage("setup_bin_data_seconds",
-                               "dataset/bin_data"):
-            self.binned = (self._bin_data_sparse(sparse)
-                           if sparse is not None else self._bin_data(data))
         # EFB: plan storage columns and encode the bundled matrix
         # (reference: dataset.cpp:69-225 FindGroups/FastFeatureBundling).
         # self.binned stays the logical per-feature view for generic
         # consumers; the device learner trains on the narrower bundle view.
-        with telem_spans.stage("setup_bundle_seconds", "dataset/bundle"):
-            self.columns = (reference.columns if reference is not None
-                            else self._plan_bundles())
-            self.bundled = self._encode_bundles() if self.columns else None
+        if sparse is not None:
+            self._construct_sparse(sparse, reference)
+        else:
+            with telem_spans.stage("setup_bin_data_seconds",
+                                   "dataset/bin_data"):
+                self.binned = self._bin_data(data)
+            with telem_spans.stage("setup_bundle_seconds",
+                                   "dataset/bundle"):
+                self.columns = (reference.columns if reference is not None
+                                else self._plan_bundles())
+                self.bundled = (self._encode_bundles() if self.columns
+                                else None)
+        held = sum(a.nbytes for a in (self._binned, self.bundled,
+                                      *(self._nz or ())) if a is not None)
+        telemetry_counters.set_gauge("host_code_bytes_per_row",
+                                     held / max(self.num_data, 1))
         # raw column stats used for leaf renewal on some objectives
         self._device_cache: Dict[str, Any] = {}
+
+    # -- the logical (N, F) view ----------------------------------------
+    # A sparse table that bundles holds the bundled (N, C) codes alone;
+    # its per-feature view is built here, on the first read, for the
+    # consumers that want one (subset, merge, continual update, drift,
+    # dump), decoded from the bundled codes. Where a row of the table
+    # holds two members of one bundle the last one pushed won, so the
+    # nonzeros' codes (`_nz`) are kept to build the view from instead.
+    # Training reads `bundled`.
+    _binned: Optional[np.ndarray] = None
+    _nz: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]] = None
+    _view_from_bundled = False
+
+    @property
+    def binned(self) -> np.ndarray:
+        if self._binned is None and self._view_from_bundled:
+            self._binned = (self._scatter_nonzeros(*self._nz)
+                            if self._nz is not None
+                            else self._decode_bundles())
+        return self._binned
+
+    @binned.setter
+    def binned(self, value) -> None:
+        self._binned, self._nz, self._view_from_bundled = value, None, False
 
     # ------------------------------------------------------------------
     @classmethod
@@ -231,7 +267,12 @@ class Dataset:
         try:
             import scipy.sparse as sp
             if sp.issparse(data):
-                csc = data.tocsc().astype(np.float64)
+                # float32 stays float32: binning reads each column as
+                # float64 anyway, and a float64 copy of a wide table's
+                # nonzeros is the largest thing its construction holds
+                csc = data.tocsc()
+                if csc.dtype not in (np.float32, np.float64):
+                    csc = csc.astype(np.float64)
                 csc.sum_duplicates()
                 csc.sort_indices()
                 return None, csc
@@ -251,13 +292,7 @@ class Dataset:
 
     def _build_mappers(self, data: np.ndarray, cat_idx: set) -> List[BinMapper]:
         cfg = self.config
-        n = self.num_data
-        sample_cnt = min(n, cfg.bin_construct_sample_cnt)
-        rng = np.random.RandomState(cfg.data_random_seed)
-        if sample_cnt < n:
-            sample_rows = np.sort(rng.choice(n, sample_cnt, replace=False))
-        else:
-            sample_rows = np.arange(n)
+        sample_rows = self._bin_sample_rows()
         forced_bounds = load_forced_bounds(cfg.forcedbins_filename)
         ignore = resolve_ignore_set(cfg.ignore_column, self.feature_names)
         mappers = []
@@ -286,11 +321,8 @@ class Dataset:
         semantics). Peak extra memory is O(nnz of one column)."""
         cfg = self.config
         n = self.num_data
-        sample_cnt = min(n, cfg.bin_construct_sample_cnt)
-        rng = np.random.RandomState(cfg.data_random_seed)
-        if sample_cnt < n:
-            sample_rows = np.sort(rng.choice(n, sample_cnt, replace=False))
-        else:
+        sample_rows = self._bin_sample_rows()
+        if len(sample_rows) == n:
             sample_rows = None
         forced_bounds = load_forced_bounds(cfg.forcedbins_filename)
         ignore = resolve_ignore_set(cfg.ignore_column, self.feature_names)
@@ -314,28 +346,141 @@ class Dataset:
                 vals, total, cfg, f, cat_idx, forced_bounds))
         return mappers
 
-    def _bin_data_sparse(self, csc) -> np.ndarray:
-        """Fill the dense code matrix column-by-column from CSC: each
-        column starts at its zero-value bin and only the nonzero entries
-        are scattered — no dense float matrix ever exists."""
-        n_used = len(self.used_features)
+    def _bin_sample_rows(self) -> np.ndarray:
+        """The sorted rows a table's bins are found on (and its bundles
+        planned on): `bin_construct_sample_cnt` of them."""
+        n = self.num_data
+        sample_cnt = min(n, self.config.bin_construct_sample_cnt)
+        if sample_cnt == n:
+            return np.arange(n)
+        rng = np.random.RandomState(self.config.data_random_seed)
+        return np.sort(rng.choice(n, sample_cnt, replace=False))
+
+    # -- sparse tables: O(nnz), and no (N, F) plane where they bundle ---
+    def _construct_sparse(self, csc, reference) -> None:
+        """Bins the nonzeros of the used columns, plans the bundles from
+        the codes of the sampled rows, and builds the bundled (N, C)
+        codes straight from them; the (N, F) code matrix is built only
+        where nothing bundles (then it is what training reads). No dense
+        float matrix ever exists."""
+        with telem_spans.stage("setup_bin_data_seconds",
+                               "dataset/bin_data"):
+            nz = self._bin_nonzeros(csc)
+        with telem_spans.stage("setup_bundle_seconds", "dataset/bundle"):
+            self.columns = (reference.columns if reference is not None
+                            else self._plan_bundles(nz))
+            self.bundled, conflicts = (self._encode_bundles_sparse(*nz)
+                                       if self.columns else (None, 0))
+        if self.columns:
+            self._view_from_bundled = True
+            self._nz = nz if conflicts else None
+            return
+        with telem_spans.stage("setup_bin_data_seconds",
+                               "dataset/bin_data"):
+            self.binned = self._scatter_nonzeros(*nz)
+
+    def _bin_nonzeros(self, csc):
+        """(starts, rows, codes): the stored entries of the used columns
+        in inner-feature order, each with its bin code; feature j's are
+        `starts[j]:starts[j + 1]`."""
         dtype = np.uint8 if self.max_num_bins <= 256 else np.uint16
-        out = np.zeros((self.num_data, max(n_used, 1)), dtype=dtype)
         indptr, indices, values = csc.indptr, csc.indices, csc.data
-        for j, f in enumerate(self.used_features):
-            m = self.bin_mappers[f]
-            zero_bin = m.value_to_bin(0.0)
-            if zero_bin:
-                out[:, j] = dtype(zero_bin)
-            lo, hi = int(indptr[f]), int(indptr[f + 1])
-            if hi > lo:
-                out[indices[lo:hi], j] = m.values_to_bins(
-                    values[lo:hi]).astype(dtype)
+        spans = [(int(indptr[f]), int(indptr[f + 1]))
+                 for f in self.used_features]
+        starts = np.zeros(len(spans) + 1, np.int64)
+        starts[1:] = np.cumsum([hi - lo for lo, hi in spans])
+        every = self.used_features == list(range(self.num_total_features))
+        rows = indices if every else np.empty(int(starts[-1]),
+                                              indices.dtype)
+        codes = np.empty(int(starts[-1]), dtype)
+        for j, (f, (lo, hi)) in enumerate(zip(self.used_features, spans)):
+            if not every:
+                rows[starts[j]:starts[j + 1]] = indices[lo:hi]
+            codes[starts[j]:starts[j + 1]] = \
+                self.bin_mappers[f].values_to_bins(values[lo:hi])
+        return starts, rows, codes
+
+    def _zero_bin(self, j: int) -> int:
+        return self.bin_mappers[self.used_features[j]].value_to_bin(0.0)
+
+    def _scatter_nonzeros(self, starts, rows, codes) -> np.ndarray:
+        """The (N, F) code matrix from the nonzeros' codes: each column
+        starts at its zero-value bin and only the nonzeros are
+        scattered."""
+        out = np.empty((self.num_data, max(self.num_features, 1)),
+                       dtype=codes.dtype)
+        out[:] = [self._zero_bin(j) for j in range(self.num_features)] or 0
+        for j in range(self.num_features):
+            a, b = starts[j], starts[j + 1]
+            out[rows[a:b], j] = codes[a:b]
+        return out
+
+    def _encode_bundles_sparse(self, starts, rows,
+                               codes) -> Tuple[np.ndarray, int]:
+        """The bundled (N, C) codes from the nonzeros' codes: what
+        `_encode_bundles` gives on the (N, F) view, byte for byte. A
+        single-feature column starts at its zero bin and takes its
+        nonzeros; a bundle member writes `base + j` at its non-default
+        rows, the last member pushed winning a conflict row, as
+        `encode_bundle` does. Also gives how many writes met a row
+        written already (conflicts)."""
+        out = np.empty((self.num_data, len(self.columns)),
+                       dtype=self._bundled_dtype())
+        out[:] = [0 if col.is_bundle else self._zero_bin(col.features[0])
+                  for col in self.columns]
+        conflicts = 0
+        for ci, col in enumerate(self.columns):
+            for j, base in zip(col.features, col.bases):
+                a, b = starts[j], starts[j + 1]
+                if not col.is_bundle:
+                    out[rows[a:b], ci] = codes[a:b]
+                    continue
+                default = self._default(j)
+                if self._zero_bin(j) != default:
+                    # the absent rows are not at the default bin: every
+                    # row is written, so take the whole column
+                    full = np.full(self.num_data, self._zero_bin(j),
+                                   codes.dtype)
+                    full[rows[a:b]] = codes[a:b]
+                    at = np.flatnonzero(full != default)
+                    bins = full[at]
+                else:
+                    keep = codes[a:b] != default
+                    at, bins = rows[a:b][keep], codes[a:b][keep]
+                # a member's codes are never 0: a row written already is
+                # a conflict, and the last member pushed wins it
+                conflicts += int(np.count_nonzero(out[at, ci]))
+                out[at, ci] = bundle_codes(bins, base, default)
+        return out, conflicts
+
+    def _decode_bundles(self) -> np.ndarray:
+        """The (N, F) code matrix from the bundled codes of a table where
+        no row holds two members of one bundle: a member's rows are those
+        whose code lies in its range, and its other rows sit at its
+        default bin."""
+        dtype = np.uint8 if self.max_num_bins <= 256 else np.uint16
+        out = np.empty((self.num_data, max(self.num_features, 1)), dtype)
+        out[:] = [self._default(j) for j in range(self.num_features)] or 0
+        for ci, col in enumerate(self.columns):
+            codes = self.bundled[:, ci]
+            if not col.is_bundle:
+                out[:, col.features[0]] = codes
+                continue
+            feature = np.zeros(col.num_bins, np.int64)   # code -> member
+            value = np.zeros(col.num_bins, dtype)        # code -> its bin
+            for j, base in zip(col.features, col.bases):
+                k = np.arange(self.bin_mappers[
+                    self.used_features[j]].num_bin - 1)
+                feature[base + k] = j
+                value[base + k] = k + (k >= self._default(j))
+            at = np.flatnonzero(codes)
+            out[at, feature[codes[at]]] = value[codes[at]]
         return out
 
     # ------------------------------------------------------------------
-    def _plan_bundles(self):
-        """EFB column plan from a sample of the binned matrix."""
+    def _plan_bundles(self, nz=None):
+        """EFB column plan from a sample of the binned matrix, or of the
+        nonzeros' codes `nz` (`_bin_nonzeros`) where there is no plane."""
         from .bundling import plan_columns
         cfg = self.config
         if (not cfg.enable_bundle or self.num_features <= 1
@@ -347,21 +492,50 @@ class Dataset:
             # would stop vstacking into one logical matrix — train on
             # the unbundled per-feature view instead
             return None
-        sample = min(self.num_data, 50_000)
-        rows = (np.linspace(0, self.num_data - 1, sample).astype(np.int64)
-                if sample < self.num_data else np.arange(self.num_data))
-        sample_bins = [self.binned[rows, j].astype(np.int32)
-                       for j in range(self.num_features)]
-        cols = plan_columns(self.used_features, self.bin_mappers, sample_bins,
-                            cfg.max_conflict_rate, cfg.sparse_threshold)
+        # the rows the bins were found on, as the reference plans
+        # (dataset.cpp FindGroups): a one-hot level the binning saw is
+        # then seen by the plan, which would otherwise bundle it blind
+        # and let the whole table conflict where the sample did not
+        rows = self._bin_sample_rows()
+        if nz is None:
+            sample = (self.binned if len(rows) == self.num_data
+                      else self.binned[rows])
+            nondefault = [np.flatnonzero(sample[:, j] != self._default(j))
+                          for j in range(self.num_features)]
+        else:
+            nondefault = [self._sample_nondefault(nz, j, rows)
+                          for j in range(self.num_features)]
+        cols = plan_columns(self.used_features, self.bin_mappers, nondefault,
+                            len(rows), cfg.max_conflict_rate,
+                            cfg.sparse_threshold)
         if all(len(c.features) == 1 for c in cols):
             return None
         return cols
 
-    def _encode_bundles(self) -> np.ndarray:
-        from .bundling import encode_bundle
+    def _default(self, j: int) -> int:
+        return self.bin_mappers[self.used_features[j]].default_bin
+
+    def _sample_nondefault(self, nz, j, sample_rows) -> np.ndarray:
+        """Indices into the (sorted) sampled rows where feature j is away
+        from its default bin, from its nonzeros alone."""
+        starts, rows, codes = nz
+        a, b = starts[j], starts[j + 1]
+        at = np.searchsorted(sample_rows, rows[a:b])
+        hit = at < len(sample_rows)
+        hit[hit] = sample_rows[at[hit]] == rows[a:b][hit]
+        away = codes[a:b][hit] != self._default(j)
+        if self._zero_bin(j) == self._default(j):
+            return at[hit][away]
+        mask = np.ones(len(sample_rows), bool)      # absent rows are away
+        mask[at[hit][~away]] = False
+        return np.flatnonzero(mask)
+
+    def _bundled_dtype(self):
         col_bins = max(c.num_bins for c in self.columns)
-        dtype = np.uint8 if col_bins <= 256 else np.uint16
+        return np.uint8 if col_bins <= 256 else np.uint16
+
+    def _encode_bundles(self) -> np.ndarray:
+        dtype = self._bundled_dtype()
         out = np.zeros((self.num_data, len(self.columns)), dtype=dtype)
         for ci, col in enumerate(self.columns):
             if not col.is_bundle:
@@ -369,8 +543,8 @@ class Dataset:
                 continue
             for j, base in zip(col.features, col.bases):
                 m = self.bin_mappers[self.used_features[j]]
-                encode_bundle(out[:, ci], self.binned[:, j].astype(np.int32),
-                              base, m.default_bin)
+                encode_bundle(out[:, ci], self.binned[:, j], base,
+                              m.default_bin)
         return out
 
     def bundle_arrays(self):

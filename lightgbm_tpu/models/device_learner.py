@@ -2641,6 +2641,19 @@ class DeviceTreeLearner:
             hi = np.where(np.arange(self.device_bins)[None, :] < nb,
                           hi, zero_slot)
             self.hist_idx = jnp.asarray(hi.astype(np.int32))
+        # how much wider the per-feature plane the split scan reads is
+        # than the column histogram it is expanded from, and the share
+        # of the features that share a column with others
+        columns = dataset.columns or []
+        telemetry.counters.set_gauge(
+            "hist_expansion_ratio",
+            self.num_features * self.device_bins
+            / max((len(columns) or self.num_features)
+                  * self.col_device_bins, 1))
+        telemetry.counters.set_gauge(
+            "bundled_feature_share",
+            100.0 * sum(len(c.features) for c in columns if c.is_bundle)
+            / max(self.num_features, 1))
         contri = config.feature_contri or []
         pen = np.array([contri[fr] if fr < len(contri) else 1.0
                         for fr in dataset.used_features], dtype=np.float32)

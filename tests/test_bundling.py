@@ -25,7 +25,8 @@ def test_find_bundles_exclusive():
     masks[1][300:600] = True     # exclusive with 0 -> same bundle
     masks[2][100:400] = True     # conflicts with both
     masks[3][600:900] = True     # exclusive with 0,1
-    bundles = find_bundles(masks, [10, 10, 10, 10],
+    bundles = find_bundles([np.flatnonzero(m) for m in masks],
+                           [10, 10, 10, 10],
                            max_conflict_rate=0.0, sample_cnt=n)
     merged = sorted(sorted(b) for b in bundles if len(b) > 1)
     assert any({0, 1}.issubset(set(b)) for b in merged)
@@ -35,7 +36,8 @@ def test_find_bundles_exclusive():
 def test_find_bundles_bin_budget():
     n = 100
     masks = [np.zeros(n, bool) for _ in range(3)]
-    bundles = find_bundles(masks, [200, 200, 200],
+    bundles = find_bundles([np.flatnonzero(m) for m in masks],
+                           [200, 200, 200],
                            max_conflict_rate=0.0, sample_cnt=n)
     # 199 + 199 > 255 non-default codes: no pair fits one uint8 column
     assert all(len(b) == 1 for b in bundles)
