@@ -153,18 +153,27 @@ def _hist_t_q(codes_t, ghq, num_bins, use_pallas, hist_chunk=0):
 
 
 def _tree_helpers(base_mask, f_numbins, f_missing, f_default, f_monotone,
-                  f_penalty, f_elide, hist_idx, *, num_bins, max_depth,
+                  f_penalty, f_elide, scan_plan, *, num_bins, max_depth,
                   l1, l2, max_delta_step, min_data_in_leaf, min_sum_hessian,
                   min_gain_to_split, bynode_k,
                   f_categorical=None, cat_statics=None, dequant=None):
     """Shared pieces of both growth strategies: per-node feature sampling,
-    the (expand + scan + materialize) split search, and per-leaf best-state
+    the (plane + scan + materialize) split search, and per-leaf best-state
     stores with depth gating.
+
+    scan_plan (ops/bundle.py split_scan_plan) says which planes the scan
+    reads. None: the column histogram is the per-feature one and is
+    scanned as it is. Otherwise every width class of features gets a
+    plane as wide as its bin counts, expanded from the column histogram
+    with the class's own map (FixHistogram included); the per-feature
+    results go back to feature order, so the argmax over features picks
+    what one (F, num_bins) plane would, lowest index first on ties.
 
     cat_statics = (cat_l2, cat_smooth, max_cat_threshold,
     max_cat_to_onehot, min_data_per_group) switches the scan into merged
-    numerical+categorical mode: each leaf evaluates both searches over the
-    same expanded histogram and the better gain wins (the in-program analog
+    numerical+categorical mode: each leaf evaluates both searches (the
+    categorical one over its own plane of num_bins) and the better gain
+    wins (the in-program analog
     of SerialTreeLearner._merge_categorical). scan then returns
     (SplitResult, left-bin mask) where the mask is all-zero for a numerical
     winner; without cat_statics the mask is a (1,) placeholder.
@@ -197,29 +206,91 @@ def _tree_helpers(base_mask, f_numbins, f_missing, f_default, f_monotone,
         kth = jnp.sort(u)[bynode_k - 1]
         return base_mask & (u <= kth)
 
+    # each plane: (feature ids or None for all, expansion map or None
+    # for the column histogram itself, the features' per-feature arrays)
+    per_f = (f_numbins, f_missing, f_default, f_monotone, f_penalty,
+             f_elide)
+    if scan_plan is None:
+        inv = None
+        num_planes = [(None, None, per_f)]
+        cat_plane = (None, None, per_f) if has_cat else None
+    else:
+        inv, num_classes, cat_class = scan_plan
+        num_planes = [(ids, idx, tuple(jnp.take(a, ids) for a in per_f))
+                      for ids, idx in num_classes]
+        cat_plane = None
+        if has_cat:
+            ids, idx = cat_class
+            cat_plane = (ids, idx, tuple(jnp.take(a, ids) for a in per_f))
+
+    def plane_of(col_hist, totals, idx, meta):
+        if idx is None:
+            return col_hist
+        return bundle_ops.expand_column_hist(col_hist, totals, idx,
+                                             meta[5], meta[2])
+
+    def numerical_best(col_hist, totals, sg, sh, cnt, mn, mx, fmask):
+        outs = []
+        for ids, idx, meta in num_planes:
+            nb_k, miss_k, def_k, mono_k, pen_k, _ = meta
+            if ids is None:
+                fmask_k = fmask & ~is_cat if has_cat else fmask
+            else:
+                fmask_k = jnp.take(fmask, ids)
+            outs.append(split_ops.per_feature_best(
+                plane_of(col_hist, totals, idx, meta), sg, sh, cnt, nb_k,
+                miss_k, def_k, fmask_k, mono_k, mn, mx, pen_k, None,
+                **scan_kwargs))
+        mat = functools.partial(split_ops.materialize_split,
+                                min_constraint=mn, max_constraint=mx,
+                                l1=l1, l2=l2, max_delta_step=max_delta_step)
+        if inv is None:
+            rel, t, use_m1, prefix = outs[0]
+            feat = jnp.argmax(rel).astype(jnp.int32)
+            return mat(feat, rel, t, use_m1, prefix)
+        # back to feature order (categorical features last in `inv`),
+        # then the winner from its own class's prefix tensors
+        rels = [o[0] for o in outs]
+        if cat_plane is not None:
+            rels.append(jnp.full(cat_plane[0].shape, NEG_INF, jnp.float32))
+        feat = jnp.argmax(jnp.concatenate(rels)[inv]).astype(jnp.int32)
+        pos = inv[feat]
+        res, start = None, 0
+        for rel_k, t_k, m1_k, prefix_k in outs:
+            f_k = rel_k.shape[0]
+            r = mat(jnp.clip(pos - start, 0, f_k - 1), rel_k, t_k, m1_k,
+                    prefix_k)
+            res = r if res is None else jax.tree.map(
+                functools.partial(jnp.where, pos >= start), r, res)
+            start += f_k
+        if res is None:           # every feature is categorical
+            z = jnp.float32(0.0)
+            return split_ops.SplitResult(
+                jnp.float32(NEG_INF), feat, jnp.int32(0), jnp.bool_(False),
+                z, z, z, z, z, z, z, z)
+        return res._replace(feature=feat)
+
     @jax.named_scope("lgbm.split_scan")
     def scan(col_hist, sg, sh, cnt, mn, mx, fmask):
         if dequant is not None:
             col_hist = dequant(col_hist)
-        hist = bundle_ops.expand_column_hist(
-            col_hist, jnp.stack([sg, sh, cnt]), hist_idx, f_elide, f_default)
-        rel, t, use_m1, prefix = split_ops.per_feature_best(
-            hist, sg, sh, cnt, f_numbins, f_missing, f_default,
-            fmask & ~is_cat if has_cat else fmask,
-            f_monotone, mn, mx, f_penalty, None, **scan_kwargs)
-        feat = jnp.argmax(rel).astype(jnp.int32)
-        res = split_ops.materialize_split(
-            feat, rel, t, use_m1, prefix, mn, mx,
-            l1=l1, l2=l2, max_delta_step=max_delta_step)
-        if not has_cat:
+        totals = jnp.stack([sg, sh, cnt])
+        res = numerical_best(col_hist, totals, sg, sh, cnt, mn, mx, fmask)
+        if cat_plane is None:
             return res, jnp.zeros((cat_b,), jnp.float32)
+        ids, idx, meta = cat_plane
+        nb_c, miss_c, _, _, pen_c, _ = meta
+        hist = plane_of(col_hist, totals, idx, meta)
+        fmask_c = fmask & is_cat if ids is None else jnp.take(fmask, ids)
         crel, caux = split_ops.per_feature_best_categorical(
-            hist, sg, sh, cnt, f_numbins, f_missing, fmask & is_cat,
-            mn, mx, f_penalty, **cat_kwargs)
+            hist, sg, sh, cnt, nb_c, miss_c, fmask_c,
+            mn, mx, pen_c, **cat_kwargs)
         cfeat = jnp.argmax(crel).astype(jnp.int32)
         cres = split_ops.materialize_cat_split(
             cfeat, crel, caux, hist, sg, sh, cnt, mn, mx,
             l1=l1, l2=l2, cat_l2=cat_l2, max_delta_step=max_delta_step)
+        if ids is not None:
+            cres = cres._replace(feature=jnp.take(ids, cfeat))
         return _merge_num_cat(res, cres)
 
     def _best_row(res: split_ops.SplitResult, child_depth) -> jax.Array:
@@ -317,7 +388,7 @@ def grow_tree(codes_t: jax.Array,         # (C, N) column codes (EFB view)
               f_penalty,                  # (F,) f32 gain multipliers
               f_categorical,              # (F,) int32 1 = categorical
               f_col, f_base, f_elide,     # (F,) int32 EFB maps
-              hist_idx,                   # (F, B) int32 expansion gather
+              scan_plan,                  # ops/bundle.py split_scan_plan
               rng_key,                    # PRNG key for by-node sampling
               *, num_leaves: int, num_bins: int, col_bins: int,
               max_depth: int,
@@ -356,7 +427,7 @@ def grow_tree(codes_t: jax.Array,         # (C, N) column codes (EFB view)
             return _hist_t(codes_t, ghx, col_bins, use_pallas, hist_chunk)
     node_mask, scan, store_best, scan2, best_row = _tree_helpers(
         base_mask, f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_elide, hist_idx,
+        f_elide, scan_plan,
         num_bins=num_bins, max_depth=max_depth, l1=l1, l2=l2,
         max_delta_step=max_delta_step, min_data_in_leaf=min_data_in_leaf,
         min_sum_hessian=min_sum_hessian, min_gain_to_split=min_gain_to_split,
@@ -560,7 +631,7 @@ def grow_tree_compact(
         grad: jax.Array, hess: jax.Array, w: jax.Array,
         base_mask: jax.Array,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         *, c_cols: int, item_bits: int,
         num_leaves: int, num_bins: int, col_bins: int, max_depth: int,
         l1: float, l2: float, max_delta_step: float,
@@ -573,7 +644,7 @@ def grow_tree_compact(
     return grow_tree_compact_core(
         codes_pack, codes_row, grad, hess, w, base_mask,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         c_cols=c_cols, item_bits=item_bits, num_leaves=num_leaves,
         num_bins=num_bins, col_bins=col_bins, max_depth=max_depth,
         l1=l1, l2=l2, max_delta_step=max_delta_step,
@@ -588,7 +659,7 @@ def grow_tree_compact(
 
 def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
                        base_mask, f_numbins, f_missing, f_default,
-                       f_monotone, f_penalty, f_elide, hist_idx,
+                       f_monotone, f_penalty, f_elide,
                        f_categorical, has_cat, cat_statics,
                        helper_kwargs):
     """PV-Tree 2-stage voting reduction + search, shared by the
@@ -619,7 +690,17 @@ def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
     d_v = jax.lax.psum(1, axis_name)
     (node_mask, _, _, _, best_row) = _tree_helpers(
         base_mask, f_numbins, f_missing, f_default, f_monotone,
-        f_penalty, f_elide, hist_idx, **helper_kwargs)
+        f_penalty, f_elide, None, **helper_kwargs)
+
+    def identity_idx(nb):
+        """(k, col_bins) expansion map of k features in columns 0..k-1
+        of a flattened (k * col_bins + 1, 3) histogram."""
+        k = nb.shape[0]
+        bins = jnp.arange(col_bins, dtype=jnp.int32)[None, :]
+        return jnp.where(
+            bins < nb[:, None],
+            jnp.arange(k, dtype=jnp.int32)[:, None] * col_bins + bins,
+            k * col_bins)
     scan_kwargs_local = dict(
         num_bins=num_bins, l1=l1, l2=l2, max_delta_step=max_delta_step,
         # integer division for the count gate, exactly the
@@ -656,7 +737,7 @@ def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
         """Per-feature local best gains from the shard's histograms."""
         lt = col_hist_l[0].sum(axis=0)        # local (sg, sh, cnt)
         hist = bundle_ops.expand_column_hist(
-            col_hist_l, lt, hist_idx, f_elide, f_default)
+            col_hist_l, lt, identity_idx(f_numbins), f_elide, f_default)
         rel, _, _, _ = split_ops.per_feature_best(
             hist, lt[0], lt[1], lt[2], f_numbins, f_missing, f_default,
             fmask & ~is_cat_v if has_cat else fmask, f_monotone,
@@ -684,13 +765,8 @@ def make_voting_search(*, axis_name, voting_k, c_cols, col_bins,
         hist_e = jax.lax.psum(jnp.take(col_hist_l, elect, axis=0),
                               axis_name)      # (2k, B, 3) global
         nb_e = jnp.take(f_numbins, elect)
-        hi_e = (jnp.arange(n_elect, dtype=jnp.int32)[:, None] * col_bins
-                + jnp.arange(col_bins, dtype=jnp.int32)[None, :])
-        hi_e = jnp.where(
-            jnp.arange(col_bins, dtype=jnp.int32)[None, :]
-            < nb_e[:, None], hi_e, n_elect * col_bins)
         hist_f = bundle_ops.expand_column_hist(
-            hist_e, jnp.stack([sg, sh, cnt]), hi_e,
+            hist_e, jnp.stack([sg, sh, cnt]), identity_idx(nb_e),
             jnp.take(f_elide, elect), jnp.take(f_default, elect))
         fmask_e = jnp.take(fmask, elect)
         if has_cat:
@@ -871,7 +947,7 @@ def grow_tree_compact_core(
         grad: jax.Array, hess: jax.Array, w: jax.Array,
         base_mask: jax.Array,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         *, c_cols: int, item_bits: int,
         num_leaves: int, num_bins: int, col_bins: int, max_depth: int,
         l1: float, l2: float, max_delta_step: float,
@@ -1008,14 +1084,14 @@ def grow_tree_compact_core(
             col_bins=col_bins, base_mask=base_mask,
             f_numbins=f_numbins, f_missing=f_missing,
             f_default=f_default, f_monotone=f_monotone,
-            f_penalty=f_penalty, f_elide=f_elide, hist_idx=hist_idx,
+            f_penalty=f_penalty, f_elide=f_elide,
             f_categorical=f_categorical, has_cat=has_cat,
             cat_statics=cat_statics, helper_kwargs=helper_kwargs)
     elif not sliced:
         (node_mask, scan, store_best, scan2,
          best_row) = _tree_helpers(
             base_mask, f_numbins, f_missing, f_default, f_monotone,
-            f_penalty, f_elide, hist_idx,
+            f_penalty, f_elide, scan_plan,
             f_categorical=f_categorical, cat_statics=cat_statics,
             **helper_kwargs)
 
@@ -1491,7 +1567,7 @@ def grow_tree_chunk(
         grad: jax.Array, hess: jax.Array, w: jax.Array,
         base_mask: jax.Array,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         *, c_cols: int, item_bits: int,
         num_leaves: int, num_bins: int, col_bins: int, max_depth: int,
         l1: float, l2: float, max_delta_step: float,
@@ -1505,7 +1581,7 @@ def grow_tree_chunk(
     return grow_tree_chunk_core(
         codes_pack, codes_row, grad, hess, w, base_mask,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         c_cols=c_cols, item_bits=item_bits, num_leaves=num_leaves,
         num_bins=num_bins, col_bins=col_bins, max_depth=max_depth,
         l1=l1, l2=l2, max_delta_step=max_delta_step,
@@ -1524,7 +1600,7 @@ def grow_tree_chunk_core(
         grad: jax.Array, hess: jax.Array, w: jax.Array,
         base_mask: jax.Array,
         f_numbins, f_missing, f_default, f_monotone, f_penalty,
-        f_categorical, f_col, f_base, f_elide, hist_idx, rng_key,
+        f_categorical, f_col, f_base, f_elide, scan_plan, rng_key,
         *, c_cols: int, item_bits: int,
         num_leaves: int, num_bins: int, col_bins: int, max_depth: int,
         l1: float, l2: float, max_delta_step: float,
@@ -1709,7 +1785,7 @@ def grow_tree_chunk_core(
             col_bins=col_bins, base_mask=base_mask,
             f_numbins=f_numbins, f_missing=f_missing,
             f_default=f_default, f_monotone=f_monotone,
-            f_penalty=f_penalty, f_elide=f_elide, hist_idx=hist_idx,
+            f_penalty=f_penalty, f_elide=f_elide,
             f_categorical=f_categorical, has_cat=has_cat,
             cat_statics=cat_statics, helper_kwargs=helper_kwargs)
         hist_w = c_cols
@@ -1720,7 +1796,7 @@ def grow_tree_chunk_core(
         (node_mask, scan, store_best, scan2,
          best_row) = _tree_helpers(
             base_mask, f_numbins, f_missing, f_default, f_monotone,
-            f_penalty, f_elide, hist_idx,
+            f_penalty, f_elide, scan_plan,
             f_categorical=f_categorical, cat_statics=cat_statics,
             **helper_kwargs)
         hist_w = c_cols
@@ -2118,16 +2194,11 @@ def make_sliced_search(*, axis_name, fp, D, c_cols, col_bins, item_bits,
     pen_sl = sl(pad1(f_penalty, 1.0))
     elide_sl = sl(pad1(f_elide, 0))
     cat_sl = sl(pad1(f_categorical, 0)) if has_cat else None
-    # local expansion gather for the slice's flattened (cs*B + 1)
-    # column histogram (identity mapping: feature j bin b -> j*B + b)
-    hi_local = (jnp.arange(cs, dtype=jnp.int32)[:, None] * col_bins
-                + jnp.arange(col_bins, dtype=jnp.int32)[None, :])
-    hi_local = jnp.where(
-        jnp.arange(col_bins, dtype=jnp.int32)[None, :] < nb_sl[:, None],
-        hi_local, cs * col_bins)
+    # identity mapping: the slice's column histogram is its features'
+    # own, scanned as it is
     (_, scan_sl, _, _, best_row) = _tree_helpers(
         mask_sl, nb_sl, miss_sl, def_sl, mono_sl, pen_sl, elide_sl,
-        hi_local, f_categorical=cat_sl, cat_statics=cat_statics,
+        None, f_categorical=cat_sl, cat_statics=cat_statics,
         **helper_kwargs)
 
     if fp:
@@ -2600,23 +2671,13 @@ class DeviceTreeLearner:
                             jnp.asarray(jnp.swapaxes(codes, 0, 1)))  # (C, N)
             self.f_col, self.f_base, self.f_elide = f_col, f_base, f_elide
             self.col_device_bins = padded_device_bins(int(col_bins))
-            # pad hist_idx bin axis to device_bins; pad slots hit the
-            # trailing zero entry of the flattened column histogram
-            zero_slot = len(dataset.columns) * self.col_device_bins
-            hi = np.asarray(hist_idx)
-            # re-space flat indices for the padded column bin count
-            raw_cb = int(col_bins)
-            cols_i = hi // raw_cb
-            bins_i = hi % raw_cb
-            invalid = hi == (len(dataset.columns) * raw_cb)
-            hi2 = np.where(invalid, zero_slot,
-                           cols_i * self.col_device_bins + bins_i)
-            pad = self.device_bins - hi2.shape[1]
-            if pad > 0:
-                hi2 = np.concatenate(
-                    [hi2, np.full((hi2.shape[0], pad), zero_slot, np.int32)],
-                    axis=1)
-            self.hist_idx = jnp.asarray(hi2.astype(np.int32))
+            n_cols = len(dataset.columns)
+            hi = bundle_ops.respace_hist_idx(
+                hist_idx, n_cols, int(col_bins), self.col_device_bins,
+                self.device_bins)
+            self.scan_plan, plane_elems = bundle_ops.split_scan_plan(
+                hi, self.f_numbins, self.f_categorical, n_cols,
+                self.col_device_bins)
         else:
             if stream_on or getattr(dataset, "row_shard", None) is not None:
                 # streaming holds no resident codes; a row-sharded
@@ -2634,17 +2695,14 @@ class DeviceTreeLearner:
             self.f_base = jnp.zeros(nf, jnp.int32)
             self.f_elide = jnp.zeros(nf, jnp.int32)
             self.col_device_bins = self.device_bins
-            zero_slot = nf * self.device_bins
-            hi = (np.arange(nf, dtype=np.int64)[:, None] * self.device_bins
-                  + np.arange(self.device_bins)[None, :])
-            nb = np.asarray(self.f_numbins)[:, None]
-            hi = np.where(np.arange(self.device_bins)[None, :] < nb,
-                          hi, zero_slot)
-            self.hist_idx = jnp.asarray(hi.astype(np.int32))
-        # how much wider the per-feature plane the split scan reads is
-        # than the column histogram it is expanded from, and the share
-        # of the features that share a column with others
+            # feature j is column j: its histogram is scanned as it is
+            self.scan_plan, plane_elems = None, nf * self.device_bins
+        # the (feature, bin) positions one child's split scan reads; how
+        # much wider a plane of every feature at the device bins would be
+        # than the column histogram (a property of the plan); and the
+        # share of the features that share a column with others
         columns = dataset.columns or []
+        telemetry.counters.set_gauge("split_scan_plane_elems", plane_elems)
         telemetry.counters.set_gauge(
             "hist_expansion_ratio",
             self.num_features * self.device_bins
@@ -2936,7 +2994,7 @@ class DeviceTreeLearner:
         statics = self._statics()
         meta = (self.f_numbins, self.f_missing, self.f_default,
                 self.f_monotone, self.f_penalty, self.f_categorical,
-                self.f_col, self.f_base, self.f_elide, self.hist_idx)
+                self.f_col, self.f_base, self.f_elide, self.scan_plan)
         quant_bits, hist_chunk = self.quant_bits, self.hist_chunk
 
         def one(codes_t, g, h, w, base_mask, key):
@@ -3040,13 +3098,13 @@ class DeviceTreeLearner:
                 self.f_numbins, self.f_missing, self.f_default,
                 self.f_monotone, self.f_penalty, self.f_categorical,
                 self.f_col, self.f_base,
-                self.f_elide, self.hist_idx, key, **kw, **self._statics())
+                self.f_elide, self.scan_plan, key, **kw, **self._statics())
         return grow_tree(
             self.codes_t, grad, hess, w, base_mask,
             self.f_numbins, self.f_missing, self.f_default,
             self.f_monotone, self.f_penalty, self.f_categorical,
             self.f_col, self.f_base,
-            self.f_elide, self.hist_idx, key,
+            self.f_elide, self.scan_plan, key,
             quant_bits=self.quant_bits, hist_chunk=self.hist_chunk,
             **self._statics())
 
@@ -3214,7 +3272,7 @@ class DeviceTreeLearner:
             ctx["data0"], dummy_row, ctx["g"], ctx["h"], ctx["w"],
             base_mask, self.f_numbins, self.f_missing, self.f_default,
             self.f_monotone, self.f_penalty, self.f_categorical,
-            self.f_col, self.f_base, self.f_elide, self.hist_idx, key,
+            self.f_col, self.f_base, self.f_elide, self.scan_plan, key,
             **kw, **self._statics())
         if ctx["idx"] is not None:
             leaf_id = self._stream_full_leaf_id(
@@ -3422,7 +3480,7 @@ class DeviceTreeLearner:
         meta = (self.f_numbins, self.f_missing, self.f_default,
                 self.f_monotone, self.f_penalty, self.f_categorical,
                 self.f_col, self.f_base,
-                self.f_elide, self.hist_idx)
+                self.f_elide, self.scan_plan)
         if goss is not None:
             top_k, other_k, multiply = goss
             bag_on = True

@@ -760,7 +760,7 @@ class DeviceDataParallelTreeLearner(DeviceTreeLearner):
             self.codes_row = jax.device_put(cr, rsh)
         self._meta = (self.f_numbins, self.f_missing, self.f_default,
                       self.f_monotone, self.f_penalty, self.f_categorical,
-                      self.f_col, self.f_base, self.f_elide, self.hist_idx)
+                      self.f_col, self.f_base, self.f_elide, self.scan_plan)
         self._tree_w_fn = None
 
     # -- row-sharded ingest (dist_shard_mode=rows) ---------------------
@@ -1273,7 +1273,7 @@ class DeviceFeatureParallelTreeLearner(DeviceTreeLearner):
         self.codes_row = jnp.asarray(host_codes)
         self._meta = (self.f_numbins, self.f_missing, self.f_default,
                       self.f_monotone, self.f_penalty, self.f_categorical,
-                      self.f_col, self.f_base, self.f_elide, self.hist_idx)
+                      self.f_col, self.f_base, self.f_elide, self.scan_plan)
         self._tree_fn = None
 
     def _grow_statics(self):

@@ -66,7 +66,7 @@ local_n = n // shards
 assert local_n * shards == n
 meta = (lrn.f_numbins, lrn.f_missing, lrn.f_default, lrn.f_monotone,
         lrn.f_penalty, lrn.f_categorical, lrn.f_col, lrn.f_base,
-        lrn.f_elide, lrn.hist_idx)
+        lrn.f_elide, lrn.scan_plan)
 statics = dict(c_cols=lrn.c_cols, item_bits=lrn.item_bits,
                pool_slots=lrn.pool_slots, scatter_cols=shards,
                **lrn._statics())
@@ -158,7 +158,7 @@ lrnc = DeviceTreeLearner(cfgc, dsc, strategy="compact", device_place=False)
 assert dsc.bundle_arrays() is None
 metac = (lrnc.f_numbins, lrnc.f_missing, lrnc.f_default, lrnc.f_monotone,
          lrnc.f_penalty, lrnc.f_categorical, lrnc.f_col, lrnc.f_base,
-         lrnc.f_elide, lrnc.hist_idx)
+         lrnc.f_elide, lrnc.scan_plan)
 staticsc = dict(c_cols=lrnc.c_cols, item_bits=lrnc.item_bits,
                 pool_slots=lrnc.pool_slots, scatter_cols=shards,
                 **lrnc._statics())

@@ -8,9 +8,13 @@ lowering the real fused step at a moderate shape and bounding the
 module size — any regression that re-embeds an (N,)-sized buffer blows
 the bound by an order of magnitude.
 """
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
+import scipy.sparse as sp
 
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import Dataset
@@ -124,3 +128,66 @@ def test_objective_buffer_names_cover_per_row_arrays():
     obj.init(ds.metadata, n)
     names = objective_buffer_names(obj)
     assert "_label_dev" in names and "_weight_dev" in names
+
+
+def _scan_arrays(txt):
+    """(op, result element count) of every instruction of a compiled
+    module's text whose `op_name` runs through `lgbm.split_scan`."""
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*\w+\[([\d,]*)\]"
+                         r"\S*\s+([\w\-]+)\(.*op_name=\"([^\"]*)\"",
+                         txt, re.M):
+        if "lgbm.split_scan" in m.group(3):
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            out.append((m.group(2), int(np.prod(dims)) if dims else 1))
+    return out
+
+
+def _grow_text(lrn, n):
+    grow, kw = lrn._grow_fn_kwargs(trivial_weights=True)
+    per_row = jnp.zeros((n,), jnp.float32)
+    f = lrn.num_features
+    return grow.lower(
+        lrn.codes_pack, lrn.codes_row, per_row, per_row, per_row,
+        jnp.ones((f,), bool), lrn.f_numbins, lrn.f_missing, lrn.f_default,
+        lrn.f_monotone, lrn.f_penalty, lrn.f_categorical, lrn.f_col,
+        lrn.f_base, lrn.f_elide, lrn.scan_plan, jax.random.PRNGKey(0),
+        **kw, **lrn._statics()).compile().as_text()
+
+
+@pytest.mark.parametrize("table", ["bundled", "dense"])
+def test_split_scan_reads_no_plane_of_every_feature_at_the_device_bins(
+        table):
+    """The compiled tree program's split scan: on a bundled table no
+    array of F x device bins positions (each width class of features
+    has a plane as wide as its bin counts), on a table with no bundles
+    no gather of a plane (the column histogram is scanned as it is);
+    `split_scan_plane_elems` the positions one child's scan reads."""
+    from lightgbm_tpu.telemetry import counters
+    rng = np.random.RandomState(2)
+    n = 3000
+    if table == "bundled":
+        levels = rng.randint(0, 100, n)
+        x = sp.hstack([sp.csr_matrix((np.ones(n), (np.arange(n), levels)),
+                                     shape=(n, 100)),
+                       sp.csr_matrix(rng.randn(n, 3))]).tocsr()
+    else:
+        x = rng.randn(n, 40).astype(np.float32)
+    cfg = Config({"objective": "binary", "num_leaves": 7, "verbosity": -1,
+                  "enable_bundle": True, "max_conflict_rate": 0.0})
+    ds = Dataset(x, config=cfg, label=(rng.rand(n) < 0.3).astype(float))
+    lrn = DeviceTreeLearner(cfg, ds, strategy="compact")
+    f, b = lrn.num_features, lrn.device_bins
+    arrays = _scan_arrays(_grow_text(lrn, n))
+    assert arrays, "no instruction under lgbm.split_scan"
+    elems = counters.get("split_scan_plane_elems")
+    if table == "bundled":
+        # the levels (some with too few rows to split are dropped) in a
+        # class of two bins, the three numbers at the device bins
+        assert f > 90 and lrn.scan_plan is not None
+        assert elems == (f - 3) * 2 + 3 * b
+        assert max(size for _, size in arrays) < f * b
+    else:
+        assert lrn.scan_plan is None and elems == f * b
+        gathers = [size for op, size in arrays if op == "gather"]
+        assert not gathers or max(gathers) < f, gathers

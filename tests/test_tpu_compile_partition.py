@@ -240,12 +240,12 @@ def test_packed_table_is_updated_in_place_in_the_split_loop(
     per_row = jnp.zeros((1,), jnp.float32)
     meta = (lrn.f_numbins, lrn.f_missing, lrn.f_default, lrn.f_monotone,
             lrn.f_penalty, lrn.f_categorical, lrn.f_col, lrn.f_base,
-            lrn.f_elide, lrn.hist_idx)
+            lrn.f_elide, lrn.scan_plan)
     tick = time.perf_counter()
     txt = grow.lower(
         shaped(lrn.codes_pack, rows), shaped(lrn.codes_row, rows),
         shaped(per_row, rows), shaped(per_row, rows), shaped(per_row, rows),
-        shaped(jnp.ones(features, bool)), *[shaped(m) for m in meta],
+        shaped(jnp.ones(features, bool)), *[jax.tree.map(shaped, m) for m in meta],
         shaped(jax.random.PRNGKey(0)), **kwargs,
         **lrn._statics()).compile().as_text()
     assert time.perf_counter() - tick < COMPILE_LIMIT_S
