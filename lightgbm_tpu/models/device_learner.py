@@ -1137,15 +1137,17 @@ def grow_tree_compact_core(
 
     # packed working buffer: codes | gh section | row id, padded by wmax
     # (gh section: three bitcast f32 words on the float path, one packed
-    # int word — two with a weight word — on the quantized path)
-    if quant:
-        gh_u = _quant_gh_words(gh_packed, w, gw)
-    else:
-        gh_u = jax.lax.bitcast_convert_type(gh, jnp.uint32)      # (N, 3)
-    ids = jnp.arange(n, dtype=jnp.uint32)[:, None]
-    data0 = jnp.concatenate([codes_pack, gh_u, ids], axis=1)
-    data0 = jnp.concatenate(
-        [data0, jnp.zeros((wmax, d_cols), jnp.uint32)], axis=0)
+    # int word — two with a weight word — on the quantized path); the
+    # table's one write that is not a split's
+    with jax.named_scope("lgbm.table_update"):
+        if quant:
+            gh_u = _quant_gh_words(gh_packed, w, gw)
+        else:
+            gh_u = jax.lax.bitcast_convert_type(gh, jnp.uint32)  # (N, 3)
+        ids = jnp.arange(n, dtype=jnp.uint32)[:, None]
+        data0 = jnp.concatenate([codes_pack, gh_u, ids], axis=1)
+        data0 = jnp.concatenate(
+            [data0, jnp.zeros((wmax, d_cols), jnp.uint32)], axis=0)
 
     # ---- root ------------------------------------------------------------
     from ..ops.histogram import (build_histogram, build_histogram_quantized,
@@ -1204,7 +1206,9 @@ def grow_tree_compact_core(
     best_cat = jnp.zeros((L, cat_b), jnp.float32).at[0].set(cm0)
     # pool dtype follows the histogram dtype: int32 on the quantized
     # path (sibling subtraction below is then exact integer arithmetic)
-    pool = jnp.zeros((K, pool_c, col_bins, 3), hist0.dtype).at[0].set(hist0)
+    with jax.named_scope("lgbm.root_hist"):
+        pool = jnp.zeros((K, pool_c, col_bins, 3), hist0.dtype) \
+            .at[0].set(hist0)
     rec = jnp.zeros((L - 1, 13), jnp.float32)
     carry = _CarryC(
         k=jnp.int32(0),
@@ -1529,9 +1533,10 @@ def grow_tree_compact_core(
         out = run_split_loop(cond, lambda cc: body(cc)[0], carry,
                              L - 1, grow_program)
     # final row -> leaf map: scatter physical-position leaves onto row ids
-    row_ids = out.data[:n, d_cols - 1].astype(jnp.int32)
-    leaf_id = jnp.zeros(n, jnp.int32).at[row_ids].set(
-        out.pos_leaf[:n], unique_indices=True)
+    with jax.named_scope("lgbm.row_leaf"):
+        row_ids = out.data[:n, d_cols - 1].astype(jnp.int32)
+        leaf_id = jnp.zeros(n, jnp.int32).at[row_ids].set(
+            out.pos_leaf[:n], unique_indices=True)
     return (out.rec, out.rec_cat if has_cat else None,
             leaf_id, out.k, totals)
 
@@ -2148,9 +2153,10 @@ def grow_tree_chunk_core(
     else:
         out = run_split_loop(cond, lambda cc: body(cc)[0], carry,
                              L - 1, grow_program)
-    row_ids = out.data[:n, d_cols - 1].astype(jnp.int32)
-    leaf_id = jnp.zeros(n, jnp.int32).at[row_ids].set(
-        out.pos_leaf[:n], unique_indices=True)
+    with jax.named_scope("lgbm.row_leaf"):
+        row_ids = out.data[:n, d_cols - 1].astype(jnp.int32)
+        leaf_id = jnp.zeros(n, jnp.int32).at[row_ids].set(
+            out.pos_leaf[:n], unique_indices=True)
     return (out.rec, out.rec_cat if has_cat else None,
             leaf_id, out.k, totals)
 
@@ -2390,35 +2396,61 @@ def fused_step_surface(step_impl, make_args, obj_keys):
     is never silently shadowed by a stale snapshot). `step.impl` and
     `step.obj_keys` are the contract surface for tests and tools
     (program-size pinning). On the same five arguments, `step.lower(...)`
-    lowers the program, `step.stage_map(...)` compiles it too and maps
-    its instructions to the `lgbm.<stage>` scopes (telemetry.stage_map),
+    lowers the program, `step.compiled_text(...)` is the text of the
+    compiled module, and four probes read it: `step.stage_map(...)`
+    maps its instructions to the `lgbm.<stage>` scopes
+    (telemetry.stage_map),
     `step.table_copies(...)` counts the copies of the packed table
     inside the compiled split loop, by computation
-    (telemetry.table_copies_in_split_loop), and
+    (telemetry.table_copies_in_split_loop),
     `step.hist_plane_elems(...)` sizes the one-hot plane the histogram
     products read, in elements a row
     (telemetry.hist_plane_elems_per_row; it feeds the gauge of that
     name), and `step.rank_pair_plane_elems(...)` the widest pair plane
     stored under a `rank_bucket_<L>` scope of `lgbm.gradients`, in
-    elements (telemetry.rank_pair_plane_elems; the gauge of that name:
-    0 where the compiler builds the ranking objective's planes inside
-    its fusions) — for a reader of a device trace and for tests, never
-    on the hot path. A warm persistent
-    compile cache hands back the executable as it was compiled, so a map
-    from a cache entry older than the scopes comes back empty."""
+    elements (telemetry.rank_pair_plane_elems: 0 where the compiler
+    builds the ranking objective's planes inside its fusions) — for a
+    reader of a device trace and for tests, never on the hot path. The
+    probes share one text per argument signature; on the arguments of
+    a call that has run, lowering and compiling are hits of JAX's own
+    caches and hand back the executable that ran (`GBDT.fused_stage_map`
+    keeps their events out of the compile counters and reports a miss).
+    A warm
+    persistent compile cache hands back the executable as it was
+    compiled, so a map from a cache entry older than the scopes comes
+    back without them: `step.missing_scopes(...)` names the stages the
+    lowered program has and the executable lacks
+    (telemetry.scopes_missing)."""
     def step(*args):
         return step_impl(*make_args(*args))
 
     def lower(*args):
         return step_impl.lower(*make_args(*args))
 
+    text = {}                 # {argument signature: compiled text}
+
+    def compiled_text(*args):
+        full = make_args(*args)
+        leaves, tree = jax.tree.flatten(full)
+        key = (tree, tuple((jax.typeof(x), getattr(x, "sharding", None))
+                           for x in leaves))
+        if key not in text:
+            compiled = step_impl.lower(*full).compile().as_text()
+            text.clear()
+            text[key] = compiled
+        return text[key]
+
     step.impl = step_impl
     step.obj_keys = obj_keys
     step.lower = lower
-    def compiled_text(*args):
-        return lower(*args).compile().as_text()
-
+    step.compiled_text = compiled_text
     step.stage_map = lambda *args: telemetry.stage_map(compiled_text(*args))
+
+    def missing_scopes(*args):
+        return telemetry.scopes_missing(
+            lower(*args).as_text(debug_info=True), compiled_text(*args))
+
+    step.missing_scopes = missing_scopes
     step.table_copies = lambda *args: telemetry.table_copies_in_split_loop(
         compiled_text(*args))
 
@@ -2428,13 +2460,8 @@ def fused_step_surface(step_impl, make_args, obj_keys):
         return elems
 
     step.hist_plane_elems = hist_plane_elems
-
-    def rank_pair_plane_elems(*args):
-        elems = telemetry.rank_pair_plane_elems(compiled_text(*args))
-        telemetry.counters.set_gauge("rank_pair_plane_elems", elems)
-        return elems
-
-    step.rank_pair_plane_elems = rank_pair_plane_elems
+    step.rank_pair_plane_elems = lambda *args: \
+        telemetry.rank_pair_plane_elems(compiled_text(*args))
     return step
 
 
@@ -3554,15 +3581,17 @@ class DeviceTreeLearner:
                     jnp.take(g, bag_idx), jnp.take(h, bag_idx),
                     jnp.ones((bag_k,), jnp.float32), base_mask,
                     *meta, tree_key, **grow_kw, **statics)
-                leaf_o = route_rows_by_rec(
-                    jnp.take(codes_pack, oob_idx, axis=0), rec, k,
-                    self.f_numbins, self.f_missing, self.f_default,
-                    self.f_col, self.f_base, self.f_elide,
-                    item_bits=self.item_bits, num_leaves=L,
-                    rec_cat=rec_cat, f_categorical=self.f_categorical)
-                leaf_id = jnp.zeros(n, jnp.int32) \
-                    .at[bag_idx].set(leaf_b, unique_indices=True) \
-                    .at[oob_idx].set(leaf_o, unique_indices=True)
+                # the out-of-bag rows' leaves, and every row's in place
+                with jax.named_scope("lgbm.row_leaf"):
+                    leaf_o = route_rows_by_rec(
+                        jnp.take(codes_pack, oob_idx, axis=0), rec, k,
+                        self.f_numbins, self.f_missing, self.f_default,
+                        self.f_col, self.f_base, self.f_elide,
+                        item_bits=self.item_bits, num_leaves=L,
+                        rec_cat=rec_cat, f_categorical=self.f_categorical)
+                    leaf_id = jnp.zeros(n, jnp.int32) \
+                        .at[bag_idx].set(leaf_b, unique_indices=True) \
+                        .at[oob_idx].set(leaf_o, unique_indices=True)
             elif use_compact:
                 rec, rec_cat, leaf_id, k, _ = grow(
                     codes_pack, codes_row, g, h, w, base_mask,

@@ -301,6 +301,7 @@ class GBDT:
         self._last_leaf_ids: Dict[int, Any] = {}
         self._last_leaf_ids_iter = -1
         self._fused_step = None
+        self._fused_last = None
         self._class_need_train = [
             self.objective.class_need_train(k) if self.objective else True
             for k in range(self.num_class)]
@@ -530,6 +531,7 @@ class GBDT:
                 self._fused_step[fkey] = self.learner.make_fused_step(
                     self.objective, goss=goss_params, bagging=bagging)
         fused_step = self._fused_step[fkey]
+        self._fused_last = fkey
         with telem.phase("feature_mask"):     # host work only
             rng = np.random.RandomState(
                 (cfg.feature_fraction_seed + self.iter) % (2**31 - 1))
@@ -626,6 +628,46 @@ class GBDT:
             self.score_updater.score = score_before.at[0].set(new_score)
         self.iter += 1
         return False
+
+    def fused_stage_map(self) -> dict:
+        """{instruction name: (stage, rung)} of the tree program this
+        booster's last fused iteration ran (telemetry.stage_map): what a
+        profiler trace of the run is read by stage with
+        (`benchmark/stage_join.py`). Lowers and compiles the step again
+        on arguments made as that iteration made its own, which JAX's
+        caches answer with the executable that ran; the compile events
+        this fires are kept out of `counters.compile_events()` and
+        `compile_seconds()` (`counters.uncounted`). For after a traced
+        window, never inside one. {} where no fused iteration has run;
+        and, with a warning, where the caches missed (a lowering or a
+        backend compile fired: the probe cost a compile, and its
+        executable is not the one that ran) or the executable came from
+        a persistent compile cache entry older than one of the
+        program's stage scopes (its map would read them as unstaged)."""
+        if not self._fused_step or self._fused_last not in self._fused_step:
+            return {}
+        step = self._fused_step[self._fused_last]
+        fmask = self.learner._feature_mask(np.random.RandomState(0))
+        with telemetry.counters.uncounted() as fired:
+            args = (self.score_updater.score[0], jnp.asarray(fmask),
+                    jax.random.PRNGKey(0), jax.random.PRNGKey(0),
+                    jnp.float32(self.shrinkage_rate))
+            missing = step.missing_scopes(*args)
+            stage_of = {} if missing else step.stage_map(*args)
+        misses = sorted({e for e in fired if "backend_compile" in e
+                         or "to_mlir_module" in e})
+        if misses:
+            log.warning(
+                "fused_stage_map: JAX's caches did not hold the tree "
+                "program that ran (%s fired, counted nowhere); no map",
+                ", ".join(misses))
+            return {}
+        if missing:
+            log.warning(
+                "fused_stage_map: the tree program that ran carries no "
+                "%s scope (a persistent compile cache entry compiled "
+                "before it); no map", ", ".join(sorted(missing)))
+        return stage_of
 
     def _fused_goss(self):
         """GOSS sampling parameters for the fused step; None for plain

@@ -699,13 +699,10 @@ class LambdarankNDCG(Objective):
 
         from ..telemetry import counters
         counters.set_gauge("rank_queries", self.num_queries)
-        counters.set_gauge("rank_queries_without_pairs",
-                           int(np.sum(~can_pair)))
         counters.set_gauge("rank_buckets", len(self._buckets))
         counters.set_gauge("rank_pair_positions_real",
                            int(np.sum(counts[can_pair] ** 2)))
         counters.set_gauge("rank_pair_positions_evaluated", evaluated)
-        counters.set_gauge("rank_pair_slice_elems", PAIR_SLICE_ELEMS)
         self._grad_fn = jax.jit(self._gradients_impl)
 
     def device_buffer_names(self):

@@ -18,8 +18,9 @@ the bookkeeping on top:
 
 Inside the tree program the stages are `jax.named_scope("lgbm.<stage>")`
 (`STAGES`); `stage_map(hlo_text)` maps a compiled module's instructions
-to them, which is how a device trace (whose events carry instruction
-names and nothing else) is read by stage, and
+to them (the compiler's own by rules): the map a device trace, whose
+events carry instruction names and nothing else, is read by stage with
+(`benchmark/stage_join.py`, `tools/stage_table.py`). And
 `table_copies_in_split_loop(hlo_text)` counts the copies of the packed
 table the compiler left inside the split loop (none, while the table
 is updated in place), and `hist_plane_elems_per_row(hlo_text)` sizes the
@@ -63,6 +64,7 @@ __all__ = ["counters", "recorder", "spans", "span", "events", "watchdogs",
            "telemetry_summary", "phase_breakdown", "prometheus_text",
            "record_iteration", "reset", "xla_trace_active",
            "note_grow_dispatches", "STAGES", "stage_map",
+           "scopes_missing",
            "table_copies_in_split_loop", "hist_plane_elems_per_row",
            "rank_pair_plane_elems"]
 
@@ -76,7 +78,7 @@ _mode = "off"
 # the compiled computation nor the persistent compile cache's key.
 STAGES = ("gradients", "root_hist", "leaf_select", "go_left", "partition",
           "table_update", "child_hist", "split_scan", "split_epilogue",
-          "score_update")
+          "row_leaf", "score_update")
 
 _HLO_INSTRUCTION = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\bop_name=\"([^\"]*)\"", re.M)
@@ -84,23 +86,153 @@ _STAGE_IN_OP_NAME = re.compile(r"lgbm\.(\w+)")
 _RUNG_IN_OP_NAME = re.compile(r"\brung_(\d+)")
 
 
+def _balanced(text: str, at: int) -> int:
+    """Index just past the parenthesis that closes the one at `at`."""
+    depth = 0
+    for i in range(at, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if not depth:
+                return i + 1
+    return len(text)
+
+
+_HLO_NAME = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_HLO_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r"\bop_name=\"([^\"]*)\"")
+# not conduits of a stage: a parameter is the computation's input, a
+# tuple gathers the results of every stage
+_NO_CONDUIT = ("parameter", "tuple")
+_NOT_A_SOURCE = _NO_CONDUIT + ("constant",)
+
+
+def _instructions(hlo_text: str) -> Dict[str, dict]:
+    """{instruction name: its opcode, operands, called computations,
+    `op_name` (None where the compiler made it) and computation} over a
+    module's text."""
+    out = {}
+    for comp, lines in _computations(hlo_text).items():
+        for line in lines:
+            found = _HLO_NAME.match(line)
+            if not found:
+                continue
+            rest = line[found.end():]
+            at = _balanced(rest, 0) if rest.startswith("(") \
+                else rest.find(" ")
+            opcode = _HLO_OPCODE.match(rest, max(at, 0))
+            if not opcode:
+                continue
+            close = _balanced(rest, opcode.end() - 1)
+            attrs = rest[close:]
+            called = []
+            for one, many in _HLO_CALLED.findall(attrs):
+                called.extend([one] if one else
+                              [c.strip().lstrip("%") for c in many.split(",")])
+            op_name = _HLO_OP_NAME.search(attrs)
+            out[found.group(1)] = {
+                "op": opcode.group(1),
+                "operands": _HLO_OPERAND.findall(rest[opcode.end():close]),
+                "called": called, "comp": comp,
+                "op_name": op_name.group(1) if op_name else None}
+    return out
+
+
+def _one_stage(names, out):
+    """The (stage, rung) all of `names` have and agree on, else None."""
+    if not names or any(n not in out for n in names):
+        return None
+    found = {out[n] for n in names}
+    stages = {stage for stage, _ in found}
+    if len(stages) != 1:
+        return None
+    return found.pop() if len(found) == 1 else (stages.pop(), None)
+
+
 def stage_map(hlo_text: str) -> Dict[str, Tuple[str, Optional[int]]]:
     """{instruction name: (stage, rung or None)} of a compiled module's
-    text (`jit(f).lower(...).compile().as_text()`): the instructions
-    whose `op_name` runs through a `lgbm.<stage>` scope, under the
-    innermost such scope, with the index of the enclosing `rung_<r>`
-    scope (the compact core's window ladder) as the rung. A device
-    trace names its "XLA Ops" events by the instruction's text, which
-    starts with this name; that is the join. Instructions the compiler
-    made itself (copies round a `while` or `conditional`) carry no
-    `op_name` and are not in the map."""
+    text (`jit(f).lower(...).compile().as_text()`). A device trace names
+    its "XLA Ops" events by the instruction's text, which starts with
+    this name; that is the join. The rules, in order:
+
+    1. Scope: an instruction whose `op_name` runs through a
+       `lgbm.<stage>` scope takes the innermost such scope, and the
+       index of the enclosing `rung_<r>` scope (the compact core's
+       window ladder) as the rung.
+    2. The compiler's own instructions (copies round a `while`, the
+       memory-space prefetches `copy-start` / `slice-start`, their
+       `*-done`, bitcasts, the `ConcatBitcast` that joins slices, the
+       pieces it cuts a running sum into), which carry no `op_name` or
+       one of the compiler's own with no scope path (`reduce_window_sum`):
+       the stage all their operands' producers have and agree on,
+       else the one all their users have and agree on (so an
+       asynchronous `*-done` takes its `*-start`'s, and a `*-start` of
+       a parameter the `*-done`'s). Parameters, tuples and constants are
+       left out of both sides: they carry no stage across.
+    3. An instruction of a computation that only instructions of one
+       stage call (the body of a staged `while`, a staged
+       `conditional`'s branches, a fusion's computation) takes that
+       stage.
+
+    Rules 2 and 3 repeat until nothing changes. An instruction none of
+    them reaches (the split loop's own `while`, its counter, the work
+    between the stages' scopes) is not in the map: a reader counts it
+    as unstaged."""
+    instrs = _instructions(hlo_text)
     out = {}
-    for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
-        stages = _STAGE_IN_OP_NAME.findall(op_name)
+    for name, ins in instrs.items():
+        stages = _STAGE_IN_OP_NAME.findall(ins["op_name"] or "")
         if stages:
-            rung = _RUNG_IN_OP_NAME.findall(op_name)
+            rung = _RUNG_IN_OP_NAME.findall(ins["op_name"])
             out[name] = (stages[-1], int(rung[-1]) if rung else None)
+    users, callers = {}, {}
+    for name, ins in instrs.items():
+        for operand in ins["operands"]:
+            users.setdefault(operand, []).append(name)
+        for comp in ins["called"]:
+            callers.setdefault(comp, []).append(name)
+    made = [name for name, ins in instrs.items()
+            if "/" not in (ins["op_name"] or "")
+            and ins["op"] not in _NO_CONDUIT]
+
+    def conduits(names):
+        return [n for n in names
+                if instrs.get(n, {}).get("op") not in _NOT_A_SOURCE]
+
+    changed = True
+    while changed:
+        changed = False
+        for name in made:
+            if name in out:
+                continue
+            got = (_one_stage(conduits(instrs[name]["operands"]), out)
+                   or _one_stage(conduits(users.get(name, ())), out))
+            if got:
+                out[name] = got
+                changed = True
+        for name, ins in instrs.items():
+            if name in out or ins["op"] == "parameter":
+                continue
+            got = _one_stage(callers.get(ins["comp"], ()), out)
+            if got and all(c in out for c in callers[ins["comp"]]):
+                out[name] = got
+                changed = True
     return out
+
+
+def scopes_missing(lowered_text: str, compiled_text: str) -> set:
+    """The stages whose scopes the lowered module names
+    (`lower(...).as_text(debug_info=True)`) and no instruction of the
+    compiled one carries. Scopes are no part of the persistent compile
+    cache's key, so a cache entry compiled from a source older than a
+    scope hands back an executable without it, whose map would read
+    the stage as unstaged; empty where the executable is the source's
+    own."""
+    def named(text):
+        return set(_STAGE_IN_OP_NAME.findall(text)) & set(STAGES)
+    return named(lowered_text) - named(compiled_text)
 
 
 _HLO_CALLED = re.compile(

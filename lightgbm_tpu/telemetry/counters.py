@@ -25,13 +25,14 @@ over it.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from typing import Dict, List, Optional
 
 __all__ = ["incr", "add_seconds", "set_gauge", "get", "is_active",
            "set_active", "snapshot", "reset", "install_compile_listener",
-           "compile_events", "compile_seconds",
+           "compile_events", "compile_seconds", "uncounted",
            "compile_seconds_by_function", "peak_rss_bytes",
            "prometheus_text"]
 
@@ -84,10 +85,16 @@ _compile_events: List[str] = []
 _compile_seconds: Dict[str, float] = {}
 _compile_seconds_by_fun: Dict[str, Dict[str, float]] = {}
 _listener_state = {"installed": False, "available": True}
+_held: List[List[str]] = []        # one list an open `uncounted()`
 
 
 def _on_duration_event(name: str, *args, **kw) -> None:
     if "compile" not in name:
+        return
+    if _held:
+        with _lock:
+            for held in _held:
+                held.append(name)
         return
     secs = float(args[0]) if args else 0.0
     fun = kw.get("fun_name")     # JAX names the jitted function on its
@@ -115,6 +122,27 @@ def install_compile_listener() -> bool:
     except ImportError:
         _listener_state["available"] = False
         return False
+
+
+@contextlib.contextmanager
+def uncounted():
+    """Compile events inside are kept out of `compile_events()` and
+    `compile_seconds()`, and handed back instead: the block's value is
+    the list of their names. For a probe of a program that has already
+    run (`GBDT.fused_stage_map`), which must not add to the counters of
+    the run it looks at, and which reports a lowering or a compile it
+    did not expect. Installs the listener. Process-wide, like the
+    listener: a compile on another thread meanwhile lands in the list
+    too."""
+    install_compile_listener()
+    held: List[str] = []
+    with _lock:
+        _held.append(held)
+    try:
+        yield held
+    finally:
+        with _lock:
+            _held[:] = [h for h in _held if h is not held]
 
 
 def compile_events() -> List[str]:

@@ -116,19 +116,23 @@ def test_the_plan_counts_what_it_lays_out(small_slices):
     hi = np.maximum.reduceat(y, bounds[:-1])
     pairs = (counts > 1) & (hi > lo)
     assert counters.get("rank_queries") == len(counts)
-    assert counters.get("rank_queries_without_pairs") == np.sum(~pairs) >= 3
+    # the rows of a query that can give no pair point past every
+    # bucket's output, at the trailing zero
+    filled = sum(L * per * slices for L, per, slices in obj._buckets)
+    no_pairs = np.asarray(obj._rank_row_pos)[bounds[:-1]] == filled
+    assert (no_pairs == ~pairs).all() and np.sum(no_pairs) >= 3
     assert counters.get("rank_buckets") == len(obj._buckets)
     assert counters.get("rank_pair_positions_real") \
         == np.sum(counts[pairs] ** 2)
     assert counters.get("rank_pair_positions_evaluated") \
         == sum(L * L * per * slices for L, per, slices in obj._buckets)
-    assert counters.get("rank_pair_slice_elems") == SLICE_ELEMS
     for L, per, slices in obj._buckets:
         # a slice's live pair plane stays under the constant (a query
         # longer than its square root is a slice of its own)
         assert per * L * L <= max(SLICE_ELEMS, L * L)
         members = np.sum(pairs & (np.array(
             [objective_mod.bucket_length(c) for c in counts]) == L))
+        assert per == min(members, max(1, SLICE_ELEMS // (L * L)))
         assert (slices - 1) * per < members <= slices * per
     # every buffer is a named jit argument, whatever its size
     names = obj.device_buffer_names()

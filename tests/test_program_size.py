@@ -109,10 +109,8 @@ def test_lambdarank_buckets_are_parameters_and_tables_share_a_program():
     assert obj._rank_start_1024.size == 1       # far under 256 elements
     # and the compiled step stores no pair plane over the live bound
     from lightgbm_tpu.objectives.objective import PAIR_SLICE_ELEMS
-    from lightgbm_tpu.telemetry import counters
     plane = step.rank_pair_plane_elems(*args)
     assert 0 < plane <= PAIR_SLICE_ELEMS
-    assert counters.get("rank_pair_plane_elems") == plane
     assert 3000 * 16 * 16 <= PAIR_SLICE_ELEMS     # one slice holds them
 
 

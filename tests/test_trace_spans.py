@@ -151,9 +151,76 @@ ENTRY %main (a: f32[4]) -> f32[4] {
 
 
 def test_stage_map_reads_innermost_scope_and_rung():
+    # (`copy.7`, the compiler's, takes its one user's stage and rung)
     assert telemetry.stage_map(HLO_TOY) == {
         "scatter.9": ("partition", 2), "fusion.3": ("partition", 2),
-        "add.1": ("split_scan", None)}
+        "add.1": ("split_scan", None), "copy.7": ("partition", 2)}
+
+
+# the compiler's own instructions round staged ones: an asynchronous
+# copy of a staged result, a bare copy, a prefetch of a parameter for a
+# staged user, a staged loop whose body holds a copy and a counter, and
+# what no rule reaches (the split loop, an unscoped add, and a running
+# sum's piece between two stages)
+HLO_RULES = """HloModule jit_step_impl, entry_computation_layout={()->f32[8]}
+
+%tile_body (p: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %p = (s32[], f32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %acc = f32[8]{0} get-tuple-element(%p), index=1
+  %copy.3 = f32[8]{0:T(128)} copy(%acc)
+  %one = s32[] constant(1)
+  %next = s32[] add(%i, %one)
+  ROOT %tuple.5 = (s32[], f32[8]{0}) tuple(%next, %copy.3)
+}
+
+%tile_cond (p.1: (s32[], f32[8])) -> pred[] {
+  %p.1 = (s32[], f32[8]{0}) parameter(0)
+  %i.1 = s32[] get-tuple-element(%p.1), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%i.1, %n), direction=LT
+}
+
+%split_body (p.2: (s32[], f32[8])) -> (s32[], f32[8]) {
+  ROOT %p.2 = (s32[], f32[8]{0}) parameter(0)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %zero = s32[] constant(0)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fc.1, metadata={op_name="jit(step_impl)/while/body/lgbm.table_update/select_n"}
+  %copy-start.2 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(%fusion.1)
+  %copy-done.2 = f32[8]{0:S(1)} copy-done(%copy-start.2)
+  %copy.4 = f32[8]{0:T(256)} copy(%fusion.1)
+  %copy-start.5 = (f32[8]{0:S(1)}, f32[8]{0}, u32[]{:S(2)}) copy-start(%a)
+  %copy-done.5 = f32[8]{0:S(1)} copy-done(%copy-start.5)
+  %fusion.6 = f32[8]{0} fusion(%copy-done.5, %copy.4), kind=kLoop, calls=%fc.2, metadata={op_name="jit(step_impl)/lgbm.score_update/mul"}
+  %init = (s32[], f32[8]{0}) tuple(%zero, %copy-done.2)
+  %while.7 = (s32[], f32[8]{0}) while(%init), condition=%tile_cond, body=%tile_body, metadata={op_name="jit(step_impl)/while/body/lgbm.leaf_select/rung_1/while/body/lgbm.partition/while"}
+  %while.8 = (s32[], f32[8]{0}) while(%while.7), condition=%lt.0, body=%split_body, metadata={op_name="jit(step_impl)/while"}
+  %out = f32[8]{0} get-tuple-element(%while.8), index=1
+  %add.9 = f32[8]{0} add(%out, %fusion.6), metadata={op_name="jit(step_impl)/add"}
+  ROOT %piece.10 = f32[8]{0} fusion(%add.9, %fusion.6), kind=kLoop, calls=%fc.3, metadata={op_name="reduce_window_sum"}
+}
+"""
+
+
+@pytest.mark.parametrize("name,stage", [
+    ("fusion.1", ("table_update", None)),     # rule 1: its own scope
+    ("copy-start.2", ("table_update", None)),  # rule 2: its producer's
+    ("copy-done.2", ("table_update", None)),   # ... its `*-start`'s
+    ("copy.4", ("table_update", None)),        # a bare copy: its producer's
+    ("copy-start.5", ("score_update", None)),  # a parameter's: the
+    ("copy-done.5", ("score_update", None)),   # `*-done`'s user's
+    ("copy.3", ("partition", 1)),   # rule 3: the staged loop's body
+    ("next", ("partition", 1)),     # and its counter
+    ("lt", ("partition", 1)),       # and its condition
+    ("while.8", None),              # the split loop: no rule reaches it
+    ("add.9", None),                # a scope path with no stage
+    ("piece.10", None),             # the compiler's, between two stages
+    ("a", None)])                   # a parameter carries none
+def test_stage_map_attributes_the_compilers_own_instructions(name, stage):
+    assert telemetry.stage_map(HLO_RULES).get(name) == stage
 
 
 # the split loop (the one `while` under no stage) with the packed table
@@ -320,6 +387,90 @@ def test_fused_step_stage_map_covers_the_split_loop(monkeypatch):
     assert not unstaged, "\n".join(unstaged)
 
 
+def test_stage_map_probe_is_counted_nowhere_and_runs_only_when_asked(
+        monkeypatch):
+    """Training reads no compiled text. The booster's probe, after it,
+    answers from JAX's caches with the executable that ran (no lowering
+    to a module, no backend compile), adds nothing to the compile
+    counters, maps the new `row_leaf` stage, and shares its one text
+    with the step's other probes."""
+    from jax._src import monitoring
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    texts = []
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(jax.stages.Compiled, "as_text",
+                        lambda self: texts.append(1) or as_text(self))
+    bst = _booster(n=10000)
+    gbdt = bst._gbdt
+    assert gbdt.fused_stage_map() == {}          # no fused iteration yet
+    for _ in range(3):
+        bst.update()
+    _ = gbdt.models
+    assert texts == []
+    events, seconds = len(counters.compile_events()), \
+        counters.compile_seconds()
+    seen = []
+
+    def listen(name, *args, **kw):
+        seen.append(name)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        stage_of = gbdt.fused_stage_map()
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert not [e for e in seen if "backend_compile" in e
+                or "to_mlir_module" in e], seen
+    assert len(counters.compile_events()) == events
+    assert counters.compile_seconds() == seconds
+    assert "row_leaf" in {stage for stage, _ in stage_of.values()}
+    step = gbdt._fused_step[gbdt._fused_last]
+    step.table_copies(*_step_args(gbdt))
+    step.hist_plane_elems(*_step_args(gbdt))
+    assert len(texts) == 1
+
+
+def test_a_probe_the_caches_miss_says_so_and_gives_no_map(monkeypatch):
+    """Where JAX's caches no longer hold the step that ran, the probe
+    lowers and compiles it anew: that costs a compile, counted nowhere,
+    so it is reported (a warning naming the events) and no map comes
+    back; the compile counters stay as they were."""
+    from lightgbm_tpu.models import gbdt as gbdt_mod
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    warned = []
+    monkeypatch.setattr(gbdt_mod.log, "warning",
+                        lambda msg, *a: warned.append(msg % a))
+    bst = _booster(n=10000)
+    bst.update()
+    _ = bst._gbdt.models
+    jax.clear_caches()
+    events, seconds = len(counters.compile_events()), \
+        counters.compile_seconds()
+    assert bst._gbdt.fused_stage_map() == {}
+    assert len(counters.compile_events()) == events
+    assert counters.compile_seconds() == seconds
+    assert len(warned) == 1 and "backend_compile" in warned[0], warned
+
+
+def test_a_map_older_than_a_scope_is_no_map(monkeypatch):
+    """An executable whose text lacks a stage the lowered program names
+    (a persistent cache entry compiled before the scope existed: the
+    cache's key leaves scopes out) gives no map, so that no reader
+    counts the stage as unstaged."""
+    lowered = 'loc("jit(step_impl)/lgbm.row_leaf/scatter") lgbm.partition'
+    assert telemetry.scopes_missing(lowered, HLO_TOY) == {"row_leaf"}
+    assert telemetry.scopes_missing(lowered.replace("row_leaf", "split_scan"),
+                                    HLO_TOY) == set()
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    as_text = jax.stages.Compiled.as_text
+    monkeypatch.setattr(
+        jax.stages.Compiled, "as_text",
+        lambda self: as_text(self).replace("lgbm.row_leaf", "older"))
+    bst = _booster(n=10000)
+    bst.update()
+    assert bst._gbdt.fused_stage_map() == {}
+
+
 # ---------------------------------------------------------------------------
 # (c) the name the benchmark finds the tree program by
 
@@ -400,3 +551,30 @@ def test_retrace_is_named_in_compile_seconds_by_function():
     assert all(after[k] > before[k] for k in before)
     totals = counters.compile_seconds()
     assert all(after[k] <= totals[k] + 1e-9 for k in after)
+
+
+def test_a_compile_inside_uncounted_is_counted_nowhere():
+    counters.install_compile_listener()
+
+    @jax.jit
+    def uncounted_probe(v):
+        return v * 3 - 1
+
+    events, seconds = len(counters.compile_events()), \
+        counters.compile_seconds()
+    with counters.uncounted() as fired:
+        uncounted_probe(jnp.ones(4))
+    # handed back, not dropped
+    assert any("backend_compile" in e for e in fired), fired
+    assert len(counters.compile_events()) == events
+    assert counters.compile_seconds() == seconds
+    assert "uncounted_probe" not in counters.compile_seconds_by_function()
+    # nested blocks each hold what fired while they were open
+    with counters.uncounted() as outer:
+        with counters.uncounted() as inner:
+            uncounted_probe(jnp.ones(5))
+        uncounted_probe(jnp.ones(7))
+    assert inner and outer[:len(inner)] == inner and len(outer) > len(inner)
+    assert len(counters.compile_events()) == events
+    uncounted_probe(jnp.ones(6))          # a new shape, outside: counted
+    assert len(counters.compile_events()) > events
