@@ -46,6 +46,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 from ..config import Config
 from ..io.binning import BIN_CATEGORICAL
@@ -57,6 +59,7 @@ from ..ops import split as split_ops
 from ..ops.fused import run_once_if, run_split_loop
 from ..ops.partition import decide_left
 from ..ops.pallas.histogram_kernel import build_histogram_pallas_t
+from ..ops.pallas import partition_kernel
 from .. import telemetry
 from ..telemetry import spans as telem_spans
 from ..telemetry import recorder as telem
@@ -2261,7 +2264,9 @@ def make_sliced_search(*, axis_name, fp, D, c_cols, col_bins, item_bits,
 # 14.0 and 14.4, then 90.6 and 119.2); a 2**22-row window in tiles of
 # 2**13 / 14 / 15 / 16 / 17 / 18 rows 7.3 / 6.4 / 8.4 / 8.2 / 11.8 / 12.8;
 # the tree program's own rungs, untiled, 6.8 at 2**16 rows, 10.3 at 2**17,
-# 11.8 at 2**18.
+# 11.8 at 2**18. On the TPU a tile goes through the Pallas kernel instead
+# (`_partition_tile`), which holds the tile's output in VMEM: 2**16 rows
+# keep it inside its budget up to 80 words a row.
 SCATTER_TILE_ROWS = 1 << 16
 
 
@@ -2293,10 +2298,56 @@ def _scan_partition(win: jax.Array, key3: jax.Array):
             n0, n1)
 
 
+def _partition_tile_tpu(tile: jax.Array, key3: jax.Array):
+    """`_partition_tile`'s lowering for the TPU: the Pallas lane
+    compaction where `partition_kernel.engaged` for the tile's shape,
+    else the scan."""
+    if not partition_kernel.engaged("tpu", *tile.shape):
+        return _scan_partition(tile, key3)
+    s, c0, c1 = partition_kernel.partition_tile(_rows_minor(tile), key3)
+    return _rows_minor(s), c0, c1
+
+
+def _partition_tile_eager(tile: jax.Array, key3: jax.Array):
+    """`_partition_tile` run op by op (`jax.disable_jit`): by the platform
+    of the device that holds the tile."""
+    if next(iter(tile.devices())).platform == "tpu":
+        return _partition_tile_tpu(tile, key3)
+    return _scan_partition(tile, key3)
+
+
+# One primitive, lowered per platform: the kernel is traced only when
+# the program is lowered for the TPU (`lax.platform_dependent` traces
+# every branch, and the kernel's trace would cost each CPU lowering of
+# a rung ~0.3 s). The rules look their functions up when they run.
+_partition_tile_p = Primitive("lgbm_partition_tile")
+_partition_tile_p.multiple_results = True
+_partition_tile_p.def_abstract_eval(
+    lambda tile, key3: (tile.update(),) + 2 * (key3.update(
+        shape=(), dtype=jnp.dtype(jnp.int32), weak_type=False),))
+_partition_tile_p.def_impl(lambda *a: _partition_tile_eager(*a))
+mlir.register_lowering(_partition_tile_p,
+                       mlir.lower_fun(lambda *a: _scan_partition(*a)))
+mlir.register_lowering(_partition_tile_p,
+                       mlir.lower_fun(lambda *a: _partition_tile_tpu(*a)),
+                       platform="tpu")
+
+
+def _partition_tile(tile: jax.Array, key3: jax.Array):
+    """`_scan_partition` of one tile (key-2 rows its tail), by the Pallas
+    lane compaction (ops/pallas/partition_kernel.py) where the program is
+    lowered for the TPU and the kernel takes the tile's shape, and by the
+    scan elsewhere. The platform is the lowering's, which is that of the
+    device that holds the packed table: a Mosaic kernel does not run on
+    the CPU backend, and the scan is the kernel's oracle there."""
+    return tuple(_partition_tile_p.bind(tile, key3))
+
+
 def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
                           tile_rows: int) -> jax.Array:
     """_scan_partition of a window of more than `tile_rows` rows with no
-    scatter larger than a tile: each tile is partitioned alone, and its
+    scatter larger than a tile: each tile is partitioned alone
+    (`_partition_tile`: on the TPU by the Pallas kernel), and its
     class-0 and class-1 pieces are appended at running offsets by masked
     whole-tile read-modify-writes (as the chunk core's pass_b places its
     lefts). PRECONDITION: the key-2 rows are the window's tail
@@ -2319,7 +2370,7 @@ def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
         j = jnp.arange(size, dtype=jnp.int32)
         tile = _rows_minor(jax.lax.dynamic_slice(win, (start, 0),
                                                  (size, d)))
-        s, c0, c1 = _scan_partition(
+        s, c0, c1 = _partition_tile(
             tile, jax.lax.dynamic_slice(key3, (start,), (size,)))
         s = _rows_minor(s)
         # [0, c0) of the sorted tile to out[o0:], then [c0, c0 + c1) to
@@ -2345,18 +2396,19 @@ def _scan_partition_tiled(win: jax.Array, key3: jax.Array,
 def partition_window(win: jax.Array, key3: jax.Array,
                      tile_rows: Optional[int] = None) -> jax.Array:
     """Stable 3-way reorder of a (W, D) u32 window by key3 in {0,1,2}
-    (reference DataPartition::Split role), the compact core's one
-    partition on every platform: per-class exclusive ranks via cumsum +
-    one row scatter (no sort passes), tile by tile where the window
-    holds more than SCATTER_TILE_ROWS rows, and then only for key-2 rows
-    that are the window's tail (see _scan_partition_tiled). The chunk
+    (reference DataPartition::Split role), the compact core's partition:
+    tile by tile where the window holds more than SCATTER_TILE_ROWS rows,
+    and then only for key-2 rows that are the window's tail (see
+    _scan_partition_tiled), each tile by `_partition_tile` (the Pallas
+    lane compaction on the TPU, per-class exclusive ranks via cumsum +
+    one row scatter elsewhere; no sort passes either way). The chunk
     core sorts its fixed chunks instead (pass B, with the reading that
     keeps it). `tile_rows` is for tests."""
     if tile_rows is None:
         tile_rows = SCATTER_TILE_ROWS
     if win.shape[0] > tile_rows:
         return _scan_partition_tiled(win, key3, tile_rows)
-    return _scan_partition(win, key3)[0]
+    return _partition_tile(win, key3)[0]
 
 
 @jax.named_scope("lgbm.go_left")
@@ -3391,6 +3443,12 @@ class DeviceTreeLearner:
                       * self.table_row_bytes)
         return {"mode": "resident", "bytes": int(total)}
 
+    def _table_platform(self) -> str:
+        """Platform of the device that holds the packed table: the one the
+        tree program is lowered for, which decides whether its partition
+        runs the Pallas kernel (`_partition_tile`)."""
+        return next(iter(self.codes_pack.devices())).platform
+
     def _count_partition_rows(self, rec) -> None:
         """Program counters of how often the tiled partition engages:
         `partition_rows`, the parent rows (by the split records' counts)
@@ -3399,9 +3457,14 @@ class DeviceTreeLearner:
         ladder that holds this device's share of the parent — is more
         than one scatter tile. The chunk core sorts its chunks and the
         masked core moves no rows: neither tiles, and the masked core
-        counts nothing. Beside them, for the compact core, what its
-        rungs' step loops ran and what the splits needed, in rows:
-        `rung_rows_run` and `rung_rows_needed` (`rung_rows`)."""
+        counts nothing. Beside them, for the compact core: what its
+        rungs' step loops ran and what the splits needed, in rows
+        (`rung_rows_run` and `rung_rows_needed`, `rung_rows`); and
+        `partition_kernel_rows`, the parent rows of the splits whose
+        tiles the Pallas kernel partitions: `partition_kernel.engaged`
+        for the table's platform, the rung's tile and the table's width,
+        the function `_partition_tile` calls. It mirrors that selection
+        from the host and does not observe the program take it."""
         if self.strategy == "masked":
             return
         parent = rec[:, R_LCNT].astype(np.float64) + rec[:, R_RCNT]
@@ -3416,6 +3479,12 @@ class DeviceTreeLearner:
         telemetry.counters.incr(
             "partition_tiled_rows",
             float(parent[rung > SCATTER_TILE_ROWS].sum()))
+        platform, words = self._table_platform(), self.table_row_bytes // 4
+        taken = np.array([partition_kernel.engaged(
+            platform, int(min(r, SCATTER_TILE_ROWS)), words) for r in rung],
+            bool)
+        telemetry.counters.incr("partition_kernel_rows",
+                                float(parent[taken].sum()))
         # exact on one device's full-data trees (the records' counts are
         # the rows); under sharding or bagging an estimate by the share
         from ..ops.histogram import resolve_chunk_size

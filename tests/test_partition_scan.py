@@ -1,7 +1,10 @@
 """The sort-free partition (cumsum ranks + one row scatter, tile by tile
-over SCATTER_TILE_ROWS) is the compact core's one partition on every
-platform: held here to a stable argsort's order, tiled and untiled, and
-to the trees of a core that moves no rows."""
+over SCATTER_TILE_ROWS) is the compact core's partition off the TPU:
+held here to a stable argsort's order, tiled and untiled, and to the
+trees of a core that moves no rows. On the TPU each tile goes through
+the Pallas lane compaction (ops/pallas/partition_kernel.py), held here
+in interpret mode to the scan, bit for bit, tile by tile and in the
+tiled window."""
 import functools
 
 import numpy as np
@@ -276,3 +279,117 @@ def test_rung_row_inflation_of_a_12m_row_tree_of_255_leaves():
             pcounts, lefts, 2 * lefts <= pcounts, ladder, 1 << 16, 8192,
             bounded)
         assert lo < run / needed < hi, (bounded, run / needed)
+
+
+@jax.jit
+def _kernel_jit(win, key3):
+    from lightgbm_tpu.ops.pallas.partition_kernel import partition_tile
+    return partition_tile(win, key3, interpret=True)
+
+
+@functools.lru_cache(maxsize=4)
+def _random_tile(rows, words):
+    rng = np.random.RandomState(rows * 64 + words)
+    return jnp.asarray(rng.randint(0, 2**32, size=(rows, words),
+                                   dtype=np.uint64).astype(np.uint32))
+
+
+def _tile_keys(pattern, tail, rows):
+    """key3 of one tile: the leaf's rows by `pattern` (0 left, 1 right),
+    then a key-2 tail of `tail` of the tile (the rows past the leaf's
+    count, which both callers make the tile's tail)."""
+    rng = np.random.RandomState(rows + int(100 * tail))
+    i = np.arange(rows)
+    side = {"all_left": np.zeros(rows, bool),
+            "all_right": np.ones(rows, bool),
+            "alternating": i % 2 == 1,
+            "left_1pct": rng.rand(rows) >= 0.01,
+            "left_99pct": rng.rand(rows) >= 0.99,
+            # runs of 1 to 300 rows a side
+            "runs": np.repeat(np.arange(rows) % 2 == 1,
+                              rng.randint(1, 301, rows))[:rows]}[pattern]
+    live = rows - int(round(rows * tail))
+    return jnp.asarray(np.where(i < live, side.astype(np.int32), 2)
+                       .astype(np.int32))
+
+
+_PATTERNS = ["all_left", "all_right", "alternating", "left_1pct",
+             "left_99pct", "runs"]
+_WIDTHS = (3, 11, 21, 39)     # under a vreg's 8 sublanes, and over it
+# Each case reaches a path of its own: every key pattern at every width
+# on a ragged tile of three grid steps (5000 rows, padded); a tile under
+# one grid step (128 rows) and one of two whole steps (4096) at every
+# width; an all-key-2 tile (every pattern is then the same keys), once
+# a width under one step and on the ragged tile; and one whole 2**16-row
+# tile, since interpret mode copies the resident output at each of the
+# kernel's stores (the chip's own run, tools/microbench_partition.py,
+# holds 2**16-row tiles at 11, 21 and 39 words to the scan).
+_TILE_CASES = [(words, 5000, pattern, tail)
+               for words in _WIDTHS
+               for pattern in _PATTERNS
+               for tail in (0.0, 0.3)] \
+    + [(words, rows, pattern, 0.3)
+       for words in _WIDTHS
+       for rows in (128, 4096)
+       for pattern in ("alternating", "runs")] \
+    + [(words, rows, "all_left", 1.0)
+       for words in _WIDTHS
+       for rows in (128, 5000)] \
+    + [(11, 1 << 16, "runs", 0.3)]
+
+
+@pytest.mark.parametrize("words, rows, pattern, tail", _TILE_CASES)
+def test_partition_kernel_is_the_scan_bit_for_bit(words, rows, pattern,
+                                                  tail):
+    """The lane compaction in interpret mode against `_scan_partition`:
+    the same rows in the same order, the key-2 tail where it was, and
+    the same counts of classes 0 and 1."""
+    from lightgbm_tpu.models.device_learner import _scan_partition
+    win = _random_tile(rows, words)
+    key3 = _tile_keys(pattern, tail, rows)
+    got, c0, c1 = _kernel_jit(win, key3)
+    want, n0, n1 = jax.jit(_scan_partition)(win, key3)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (int(c0), int(c1)) == (int(n0), int(n1))
+
+
+@pytest.mark.parametrize("pattern", ["mixed_tail", "all_left", "empty_tail",
+                                     "ragged"])
+@pytest.mark.parametrize("w, t", [(8192, 2048),     # whole tiles
+                                  (12000, 4096)])   # and a ragged last one
+def test_tiled_partition_with_the_kernel_is_the_scan(monkeypatch, w, t,
+                                                     pattern):
+    """`_scan_partition_tiled` with the kernel (interpret mode) in its
+    tile step: the XLA path's window, bit for bit, full tiles, the
+    ragged last step and the tiles past the leaf's rows included."""
+    from lightgbm_tpu.models import device_learner as dl
+    from lightgbm_tpu.ops.pallas.partition_kernel import partition_tile
+    if pattern == "ragged":
+        w -= 37
+    rng = np.random.RandomState(w + t)
+    win = jnp.asarray(rng.randint(0, 2**32, size=(w, 11),
+                                  dtype=np.uint64).astype(np.uint32))
+    key3 = jnp.asarray(_keys(pattern, w, rng))
+    want = np.asarray(_partition_jit(win, key3, t))
+    tiles = []
+
+    def kernel(tile, key3):
+        tiles.append(tile.shape[0])
+        return partition_tile(tile, key3, interpret=True)
+
+    monkeypatch.setattr(dl, "_partition_tile", kernel)
+    got = jax.jit(lambda a, k: dl.partition_window(a, k, tile_rows=t))(
+        win, key3)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert sorted(set(tiles)) == sorted({t, w % t} - {0})
+
+
+def test_partition_off_the_tpu_is_the_scan():
+    """Lowered for the CPU, the partition holds no Mosaic kernel and is
+    the scan's row scatter: the kernel's branch is the TPU's alone."""
+    from lightgbm_tpu.models import device_learner as dl
+    txt = jax.jit(lambda w, k: dl.partition_window(w, k)).lower(
+        jax.ShapeDtypeStruct((4096, 11), jnp.uint32),
+        jax.ShapeDtypeStruct((4096,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" not in txt
+    assert " scatter(" in txt

@@ -1,9 +1,11 @@
-"""The tiled scan partition, compiled for a described TPU v5e (no chip
+"""The tiled partition, compiled for a described TPU v5e (no chip
 attached, nothing runs): what only the TPU's compiler decides and the
-chip then pays for (PERF.md §6, PR 27). Past 2**18 indices it takes the
-slow row scatter, so no scatter may be wider than one tile; and layout
-assignment would hand the tile's words-minor layout (512 B for a 44-byte
-row) to every window-sized buffer, so none may have it. And a buffer
+chip then pays for (PERF.md §6). Its tiles go through the
+Pallas lane compaction, so no row scatter of the row's width is left
+(the compiler sorts a scatter's indices and wants its operand
+words-minor); and layout assignment would hand a words-minor
+layout (512 B for a 44-byte row) to every window-sized buffer, so none
+may have it: the kernel reads the rows-minor tile as it lies. And a buffer
 that crosses a `conditional` is copied on its way in and out, so the
 packed table may cross none inside the split loop (PR 30). And the
 histogram's one-hot is factored, so no plane of F x 256 elements a row
@@ -22,35 +24,99 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("rows", [1 << 19,      # whole tiles
                                   300_000])     # a ragged last tile
-def test_tiled_partition_compiles_to_tile_sized_scatters(one_chip, rows):
+@pytest.mark.parametrize("d_cols", [11, 21, 39])  # `higgs`, `criteo`, `msltr`
+def test_tiled_partition_compiles_to_tile_sized_scatters(one_chip, rows,
+                                                         d_cols):
+    """Its tiles go through the Mosaic kernel, and no row scatter of the
+    row's width is left (with the scan: tile-sized scatters, none wider
+    than SCATTER_TILE_ROWS); every window-sized buffer stays rows-minor."""
     from lightgbm_tpu.models import device_learner as dl
-    d_cols = 11
+    from lightgbm_tpu.ops.pallas import partition_kernel
     assert rows > dl.SCATTER_TILE_ROWS
+    assert partition_kernel.engaged("tpu", dl.SCATTER_TILE_ROWS, d_cols)
     txt = jax.jit(lambda w, k: dl.partition_window(w, k)).lower(
         jax.ShapeDtypeStruct((rows, d_cols), jnp.uint32, sharding=one_chip),
         jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
     ).compile().as_text()
-    scatters = [int(m) for m in re.findall(
-        r"= u32\[(\d+),%d\]\{[^}]*\} scatter\(" % d_cols, txt)]
-    assert scatters and max(scatters) <= dl.SCATTER_TILE_ROWS, scatters
+    scatters = re.findall(r"= u32\[\d+,%d\]\{[^}]*\} scatter\(" % d_cols,
+                          txt)
+    assert not scatters, scatters
+    assert 'custom_call_target="tpu_custom_call"' in txt
     # {0,1...}: rows minor, the packed table's layout; {1,0...}: words minor
     window = set(re.findall(r"u32\[%d,%d\](\{[01],[01])" % (rows, d_cols),
                             txt))
     assert window == {"{0,1"}, window
+
+
+def test_data_parallel_tree_partitions_through_the_kernel(v5e_2x2):
+    """The data-parallel learner's whole-tree program (the compact core
+    on each shard's rows under `shard_map`), compiled for the four chips
+    of the described v5e 2x2, 100,000 rows a shard (one rung over a
+    scatter tile) at `higgs`'s 11-word rows: each shard's partition holds
+    the Mosaic kernel, and no row scatter of the table's width is left.
+    The learner is built on this process's devices for its statics and
+    per-feature arrays; the rows are shapes alone."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.config import Config
+    from lightgbm_tpu.io.dataset import Dataset
+    from lightgbm_tpu.models import device_learner as dl
+    from lightgbm_tpu.parallel.learners import DeviceDataParallelTreeLearner
+    features, local = 28, 100_000
+    r = np.random.RandomState(features)
+    cfg = Config({"objective": "binary", "num_leaves": 15, "max_bin": 255,
+                  "min_data_in_bin": 1, "verbosity": -1})
+    lrn = DeviceDataParallelTreeLearner(
+        cfg, Dataset(r.randn(4000, features).astype(np.float32), config=cfg,
+                     label=(r.rand(4000) > 0.5).astype(np.float64)),
+        mesh=Mesh(np.array(jax.devices()[:4]), ("data",)))
+    assert lrn.strategy == "compact"
+    # the packed table's row: the codes, the gradient words, the row id
+    d_cols = lrn.table_row_bytes // 4
+    assert d_cols == 11
+    assert dl._size_classes(local)[-1] > dl.SCATTER_TILE_ROWS
+    lrn.mesh = Mesh(np.array(v5e_2x2.devices), ("data",))
+    lrn.local_n = local
+    fn = lrn._sharded_tree_fn(with_bag_key=False)
+
+    def shaped(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(lrn.mesh, spec))
+
+    rows = 4 * local
+    txt = jax.jit(fn).lower(
+        shaped((rows,) + lrn.codes_pack.shape[1:], lrn.codes_pack.dtype,
+               P("data", None)),
+        shaped((rows,) + lrn.codes_row.shape[1:], lrn.codes_row.dtype,
+               P("data", None)),
+        shaped((rows,), jnp.float32, P("data")),
+        shaped((rows,), jnp.float32, P("data")),
+        shaped((rows,), jnp.float32, P("data")),
+        shaped((features,), jnp.bool_, P()),
+        shaped((2,), jnp.uint32, P())).compile().as_text()
+    scatters = re.findall(r"= u32\[\d+,%d\]\{[^}]*\} scatter\(" % d_cols,
+                          txt)
+    assert not scatters, scatters
+    assert 'custom_call_target="tpu_custom_call"' in txt
+    # the shard's own packed table: the module is per shard
+    assert re.search(r"u32\[%d,%d\]" % (2 * local, d_cols), txt)
 
 
 @pytest.mark.parametrize("features, d_cols", [(28, 11),     # `higgs`

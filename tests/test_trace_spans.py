@@ -532,6 +532,47 @@ def test_partition_row_counters_follow_the_split_records(
     assert counters.get("partition_tiled_rows") == tiled
     if tile_rows:
         assert 0 < tiled < parents.sum()
+    # off the TPU the scan partitions every tile: the kernel counts none
+    assert counters.get("partition_kernel_rows") == 0
+
+
+@pytest.mark.parametrize("largest", [
+    None,          # the kernel takes every tile at this width
+    4096])         # it takes tiles of 4096 rows or fewer alone
+def test_partition_kernel_rows_follow_the_kernels_selection(
+        monkeypatch, largest):
+    """`partition_kernel_rows`, with the learner's table reported on
+    the TPU, is the parent rows of the splits whose rung's tile (the rung
+    itself up to one scatter tile; ladder 4096, 8192, 10000 under a tile
+    forced to 8192) is `partition_kernel.engaged` for, at the table's
+    width and on the table's platform."""
+    from lightgbm_tpu.models import device_learner as dl
+    from lightgbm_tpu.ops.pallas import partition_kernel
+    monkeypatch.setenv("LGBM_TPU_STRATEGY", "compact")
+    monkeypatch.setattr(dl, "SCATTER_TILE_ROWS", 8192)
+    monkeypatch.setattr(dl.DeviceTreeLearner, "_table_platform",
+                        lambda self: "tpu")
+    asked = set()
+    real = partition_kernel.engaged
+
+    def engaged(platform, rows, words):
+        asked.add((platform, rows, words))
+        return real(platform, rows, words) and (largest is None
+                                                or rows <= largest)
+
+    monkeypatch.setattr(partition_kernel, "engaged", engaged)
+    bst = _booster(n=10000, num_leaves=15)
+    bst.update()
+    tree = bst._gbdt.models[0]
+    parents = np.asarray(tree.internal_count[:tree.num_leaves - 1])
+    # the table's width: 6 four-bit codes (max_bin 15) in one word, 3
+    # gradient words, the row id
+    assert {(p, w) for p, _, w in asked} == {("tpu", 5)}
+    assert {r for _, r, _ in asked} <= {4096, 8192}
+    kernel = parents[parents <= largest].sum() if largest \
+        else parents.sum()
+    assert counters.get("partition_kernel_rows") == kernel
+    assert 0 < kernel <= parents.sum() == counters.get("partition_rows")
 
 
 def test_retrace_is_named_in_compile_seconds_by_function():
